@@ -36,12 +36,20 @@ import numpy as np
 from .calibrate import DEFAULT_LADDER, CalibrationTable, calibrate
 
 __all__ = ["Tunable", "tunables", "register_tunables", "autotune",
-           "TUNED_KINDS"]
+           "tuned_kinds"]
 
-#: PE kinds the tuned Pallas ops register for — the kernels run in
-#: interpret mode off-TPU, so any kind can host them; "acc" is where
-#: emulated platforms put accelerator PEs.
-TUNED_KINDS = ("cpu", "gpu", "acc")
+
+def tuned_kinds() -> Tuple[str, ...]:
+    """PE kinds the tuned Pallas ops register for.  Off the TPU the
+    kernels run in interpret mode, so any kind can host them ("acc" is
+    where emulated platforms put accelerator PEs).  On a TPU they run on
+    the chip, so only device kinds register: a "cpu" PE would otherwise
+    time the chip and record it as the host."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        return ("gpu", "acc")
+    return ("cpu", "gpu", "acc")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,14 +170,16 @@ def tunables() -> List[Tunable]:
     ]
 
 
-def register_tunables(registry=None, *, kinds: Sequence[str] = TUNED_KINDS,
+def register_tunables(registry=None, *, kinds: Optional[Sequence[str]] = None,
                       replace: bool = False) -> List[str]:
     """Register every tunable op (default + candidate variants + calib
-    input factory) on ``registry`` (default: the process registry).
-    Returns the op names, for ``calibrate(ops=...)``.  Idempotent with
-    ``replace=True``."""
+    input factory) on ``registry`` (default: the process registry) for
+    ``kinds`` (default: :func:`tuned_kinds`).  Returns the op names, for
+    ``calibrate(ops=...)``.  Idempotent with ``replace=True``."""
     if registry is None:
         from .api import default_registry as registry  # noqa: N813
+    if kinds is None:
+        kinds = tuned_kinds()
     names = []
     for t in tunables():
         names.append(t.op)

@@ -27,6 +27,7 @@ subprocess.
 from __future__ import annotations
 
 import multiprocessing as mp
+import os
 import pickle
 import threading
 import time
@@ -42,6 +43,9 @@ __all__ = ["WorkerDied", "ProcessWorker", "ProcessWorkerPool", "worker_main"]
 
 # Scratch segment each worker allocates for its outputs (grown on demand).
 _SCRATCH_START = 8 << 20
+
+# Serializes the environment swap in start_off_chip across threads.
+_SPAWN_ENV_LOCK = threading.Lock()
 
 
 class WorkerDied(RuntimeError):
@@ -212,6 +216,23 @@ def worker_main(conn, pe_name: str) -> None:
 # ---------------------------------------------------------------------------
 
 
+def start_off_chip(proc: mp.Process) -> None:
+    """Start ``proc`` with ``JAX_PLATFORMS=cpu`` in its environment.  A
+    spawned child inherits the parent's environment, and one that
+    imports a JAX kernel must not reach for a TPU the parent holds: it
+    would fail or hang on the chip's lock."""
+    with _SPAWN_ENV_LOCK:
+        saved = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            proc.start()
+        finally:
+            if saved is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = saved
+
+
 class ProcessWorker:
     """Parent handle for one PE's subprocess: pipe, clock offset, cache
     of which kernels were already shipped."""
@@ -224,7 +245,7 @@ class ProcessWorker:
             target=worker_main, args=(child, pe_name),
             name=f"rimms-pe-{pe_name}", daemon=True,
         )
-        self.proc.start()
+        start_off_chip(self.proc)
         child.close()
         self._sent: set = set()
         self._scratch_names: set = set()

@@ -76,24 +76,23 @@ BACKENDS = ("thread", "process", "auto")
 def resolve_backend(backend: Optional[str]) -> str:
     """Validate + resolve a backend name to ``"thread"`` or ``"process"``.
 
-    ``None`` means thread (the historical default).  ``"auto"`` picks the
-    process backend when real parallelism is available — more than one
-    CPU core, or more than one JAX device — and thread otherwise."""
+    ``None`` means thread (the historical default).  ``"auto"`` means
+    thread on a TPU, whose chips belong to this process and no worker
+    can reach; elsewhere it picks the process backend when real
+    parallelism is available — more than one CPU core, or more than one
+    JAX device — and thread otherwise."""
     if backend is None:
         return "thread"
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown backend {backend!r}: choose one of {BACKENDS}")
     if backend == "auto":
-        if (os.cpu_count() or 1) > 1:
-            return "process"
-        try:
-            import jax
+        import jax
 
-            if len(jax.devices()) > 1:
-                return "process"
-        except Exception:  # pragma: no cover - jax is baked in
-            pass
+        if jax.default_backend() == "tpu":
+            return "thread"
+        if (os.cpu_count() or 1) > 1 or len(jax.devices()) > 1:
+            return "process"
         return "thread"
     return backend
 
@@ -770,19 +769,21 @@ def make_emulated_soc(
     kernels over per-device jax payloads.  ``"process"`` builds the SoC
     for subprocess PE workers: host buffers come from a
     :class:`~repro.core.shm.SharedHostArena` (``host_arena_bytes``
-    capacity) that workers map zero-copy, and emulated accelerator
-    spaces hold host-format numpy payloads (their arenas — capacity,
-    eviction, the whole ledger — stay modeled exactly as before).  When
-    ``jax.devices()`` exposes more than one real device, accelerators
-    are spread round-robin across them and keep in-process async
-    dispatch (real device parallelism beats a worker pipe).
+    capacity) that workers map zero-copy, and on a single CPU device the
+    emulated accelerator spaces hold host-format numpy payloads (their
+    arenas — capacity, eviction, the whole ledger — stay modeled exactly
+    as before).  On a TPU, or when ``jax.devices()`` exposes more than
+    one device, accelerators are spread round-robin across the devices
+    and keep in-process async dispatch: a worker cannot reach a chip
+    this process holds, and real device parallelism beats a worker pipe.
     """
     import jax
 
     backend = resolve_backend(backend)
     ctx = context or HeteContext(tracking=tracking)
     devices = jax.devices()
-    multi_device = len(devices) > 1
+    host_payloads = (backend == "process" and len(devices) == 1
+                     and jax.default_backend() != "tpu")
     if backend == "process" and ctx.host_arena is None:
         from .shm import SharedHostArena, default_arena_bytes
 
@@ -810,7 +811,7 @@ def make_emulated_soc(
             arena_bytes.get(name, 64 << 20)
             if isinstance(arena_bytes, dict) else arena_bytes
         )
-        if backend == "process" and not multi_device:
+        if host_payloads:
             # Subprocess workers execute this PE's kernels: device copies
             # are host-format (distinct shared-memory buffers — the
             # host→device copy is real, the arena stays modeled).

@@ -1,18 +1,12 @@
-"""JAX version-compatibility shims (ISSUE 2).
+"""JAX call shapes shared by src/ and tests/.
 
-The repo targets both older (0.4.x) and current JAX:
+* :func:`make_mesh` builds meshes whose axes are all ``Auto``-typed,
+  which is what this codebase's sharding rules assume.
+* :func:`cost_analysis` returns ``compiled.cost_analysis()`` as a plain
+  dict (``{}`` when the backend reports nothing).
 
-* ``jax.sharding.AxisType`` (explicit/auto axis types) only exists in
-  newer releases — on older ones every mesh axis is implicitly "auto",
-  which is exactly what this codebase wants, so :func:`make_mesh` simply
-  omits the argument there.
-* ``PartitionSpec`` equality: older releases compare entries
-  structurally, so ``P("data") != P(("data",))``; newer ones normalize.
-  ``AxisRules.entry`` / ``resolve_spec`` therefore always emit the
-  canonical tuple form (see repro.distributed.sharding).
-
-Import this module instead of touching ``jax.sharding.AxisType``
-directly anywhere in src/ or tests/.
+``PartitionSpec`` entries are always emitted in canonical tuple form by
+``AxisRules.entry`` / ``resolve_spec`` (see repro.distributed.sharding).
 """
 
 from __future__ import annotations
@@ -20,31 +14,17 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5-era: explicit sharding axis types
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # older jax: meshes are implicitly auto-typed
-    AxisType = None
-
-HAS_AXIS_TYPE = AxisType is not None
-
-__all__ = ["AxisType", "HAS_AXIS_TYPE", "make_mesh", "cost_analysis"]
+__all__ = ["make_mesh", "cost_analysis"]
 
 
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with auto axis types on every jax version."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            tuple(shape), tuple(axis_names),
-            axis_types=(AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every jax version
-    (older releases return a one-element list of dicts)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a dict."""
+    return dict(compiled.cost_analysis() or {})

@@ -5,11 +5,21 @@ Each kernel subpackage ships:
   ops.py    — the jit'd public wrapper (padding, reshapes, vmap)
   ref.py    — the pure-jnp oracle used by the allclose test sweeps
 
-``INTERPRET`` is True off-TPU: kernels execute their bodies in Python
-via the Pallas interpreter for correctness validation (this container is
-CPU-only; TPU v5e is the deployment target).
+Interpret mode is decided when a kernel is traced, never when this
+package is imported (importing must not start a JAX backend, e.g. in a
+worker process that has to stay off the chip).  Every kernel takes
+``interpret=None``, which :func:`resolve_interpret` turns into the
+Pallas interpreter wherever JAX's default backend is not a TPU.
 """
+
+from typing import Optional
 
 import jax
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """A kernel's ``interpret`` argument as traced now: an explicit bool
+    wins; ``None`` means interpret unless the default backend is a TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

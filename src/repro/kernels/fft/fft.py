@@ -14,12 +14,13 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import INTERPRET
+from repro.kernels import resolve_interpret
 
 BLOCK_ROWS = 8
 
@@ -34,7 +35,9 @@ def _fft_kernel(n, xr_ref, xi_ref, or_ref, oi_ref):
         m2 = m // 2
         ar, br = xr[:, :, :m2], xr[:, :, m2:]
         ai, bi = xi[:, :, :m2], xi[:, :, m2:]
-        k = jax.lax.broadcasted_iota(jnp.float32, (1, 1, m2), 2)
+        # Mosaic's iota is integer-only; the cast is exact below 2**24
+        k = jax.lax.broadcasted_iota(jnp.int32, (1, 1, m2), 2).astype(
+            jnp.float32)
         ang = (-2.0 * math.pi / m) * k
         wr, wi = jnp.cos(ang), jnp.sin(ang)
         sr, si = ar - br, ai - bi
@@ -50,7 +53,7 @@ def _fft_kernel(n, xr_ref, xi_ref, or_ref, oi_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def fft_planes(xr, xi, *, block_rows: int = BLOCK_ROWS,
-               interpret: bool = INTERPRET):
+               interpret: Optional[bool] = None):
     """xr, xi: (rows, N) f32 → FFT along axis 1 (rows padded to tiles).
     ``block_rows`` is a pure launch parameter — rows are independent, so
     any tiling produces bit-identical planes (autotuned, ISSUE 10)."""
@@ -62,5 +65,5 @@ def fft_planes(xr, xi, *, block_rows: int = BLOCK_ROWS,
         in_specs=[spec, spec],
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((rows, n), jnp.float32)] * 2,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xr, xi)

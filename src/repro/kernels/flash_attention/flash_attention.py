@@ -14,13 +14,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import INTERPRET
+from repro.kernels import resolve_interpret
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
@@ -85,7 +86,7 @@ def _flash_kernel(block_q, block_k, scale, causal,
 def flash_attention_bh(q, k, v, *, causal: bool = True,
                        block_q: int = DEFAULT_BLOCK_Q,
                        block_k: int = DEFAULT_BLOCK_K,
-                       interpret: bool = INTERPRET):
+                       interpret: Optional[bool] = None):
     """q,k,v: (BH, S, d) — batch·heads flattened. Returns (BH, S, d)."""
     bh, s, d = q.shape
     block_q = min(block_q, s)
@@ -107,5 +108,5 @@ def flash_attention_bh(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -17,13 +17,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import INTERPRET
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +83,7 @@ def _paged_kernel(page_size, n_kv, group, scale,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
-                    interpret: bool = INTERPRET):
+                    interpret: Optional[bool] = None):
     """q: (B, Hq, d); k_pages/v_pages: (P, page, Hkv, d);
     block_table: (B, n_pages) int32; lengths: (B,) int32.
     Returns (B, Hq, d)."""
@@ -112,5 +113,5 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
         functools.partial(_paged_kernel, page, n_kv, group, scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, hq, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_table, lengths, q, k_pages, v_pages)

@@ -10,12 +10,13 @@ dimension 128 to match the VPU registers.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import INTERPRET
+from repro.kernels import resolve_interpret
 
 BLOCK_ROWS = 256
 LANES = 128
@@ -30,7 +31,7 @@ def _zip_kernel(ar_ref, ai_ref, br_ref, bi_ref, or_ref, oi_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def zip_mul_planes(ar, ai, br, bi, *, block_rows: int = BLOCK_ROWS,
-                   interpret: bool = INTERPRET):
+                   interpret: Optional[bool] = None):
     """(rows, 128) f32 planes → complex product planes.  ``block_rows``
     is a pure launch parameter (elementwise op → bit-identical tiling,
     autotuned in ISSUE 10)."""
@@ -43,5 +44,5 @@ def zip_mul_planes(ar, ai, br, bi, *, block_rows: int = BLOCK_ROWS,
         in_specs=[spec] * 4,
         out_specs=[spec] * 2,
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.float32)] * 2,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ar, ai, br, bi)

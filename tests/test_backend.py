@@ -73,6 +73,54 @@ def test_resolve_backend_auto_rule():
                             else "thread")
 
 
+def test_resolve_backend_auto_is_thread_on_tpu(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_backend("auto") == "thread"
+    assert resolve_backend("process") == "process"  # an explicit choice stands
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_process_backend_keeps_accelerators_on_a_tpu(monkeypatch, platform):
+    """Host-format accelerator payloads (run by workers) only on a lone
+    CPU device: a TPU's accelerators always hold its device arrays."""
+    import jax
+
+    from repro.core.locations import Location
+
+    host_payloads = platform == "cpu" and len(jax.devices()) == 1
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    _, ctx = runtime_mod.make_emulated_soc(
+        n_cpu=1, accelerators=("gpu0",), backend="process")
+    try:
+        space = ctx.spaces[Location("device", "gpu0")]
+        assert space.proc_exec is host_payloads
+        moved = space.ingest(np.ones(4))
+        assert isinstance(moved, jax.Array) is not host_payloads
+    finally:
+        ctx.host_arena.destroy()
+
+
+@pytest.mark.parametrize("parent_value", ["tpu", None])
+def test_workers_start_with_cpu_only_jax(monkeypatch, parent_value):
+    from repro.core.pworker import start_off_chip
+
+    if parent_value is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", parent_value)
+    seen = {}
+
+    class _Proc:
+        def start(self):
+            seen["child"] = os.environ.get("JAX_PLATFORMS")
+
+    start_off_chip(_Proc())
+    assert seen["child"] == "cpu"
+    assert os.environ.get("JAX_PLATFORMS") == parent_value
+
+
 def test_unknown_backend_rejected_with_choices():
     with pytest.raises(ValueError, match="unknown backend"):
         resolve_backend("celery")

@@ -275,6 +275,22 @@ def test_tuned_variant_candidates_bit_identical_to_default():
                 )
 
 
+@pytest.mark.parametrize("platform, kinds", [
+    ("cpu", {"cpu", "gpu", "acc"}),
+    ("tpu", {"gpu", "acc"}),  # a cpu PE must not time the chip
+])
+def test_tunables_register_for_the_platforms_kinds(monkeypatch, platform,
+                                                   kinds):
+    import jax
+
+    from repro.core.autotune import register_tunables
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    reg = OpRegistry()
+    for op_name in register_tunables(reg):
+        assert set(reg.kinds(op_name)) == kinds, op_name
+
+
 def test_autotune_registers_variants_and_attaches_table():
     from repro.core.autotune import autotune, register_tunables
 
