@@ -77,7 +77,7 @@ from .runtime import (BACKENDS, Runtime, Task,  # noqa: F401
                       make_emulated_soc, platform_names, register_platform,
                       resolve_backend)
 from .telemetry import Sampler, metrics_text, serve_metrics, slo_eval
-from .trace import (MetricsRegistry, TraceCollector, trace,  # noqa: F401
+from .trace import (NULL_REGION, MetricsRegistry, TraceCollector, trace,  # noqa: F401
                     trace_lint)
 
 __all__ = ["OpRegistry", "OpVariant", "op", "default_registry",
@@ -669,35 +669,34 @@ class Session:
         through the returned futures, not here."""
         self._check_open()
         cl = self._resolve_client(client)
+        name = name or f"{op_name}#{next(self._seq)}"
+        tracer = self.context.tracer
+        with (NULL_REGION if tracer is None else
+              tracer.region(name, "submit", f"tenant:{cl.name}", task=name,
+                            op=op_name, client=cl.name)):
+            return self._submit(cl, op_name, name, inputs, out, out_shape,
+                                out_dtype, n_out, pin, nowait, params)
+
+    def _submit(self, cl, op_name, name, inputs, out, out_shape, out_dtype,
+                n_out, pin, nowait, params):
         ins_hd = [self._coerce(x, owner=cl.name) for x in inputs]
         outs_hd, single = self._normalize_outs(
             ins_hd, out, out_shape, out_dtype, n_out, owner=cl.name)
         task = Task(
             op_name, ins_hd, outs_hd, params=dict(params), pin=pin,
-            name=name or f"{op_name}#{next(self._seq)}", client=cl.name,
+            name=name, client=cl.name,
         )
         self.metrics.counter("submits").inc()
         tracer = self.context.tracer
-        if tracer is not None:
-            tracer.instant("submit", "submit", f"tenant:{cl.name}",
-                           {"task": task.name, "op": op_name,
-                            "client": cl.name})
-            t_adm = tracer.now()
         try:
-            stall = self.qos.admit(cl.state, admission_cost(task),
-                                   nowait=nowait)
+            with (NULL_REGION if tracer is None else
+                  tracer.region(name, "qos", f"tenant:{cl.name}", task=name,
+                                op=op_name, client=cl.name)):
+                stall = self.qos.admit(cl.state, admission_cost(task),
+                                       nowait=nowait)
         except BackpressureFull:
             self.metrics.counter("backpressure_rejections").inc()
-            if tracer is not None:
-                tracer.instant("backpressure_full", "qos",
-                               f"tenant:{cl.name}",
-                               {"task": task.name, "client": cl.name})
             raise
-        if tracer is not None:
-            tracer.span("qos_admit", "qos", f"tenant:{cl.name}",
-                        t_adm, tracer.now(),
-                        {"task": task.name, "client": cl.name,
-                         "stall_s": stall})
         if stall > 0.0:
             self.metrics.counter("backpressure_blocks").inc()
             if tracer is not None:

@@ -210,46 +210,39 @@ def _execute_task(rt: "Runtime", task: "Task", pe: "PE",
     ``(w0, w1, tr_s, spill_s, comp_s, out_s, moves)`` — wall bounds plus
     the modeled accounting both executors feed their schedule
     simulations."""
-    tracer = rt.context.tracer
     w0 = time.perf_counter()
-    pre = fut.result() if fut is not None else None
-    loc = pe.location
-    staged = None
-    if pre is not None:
-        # Pin first, then validate: once pinned the inputs cannot be
-        # evicted, so unchanged eviction epochs prove the prefetched
-        # staging is still current.
-        pre_staged, epochs = pre
-        rt._pin_inputs(task, loc)
-        if all(hd.root.eviction_epoch == ep
-               for hd, ep in zip(task.inputs, epochs)):
-            staged = pre_staged
-        else:  # pressure evicted warmed bytes: stage on demand
-            rt._unpin_inputs(task, loc)
-    if staged is None:
-        # no prefetch, prefetch deferred, or warmed bytes evicted —
-        # authoritative pinned staging
-        staged = rt._stage_inputs(task, pe)
-        if pre is not None:  # account the wasted warm-up too
-            staged = (staged[0], staged[1] + pre[0][1],
-                      staged[2] + pre[0][2], pre[0][3] + staged[3])
+    with rt._region("stage", task, pe):
+        pre = fut.result() if fut is not None else None
+        loc = pe.location
+        staged = None
+        if pre is not None:
+            # Pin first, then validate: once pinned the inputs cannot be
+            # evicted, so unchanged eviction epochs prove the prefetched
+            # staging is still current.
+            pre_staged, epochs = pre
+            rt._pin_inputs(task, loc)
+            if all(hd.root.eviction_epoch == ep
+                   for hd, ep in zip(task.inputs, epochs)):
+                staged = pre_staged
+            else:  # pressure evicted warmed bytes: stage on demand
+                rt._unpin_inputs(task, loc)
+        if staged is None:
+            # no prefetch, prefetch deferred, or warmed bytes evicted —
+            # authoritative pinned staging
+            staged = rt._stage_inputs(task, pe)
+            if pre is not None:  # account the wasted warm-up too
+                staged = (staged[0], staged[1] + pre[0][1],
+                          staged[2] + pre[0][2], pre[0][3] + staged[3])
     ins, tr_s, sp_s, moves = staged
     w_staged = time.perf_counter()
     try:
         outs, comp_s = rt._run_kernel(task, pe, ins)
-        w_comp = time.perf_counter()
         out_s, sp2_s = rt._commit_outputs(task, pe, outs)
     finally:
         rt._unpin_inputs(task, pe.location)
     w1 = time.perf_counter()
     rt.divergence.observe("stage", task.op, pe.kind, task.in_bytes,
                           w_staged - w0, tr_s + sp_s)
-    if tracer is not None:
-        tname = task.name or task.op
-        targs = {"task": tname, "op": task.op, "client": task.client}
-        tracer.span(tname, "stage", f"pe:{pe.name}:stage", w0, w_staged, targs)
-        tracer.span(tname, "compute", f"pe:{pe.name}", w_staged, w_comp, targs)
-        tracer.span(tname, "writeback", f"pe:{pe.name}", w_comp, w1, targs)
     return w0, w1, tr_s, sp_s + sp2_s, comp_s, out_s, moves
 
 
@@ -462,17 +455,11 @@ class _ExecutorBase:
         if every input root's eviction epoch is unchanged once pinned —
         or None when capacity pressure defers to demand staging (never
         evicting bytes another queued task still reads)."""
-        tracer = self.rt.context.tracer
-        t0 = time.perf_counter() if tracer is not None else 0.0
         try:
-            staged = self.rt._stage_inputs(task, pe, prefetch=True)
+            with self.rt._region("stage", task, pe, prefetch=True):
+                staged = self.rt._stage_inputs(task, pe, prefetch=True)
         except PrefetchDeferred:
             return None
-        if tracer is not None:
-            tname = task.name or task.op
-            tracer.span(tname, "stage", f"pe:{pe.name}:stage",
-                        t0, time.perf_counter(),
-                        {"task": tname, "prefetch": True})
         return staged, tuple(hd.root.eviction_epoch for hd in task.inputs)
 
     # -- claims -------------------------------------------------------------

@@ -360,6 +360,15 @@ class HeteContext:
         baseline = self.ledger.attach_tracer(tracer, label)
         tracer.set_ledger_baseline(label, baseline)
 
+    def copy_region(self, src: Location, dst: Location, nbytes: int):
+        """The tracer's region around one real copy, on the link's track
+        (the shared null context when tracing is off)."""
+        tracer = self.tracer
+        if tracer is None:
+            return trace_mod.NULL_REGION
+        return tracer.region("copy", "copy", f"link:{src}->{dst}",
+                             src=str(src), dst=str(dst), nbytes=int(nbytes))
+
     def attach_host_arena(self, arena) -> None:
         """Attach a :class:`~repro.core.shm.SharedHostArena`: host buffers
         from :meth:`malloc` (and staging copies routed through
@@ -848,11 +857,12 @@ class HeteContext:
             # interval: ONE whole-parent transfer covers root and
             # fragments alike; fragments get zero-copy slices of the
             # peer buffer (the shape _propagate_to_fragments produces).
-            moved = pspace.ingest(space.egress(root.copies[loc]))
+            with self.copy_region(loc, peer, root.nbytes):
+                moved = pspace.ingest(space.egress(root.copies[loc]))
+                wb_s += self.record_copy(loc, peer, root.nbytes)
             root.copies[peer] = moved
             root.last_location = peer
             root.valid_at.add(peer)
-            wb_s += self.record_copy(loc, peer, root.nbytes)
             if root.fragments:
                 step = int(root.fragments[0].shape[0])
                 for i, frag in enumerate(root.fragments):
@@ -864,10 +874,11 @@ class HeteContext:
             # Fragments own the flag and hold their own device arrays:
             # spill each dirty fragment individually.
             for o in dirty_owners:
-                o.copies[peer] = pspace.ingest(space.egress(o.copies[loc]))
+                with self.copy_region(loc, peer, o.nbytes):
+                    o.copies[peer] = pspace.ingest(space.egress(o.copies[loc]))
+                    wb_s += self.record_copy(loc, peer, o.nbytes)
                 o.last_location = peer
                 o.valid_at.add(peer)
-                wb_s += self.record_copy(loc, peer, o.nbytes)
         self._touch(root, peer)
         return wb_s
 
@@ -1024,20 +1035,21 @@ class HeteContext:
                 return hd.copies[dst], 0.0
             if dst != HOST:
                 self._reserve(hd, dst)
-            value = hd.copies[src]
-            host_np = self.spaces[src].egress(value) if src != HOST else value
-            if dst == HOST and (hd.parent is not None or hd.fragments):
-                # preserve the zero-copy host views linking parent and
-                # fragments (rebinding would orphan them)
-                np.copyto(hd.copies[HOST], np.asarray(host_np).reshape(hd.shape))
-                moved = hd.copies[HOST]
-            else:
-                moved = self.spaces[dst].ingest(host_np) if dst != HOST else host_np
-                hd.copies[dst] = moved
+            with self.copy_region(src, dst, hd.nbytes):
+                value = hd.copies[src]
+                host_np = self.spaces[src].egress(value) if src != HOST else value
+                if dst == HOST and (hd.parent is not None or hd.fragments):
+                    # preserve the zero-copy host views linking parent and
+                    # fragments (rebinding would orphan them)
+                    np.copyto(hd.copies[HOST], np.asarray(host_np).reshape(hd.shape))
+                    moved = hd.copies[HOST]
+                else:
+                    moved = self.spaces[dst].ingest(host_np) if dst != HOST else host_np
+                    hd.copies[dst] = moved
+                tr_s = self.record_copy(src, dst, hd.nbytes)
             hd.valid_at.add(dst)
             if dst != HOST:
                 self._touch(hd.root, dst)
-            tr_s = self.record_copy(src, dst, hd.nbytes)
             self._log_move(src, dst, hd.nbytes)
             return moved, tr_s
 

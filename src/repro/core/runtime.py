@@ -59,6 +59,7 @@ from .hete import HeteContext, HeteData, MemorySpace
 from .instrument import Timeline, TimelineEvent
 from .locations import HOST, Location
 from .telemetry import DivergenceMonitor
+from .trace import NULL_REGION
 
 __all__ = ["PE", "Task", "Runtime", "make_emulated_soc", "SCHEDULERS",
            "BACKENDS", "resolve_backend", "register_platform",
@@ -430,8 +431,9 @@ class Runtime:
                     with hd.lock:
                         host_val = hd.copies[HOST]
                         if loc != HOST:
-                            moved = ctx.spaces[loc].ingest(host_val)
-                            model_s += ctx.record_copy(HOST, loc, hd.nbytes)
+                            with ctx.copy_region(HOST, loc, hd.nbytes):
+                                moved = ctx.spaces[loc].ingest(host_val)
+                                model_s += ctx.record_copy(HOST, loc, hd.nbytes)
                             moves.append((HOST, loc, hd.nbytes))
                             ins.append(moved)
                         else:
@@ -468,20 +470,21 @@ class Runtime:
         zero-copy, the parent thread blocks GIL-free on the reply — for
         every PE whose space holds host payloads; other PEs (real JAX
         devices) execute in-process as before."""
-        if self.backend == "process" and self._proc_eligible(pe):
-            outs, dt = self._run_kernel_process(task, pe, ins)
-        else:
-            fn, params, _ = self._select_kernel(task, pe)
-            t0 = time.perf_counter()
-            outs = _as_tuple(fn(ins, **params))
-            if pe.location != HOST:
-                try:
-                    import jax
-                    outs = tuple(jax.block_until_ready(o) for o in outs)
-                except ImportError:  # pragma: no cover - jax is baked in
-                    pass
-            dt = time.perf_counter() - t0
-            self.cost_model.observe(task.op, pe.kind, task.in_bytes, dt)
+        with self._region("compute", task, pe):
+            if self.backend == "process" and self._proc_eligible(pe):
+                outs, dt = self._run_kernel_process(task, pe, ins)
+            else:
+                fn, params, _ = self._select_kernel(task, pe)
+                t0 = time.perf_counter()
+                outs = _as_tuple(fn(ins, **params))
+                if pe.location != HOST:
+                    try:
+                        import jax
+                        outs = tuple(jax.block_until_ready(o) for o in outs)
+                    except ImportError:  # pragma: no cover - jax is baked in
+                        pass
+                dt = time.perf_counter() - t0
+                self.cost_model.observe(task.op, pe.kind, task.in_bytes, dt)
         self.divergence.observe(
             "compute", task.op, pe.kind, task.in_bytes, dt,
             self.cost_model.prior_estimate(task.op, pe.kind, task.in_bytes))
@@ -536,18 +539,35 @@ class Runtime:
         ctx, loc = self.context, pe.location
         model_s = 0.0
         ctx.take_spill_seconds()  # clear this thread's residue
-        if self.policy == "reference":
-            for hd, val in zip(task.outputs, outs):
-                if loc != HOST:
-                    host_val = ctx.spaces[loc].egress(val)
-                    model_s += ctx.record_copy(loc, HOST, hd.nbytes)
-                else:
-                    host_val = np.asarray(val)
-                ctx.mark_written(hd, HOST, host_val.reshape(hd.shape))
-        else:
-            for hd, val in zip(task.outputs, outs):
-                ctx.mark_written(hd, loc, val)
+        with self._region("writeback", task, pe):
+            if self.policy == "reference":
+                for hd, val in zip(task.outputs, outs):
+                    if loc != HOST:
+                        with ctx.copy_region(loc, HOST, hd.nbytes):
+                            host_val = ctx.spaces[loc].egress(val)
+                            model_s += ctx.record_copy(loc, HOST, hd.nbytes)
+                    else:
+                        host_val = np.asarray(val)
+                    ctx.mark_written(hd, HOST, host_val.reshape(hd.shape))
+            else:
+                for hd, val in zip(task.outputs, outs):
+                    ctx.mark_written(hd, loc, val)
         return model_s, ctx.take_spill_seconds()
+
+    def _region(self, cat: str, task: Task, pe: PE, prefetch: bool = False):
+        """The tracer's region for one phase of ``task`` on ``pe`` —
+        ``stage``, ``compute`` or ``writeback``, on the PE's track — or
+        the shared null context when tracing is off.  Both executors
+        and the serial path enter it."""
+        tracer = self.context.tracer
+        if tracer is None:
+            return NULL_REGION
+        tname = task.name or task.op
+        track = f"pe:{pe.name}:stage" if cat == "stage" else f"pe:{pe.name}"
+        if prefetch:
+            return tracer.region(tname, cat, track, task=tname, op=task.op,
+                                 pe=pe.name, prefetch=1)
+        return tracer.region(tname, cat, track, task=tname, op=task.op, pe=pe.name)
 
     def _add_transfer_lanes(self, topo, task: Task, moves: Sequence[tuple],
                             start: float, node: int = -1) -> float:
@@ -600,17 +620,16 @@ class Runtime:
         topo = getattr(self.context.ledger.bandwidth_model, "topology", None)
         if topo is not None:
             topo.reset_contention()
-        tracer = self.context.tracer
         model_t = 0.0
         t0 = time.perf_counter()
         for node_i, task in enumerate(tasks):
             pe = self._schedule(task)
             w0 = time.perf_counter()
-            ins, tr_s, sp_s, moves = self._stage_inputs(task, pe)
+            with self._region("stage", task, pe):
+                ins, tr_s, sp_s, moves = self._stage_inputs(task, pe)
             w_staged = time.perf_counter()
             try:
                 outs, comp_s = self._run_kernel(task, pe, ins)
-                w_comp = time.perf_counter()
                 out_s, sp2_s = self._commit_outputs(task, pe, outs)
             finally:
                 self._unpin_inputs(task, pe.location)
@@ -618,15 +637,6 @@ class Runtime:
             self.divergence.observe(
                 "stage", task.op, pe.kind, task.in_bytes,
                 w_staged - w0, tr_s + sp_s)
-            if tracer is not None:
-                tname = task.name or task.op
-                targs = {"task": tname, "op": task.op, "node": node_i}
-                tracer.span(tname, "stage", f"pe:{pe.name}:stage",
-                            w0, w_staged, targs)
-                tracer.span(tname, "compute", f"pe:{pe.name}",
-                            w_staged, w_comp, targs)
-                tracer.span(tname, "writeback", f"pe:{pe.name}",
-                            w_comp, w1, targs)
             spill_s = sp_s + sp2_s
             stage_m = tr_s
             if topo is not None:
@@ -653,6 +663,7 @@ class Runtime:
             model_t += dur_m
             self.task_log.append((task.name or task.op, pe.name))
         self.last_makespan_model = model_t
+        tracer = self.context.tracer
         if tracer is not None:
             tracer.add_timeline(self.timeline, label="serial")
         return time.perf_counter() - t0

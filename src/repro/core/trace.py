@@ -7,6 +7,10 @@ module imports only the stdlib, so every other layer may import it):
     Low-overhead event collection.  Wall-clock events (spans and
     instants) go into per-thread append-only ring buffers — no locks on
     the record path, bounded memory, a drop counter when a ring fills.
+    ``region()`` spans go to two sinks: while open, a
+    ``jax.profiler.TraceAnnotation`` named ``rimms.<category>`` (so a
+    profiler trace holds them on the device's clock, with their stats);
+    on exit, the same span in the thread's ring.
     Modeled-time events are derived in bulk from ``Timeline`` objects
     pushed at sync points (end of ``Runtime.run`` / ``GraphExecutor.run``
     / ``Session.close``), so the deterministic replay timebase costs
@@ -57,7 +61,12 @@ __all__ = [
     "trace_lint",
     "install_global",
     "global_collector",
+    "NULL_REGION",
 ]
+
+#: What a call site enters when tracing is off: one shared, reusable
+#: no-op context, so the untraced path builds no object per boundary.
+NULL_REGION = contextlib.nullcontext()
 
 # Wall-clock events live in process group 1, modeled-time events in
 # group 2, so Perfetto renders the two timebases as separate track
@@ -86,6 +95,49 @@ class _Ring:
         self.thread_name = thread_name
 
 
+# A TraceMe encodes its stats as "name#key=value,key=value#": string
+# values carry those delimiters percent-encoded (task names are "op#n").
+_DELIMITERS = str.maketrans({"%": "%25", "#": "%23", ",": "%2C"})
+
+
+def _encodable(stats: dict) -> dict:
+    for v in stats.values():
+        if v.__class__ is str and ("#" in v or "," in v or "%" in v):
+            return {k: x.translate(_DELIMITERS) if isinstance(x, str) else x
+                    for k, x in stats.items()}
+    return stats
+
+
+class _Region:
+    """One open :meth:`TraceCollector.region`."""
+
+    __slots__ = ("tc", "name", "cat", "track", "stats", "ann", "t0")
+
+    def __init__(self, tc: "TraceCollector", name: str, cat: str, track: str,
+                 stats: dict):
+        self.tc, self.name, self.cat, self.track = tc, name, cat, track
+        self.stats = stats
+
+    def __enter__(self) -> "_Region":
+        ann = self.ann = self.tc._annotation("rimms." + self.cat,
+                                             **_encodable(self.stats))
+        ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
+        tc = self.tc
+        r = tc._ring()  # inlined span(): this is the traced hot path
+        if len(r.events) < r.capacity:
+            r.events.append(("X", self.name, self.cat, self.track,
+                             self.t0 - tc._t0, t1 - self.t0, self.stats))
+        else:
+            r.drops += 1
+        return False
+
+
 class TraceCollector:
     """Collects wall + modeled events; exports Perfetto trace JSON.
 
@@ -109,6 +161,10 @@ class TraceCollector:
         self._divergence: Optional[dict] = None  # wall/modeled ratio table
         self._nctx = 0
         self._nrun = 0
+        # the profiler sink; imported here so the module stays stdlib-only
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
 
     # -- hot path ----------------------------------------------------------
 
@@ -148,6 +204,17 @@ class TraceCollector:
             r.events.append(("X", name, cat, track, t0 - self._t0, t1 - t0, args))
         else:
             r.drops += 1
+
+    def region(self, name: str, cat: str, track: str, **stats):
+        """A span around a ``with`` block: a profiler annotation
+        ``rimms.<cat>`` carrying ``stats`` while it is open (``%``, ``#``
+        and ``,`` in string values percent-encoded; the profiler reads a
+        value that looks like a number as one), then the same span
+        (``stats`` as its args) in this thread's ring.  Paused, it records
+        nothing."""
+        if not self.enabled:
+            return NULL_REGION
+        return _Region(self, name, cat, track, stats)
 
     def forward_span(
         self,

@@ -42,6 +42,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.api import OpRegistry, Session
 from repro.core.kv_manager import KVManager
+from repro.core.trace import NULL_REGION
 from repro.models import layers as L
 
 from .engine import SUPPORTED_FAMILIES, Request, _paged_decode_step
@@ -59,9 +60,10 @@ def _jit_grouped_step(cfg: ArchConfig, n_groups: int):
     """One batched decode step over a compacted pool of ``n_groups``
     page groups: concat → legacy step → split, jitted as one unit.
     Cached per (config, group count) so every engine instance — and
-    every run in a benchmark — shares compilations."""
+    every run in a benchmark — shares compilations.  The function's name
+    names the program in a device trace (``jit_serve_step``)."""
 
-    def fn(params, k_groups, v_groups, block_tables, tokens, pos, lengths):
+    def serve_step(params, k_groups, v_groups, block_tables, tokens, pos, lengths):
         k_pool = jnp.concatenate(k_groups, axis=1)
         v_pool = jnp.concatenate(v_groups, axis=1)
         nxt, k_pool, v_pool = _paged_decode_step(
@@ -72,7 +74,7 @@ def _jit_grouped_step(cfg: ArchConfig, n_groups: int):
         return (nxt, tuple(jnp.split(k_pool, cuts, axis=1)),
                 tuple(jnp.split(v_pool, cuts, axis=1)))
 
-    return jax.jit(fn)
+    return jax.jit(serve_step)
 
 
 class SessionServeEngine:
@@ -240,6 +242,12 @@ class SessionServeEngine:
         self.waiting.append(req)
         return req
 
+    def _region(self, cat: str):
+        """The tracer's region for one engine phase (``step`` or
+        ``admit``), or the shared null context when tracing is off."""
+        tracer = self.session.context.tracer
+        return NULL_REGION if tracer is None else tracer.region(cat, cat, "serve")
+
     def _admit(self) -> None:
         from repro.core.allocator import AllocError
         from repro.core.qos import QuotaExceeded
@@ -320,7 +328,12 @@ class SessionServeEngine:
     def step(self) -> int:
         """One lock-step decode over all active slots — submitted as one
         latency-sensitive sub-step per tenant present; returns #active."""
-        self._admit()
+        with self._region("step"):
+            return self._step()
+
+    def _step(self) -> int:
+        with self._region("admit"):
+            self._admit()
         active = np.array([r is not None for r in self.slot_req])
         if not active.any():
             self.kv.publish_metrics()

@@ -320,3 +320,170 @@ def test_metrics_registry_create_or_get_and_snapshot():
     assert snap["g"]["value"] == 1.5
     assert snap["h"]["count"] == 1
     assert reg.histograms() == [("h", reg.histogram("h"))]
+
+
+# ---------------------------------------------------------------------------
+# regions: one span in the ring and on the profiler's clock
+# ---------------------------------------------------------------------------
+
+RIMMS_CATS = ("submit", "qos", "stage", "copy", "compute", "writeback", "step",
+              "admit")
+
+
+def _profiled(tmp_path, work):
+    """Run ``work()`` under the JAX profiler; return the host events named
+    ``rimms.*`` as {line index: [(start, end, name, stats)]}."""
+    import glob
+    import os
+    from urllib.parse import unquote
+
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    lines = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for k, ln in enumerate(plane.lines):
+            evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                    {k: unquote(v) if isinstance(v, str) else v for k, v in e.stats})
+                   for e in ln.events if e.name.startswith("rimms.")]
+            if evs:
+                lines[(plane.name, k)] = sorted(evs, key=lambda x: (x[0], -x[1]))
+    return lines
+
+
+def _serve_and_radar(tc):
+    """A tiny serving engine (step, admit, prefill and decode tasks) and a
+    radar session with prefetch, both traced into ``tc``."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serve.session_engine import SessionServeEngine
+
+    cfg = dataclasses.replace(get_config("llama3_8b").smoke(), dtype="float32")
+    params = build_model(cfg).init(jax.random.key(1))
+    with SessionServeEngine(cfg, params, max_batch=2, page_size=8, num_pages=16,
+                            max_pages_per_seq=4, pages_per_group=8) as eng:
+        eng.session.context.set_tracer(tc)
+        eng.submit([3, 5, 7], 3, tenant="a")
+        eng.run()
+    eng.session.runtime.close()
+    sess = make_session(trace=tc, accelerators=("gpu0",))
+    try:
+        submit_2fzf(sess, 64)
+        sess.barrier()
+    finally:
+        sess.close()
+        sess.runtime.close()
+
+
+def _inside(inner, outers):
+    return any(s <= inner[0] and inner[1] <= e for s, e, _, _ in outers)
+
+
+def test_regions_reach_the_profiler_nested_per_thread_with_stats(tmp_path):
+    tc = TraceCollector()
+    lines = _profiled(tmp_path, lambda: _serve_and_radar(tc))
+    events = [ev for evs in lines.values() for ev in evs]
+    names = {ev[2] for ev in events}
+    assert names == {f"rimms.{c}" for c in RIMMS_CATS}
+    for s, e, name, stats in events:
+        assert e >= s
+        if name in ("rimms.stage", "rimms.compute", "rimms.writeback"):
+            assert {"task", "op", "pe"} <= set(stats), (name, stats)
+        elif name in ("rimms.submit", "rimms.qos"):
+            assert {"task", "op", "client"} <= set(stats), (name, stats)
+        elif name == "rimms.copy":
+            assert set(stats) == {"src", "dst", "nbytes"} and stats["nbytes"] > 0
+    assert any(st.get("prefetch") == 1 for _, _, n, st in events if n == "rimms.stage")
+    # task names keep their "#" through the profiler's encoding
+    assert any(st["task"].startswith("prefill#") for _, _, n, st in events
+               if n == "rimms.compute")
+    ops = {st["op"] for _, _, n, st in events if n == "rimms.compute"}
+    assert {"llm_prefill", "llm_decode"} <= ops
+    for evs in lines.values():
+        by = {}
+        for ev in evs:
+            by.setdefault(ev[2], []).append(ev)
+        # nesting within one thread's line
+        for q in by.get("rimms.qos", []):
+            assert _inside(q, by["rimms.submit"])
+        for a in by.get("rimms.admit", []):
+            assert _inside(a, by["rimms.step"])
+        # a PE thread runs one phase at a time (a line is one thread id,
+        # which a later session's thread may reuse)
+        phases = sorted(by.get("rimms.compute", []) + by.get("rimms.writeback", []))
+        assert all(a[1] <= b[0] for a, b in zip(phases, phases[1:]))
+    # the prefill is submitted from inside admission, on the caller's thread
+    prefill = [ev for ev in events if ev[2] == "rimms.submit"
+               and ev[3]["op"] == "llm_prefill"]
+    admits = [ev for ev in events if ev[2] == "rimms.admit"]
+    assert prefill and all(_inside(p, admits) for p in prefill)
+
+
+def test_ring_and_profiler_hold_the_same_spans(tmp_path):
+    tc = TraceCollector()
+    lines = _profiled(tmp_path, lambda: _serve_and_radar(tc))
+    prof = {}
+    for evs in lines.values():
+        for _, _, name, _ in evs:
+            prof[name] = prof.get(name, 0) + 1
+    ring = {}
+    for ph, _, cat, _, _, _, _ in tc.wall_events():
+        if ph == "X" and cat in RIMMS_CATS:
+            ring[f"rimms.{cat}"] = ring.get(f"rimms.{cat}", 0) + 1
+    assert ring == prof
+    # every copy the ledger recorded has its span
+    transfers = [e for e in tc.wall_events() if e[2] == "transfer"]
+    assert ring["rimms.copy"] == len(transfers) > 0
+    assert trace_lint(tc.export()) == []
+
+
+def test_tracing_off_builds_no_annotation_and_writes_no_event(monkeypatch):
+    import jax.profiler
+
+    built = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(*args, **kwargs):
+        built.append(args[0])
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    sess = make_session(accelerators=("gpu0",))
+    paused = TraceCollector()
+    try:
+        assert sess.context.tracer is None
+        submit_2fzf(sess, 64)
+        sess.barrier()
+        # a paused collector: attached, but every boundary is a no-op
+        paused.pause()
+        sess.context.set_tracer(paused)
+        submit_2fzf(sess, 64, seed=1)
+        sess.barrier()
+    finally:
+        sess.close()
+        sess.runtime.close()
+    assert built == []
+    assert paused.wall_events() == []
+    # the patch is live: a traced session builds its annotations with it
+    on = make_session(trace=True, accelerators=("gpu0",))
+    try:
+        submit_2fzf(on, 64)
+        on.barrier()
+    finally:
+        on.close()
+        on.runtime.close()
+    assert {f"rimms.{c}" for c in ("submit", "qos", "stage", "compute",
+                                    "writeback")} <= set(built)
