@@ -479,7 +479,7 @@ class Session:
         self._client_seq = itertools.count()
         self._stream = StreamExecutor(
             runtime, scheduler=scheduler, prefetch=prefetch,
-            on_done=self._node_done, window=window,
+            on_done=self._node_done, window=window, metrics=self.metrics,
         )
         # Submissions mutate the builder's node linkage (deps/dependents)
         # that stream completion iterates: one reentrant lock serializes

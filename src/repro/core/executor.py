@@ -76,6 +76,7 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
 from .graph import TaskGraph, TaskNode, build_graph
 from .hete import PrefetchDeferred
 from .instrument import Timeline, TimelineEvent, TransferEvent
+from .locations import HOST
 
 if TYPE_CHECKING:  # pragma: no cover - typing only, avoids import cycle
     from .runtime import PE, Runtime, Task
@@ -146,15 +147,31 @@ class WorkerPool:
         self.queues[pe_name].put((run, payload))
 
     def _loop(self, pe: "PE") -> None:
+        """Serve ``pe``'s queue.  On an in-process device PE, a worker
+        that takes a payload also takes every payload of that run already
+        queued, without blocking, and hands them over together: a queued
+        task is ready, so they may share kernel launches."""
         q = self.queues[pe.name]
+        held: List[Any] = []  # taken while draining, not of this run
         while True:
-            item = q.get()
+            item = held.pop() if held else q.get()
             if item is _SHUTDOWN:
                 return
             run, payload = item
+            payloads = [payload]
+            if pe.location != HOST and run.rt._in_process(pe):
+                while True:
+                    try:
+                        item = q.get_nowait()
+                    except queue.Empty:
+                        break
+                    if item is _SHUTDOWN or item[0] is not run:
+                        held.append(item)
+                        break
+                    payloads.append(item[1])
             self.active[pe.name] = True
             try:
-                run._process(pe, payload)
+                run._process_ready(pe, payloads)
             finally:
                 self.active[pe.name] = False
 
@@ -201,16 +218,12 @@ def _reap_future(fut: Optional[Future]) -> None:
             pass
 
 
-def _execute_task(rt: "Runtime", task: "Task", pe: "PE",
-                  fut: Optional[Future]) -> tuple:
-    """Authoritative execution of one task on its PE worker thread:
+def _stage_task(rt: "Runtime", task: "Task", pe: "PE",
+                fut: Optional[Future]) -> tuple:
+    """Authoritative staging of one task on its PE worker thread:
     validate/reuse the speculative prefetch staging (pin first, then
-    check eviction epochs), fall back to pinned demand staging, run the
-    kernel, commit outputs, release pins.  Returns
-    ``(w0, w1, tr_s, spill_s, comp_s, out_s, moves)`` — wall bounds plus
-    the modeled accounting both executors feed their schedule
-    simulations."""
-    w0 = time.perf_counter()
+    check eviction epochs), fall back to pinned demand staging.  Returns
+    ``(ins, tr_s, spill_s, moves)`` with the inputs pinned at ``pe``."""
     with rt._region("stage", task, pe):
         pre = fut.result() if fut is not None else None
         loc = pe.location
@@ -233,17 +246,48 @@ def _execute_task(rt: "Runtime", task: "Task", pe: "PE",
             if pre is not None:  # account the wasted warm-up too
                 staged = (staged[0], staged[1] + pre[0][1],
                           staged[2] + pre[0][2], pre[0][3] + staged[3])
-    ins, tr_s, sp_s, moves = staged
-    w_staged = time.perf_counter()
-    try:
-        outs, comp_s = rt._run_kernel(task, pe, ins)
-        out_s, sp2_s = rt._commit_outputs(task, pe, outs)
-    finally:
-        rt._unpin_inputs(task, pe.location)
-    w1 = time.perf_counter()
-    rt.divergence.observe("stage", task.op, pe.kind, task.in_bytes,
-                          w_staged - w0, tr_s + sp_s)
-    return w0, w1, tr_s, sp_s + sp2_s, comp_s, out_s, moves
+    return staged
+
+
+def _execute_batch(rt: "Runtime", items: Sequence[Tuple["Task", Optional[Future]]],
+                   pe: "PE") -> list:
+    """Authoritative execution of ready tasks on their PE worker thread,
+    as one kernel launch: stage each (:func:`_stage_task`), launch them
+    together (:meth:`Runtime._launch`), then commit each task's outputs
+    and release its pins.  A task whose staging, kernel or commit fails
+    fails alone.  Returns, per task, ``(w0, w1, tr_s, spill_s, comp_s,
+    out_s, moves)`` — wall bounds plus the modeled accounting both
+    executors feed their schedule simulations — or the exception that
+    failed it."""
+    results: list = [None] * len(items)
+    staged = []
+    for k, (task, fut) in enumerate(items):
+        w0 = time.perf_counter()
+        try:
+            st = _stage_task(rt, task, pe, fut)
+        except BaseException as e:
+            results[k] = e
+            continue
+        staged.append((k, w0, time.perf_counter(), st))
+    runs = rt._launch([items[k][0] for k, *_ in staged], pe,
+                      [st[0] for *_, st in staged]) if staged else []
+    for (k, w0, w_staged, (_, tr_s, sp_s, moves)), run in zip(staged, runs):
+        task = items[k][0]
+        try:
+            if isinstance(run, BaseException):
+                raise run
+            outs, comp_s = run
+            out_s, sp2_s = rt._commit_outputs(task, pe, outs)
+        except BaseException as e:
+            results[k] = e
+            continue
+        finally:
+            rt._unpin_inputs(task, pe.location)
+        rt.divergence.observe("stage", task.op, pe.kind, task.in_bytes,
+                              w_staged - w0, tr_s + sp_s)
+        results[k] = (w0, time.perf_counter(), tr_s, sp_s + sp2_s, comp_s,
+                      out_s, moves)
+    return results
 
 
 def replay_schedule(rt: "Runtime", nodes: Sequence[TaskNode],
@@ -456,7 +500,7 @@ class _ExecutorBase:
         or None when capacity pressure defers to demand staging (never
         evicting bytes another queued task still reads)."""
         try:
-            with self.rt._region("stage", task, pe, prefetch=True):
+            with self.rt._region("stage", task, pe, prefetch=1):
                 staged = self.rt._stage_inputs(task, pe, prefetch=True)
         except PrefetchDeferred:
             return None
@@ -608,6 +652,12 @@ class GraphExecutor(_ExecutorBase):
             self._pool.submit(self, pe.name, (i, pe, futs.get(i)))
 
     # -- workers ------------------------------------------------------------
+    def _process_ready(self, pe: "PE", payloads: List[tuple]) -> None:
+        """One launch per payload: the batch engine halts at its first
+        failure, so a task queued behind a failed one never runs."""
+        for payload in payloads:
+            self._process(pe, payload)
+
     def _process(self, pe: "PE", payload: tuple) -> None:
         """Execute one queued payload on its PE worker thread.  Called by
         the persistent pool; must never kill the worker thread."""
@@ -627,27 +677,22 @@ class GraphExecutor(_ExecutorBase):
                 return
             i, pe_assigned, fut = payload
             node = self._graph.nodes[i]
-            unprotected = False
+            (res,) = _execute_batch(self.rt, [(node.task, fut)], pe_assigned)
+            # This task no longer reads its inputs: release the queued-reader
+            # claim exactly once, before dependents are scheduled (inside
+            # _complete).
+            self._unprotect(node, pe_assigned)
             try:
-                (w0, w1, tr_s, spill_s, comp_s, out_s, moves) = _execute_task(
-                    self.rt, node.task, pe_assigned, fut
-                )
-                # This task no longer reads its inputs: release the
-                # queued-reader claim exactly once, before dependents are
-                # scheduled (inside _complete).
-                self._unprotect(node, pe_assigned)
-                unprotected = True
+                if isinstance(res, BaseException):
+                    raise res
                 # _complete can itself raise while scheduling newly-ready
                 # dependents (unknown pin, op with no eligible PE) — it
                 # must stay inside the except so the run never hangs.
-                self._complete(node, pe_assigned, w0, w1, tr_s,
-                               spill_s, comp_s, out_s, moves)
+                self._complete(node, pe_assigned, *res)
             except BaseException as e:  # surface to the caller, stop the run
                 with self._lock:
                     if self._error is None:
                         self._error = e
-                if not unprotected:
-                    self._unprotect(node, pe_assigned)
                 self._done.set()
         finally:
             with self._quiet:
@@ -766,6 +811,13 @@ class StreamExecutor(_ExecutorBase):
       the stream lock at every completion or failure, lets the session
       resolve futures and release buffer lifecycles out of order, as
       tasks actually finish.
+    * **launch batching**: on an in-process device PE the worker takes
+      every ready payload queued for it at once, and their tasks share
+      one kernel launch — each task's kernel dispatched, one wait for
+      the device (:meth:`_process_ready`).  Every task keeps its own
+      stage, compute and write-back regions and its own failure;
+      ``metrics`` counts ``launch/<pe>/launches`` and
+      ``launch/<pe>/tasks``.
 
     Modeled evidence: online accounting mirrors the batch engine
     (per-PE model clocks, task log, timeline events); :meth:`report`
@@ -782,9 +834,12 @@ class StreamExecutor(_ExecutorBase):
         prefetch: bool = True,
         on_done: Optional[Callable[[int, Optional[BaseException]], None]] = None,
         window: int = 64,
+        metrics=None,
     ) -> None:
         super().__init__(rt, scheduler=scheduler, prefetch=prefetch)
         self.window = window
+        #: the session's MetricsRegistry, or None
+        self.metrics = metrics
         self._on_done = on_done
         # Reentrant: the session serializes GraphBuilder mutations under
         # this same lock (see state_lock) and admit() re-enters it.
@@ -933,24 +988,40 @@ class StreamExecutor(_ExecutorBase):
             n.rank = cm.mean_estimate(n.task.op, kinds, n.task.in_bytes) + succ
 
     # -- workers ------------------------------------------------------------
-    def _process(self, pe: "PE", payload: tuple) -> None:
-        """Execute one payload on its PE worker thread.  Unlike the
-        batch engine, a peer's failure does not drain the stream — only
-        the failing task's dependent subtree is failed."""
-        i, pe_assigned, fut = payload
+    def _process_ready(self, pe: "PE", payloads: List[tuple]) -> None:
+        """Execute the ready payloads ``pe``'s worker took at once, in
+        order, as kernel launches that each fit ``pe``'s arena
+        (:meth:`Runtime.batch_fits`)."""
+        while payloads:
+            n = self.rt.batch_fits(
+                [self._nodes[i].task for i, _, _ in payloads], pe)
+            self._process(pe, payloads[:n])
+            payloads = payloads[n:]
+
+    def _process(self, pe: "PE", payloads: List[tuple]) -> None:
+        """Execute payloads as one launch on their PE worker thread.
+        Unlike the batch engine, a peer's failure does not drain the
+        stream — only the failing task's dependent subtree is failed.  A
+        launch that completed tasks counts in :attr:`metrics`."""
         if self._closed:
-            self._abandon(payload)
+            for payload in payloads:
+                self._abandon(payload)
             return
-        node = self._nodes[i]
-        try:
-            result = _execute_task(self.rt, node.task, pe_assigned, fut)
-        except BaseException as e:
+        nodes = [self._nodes[i] for i, _, _ in payloads]
+        results = _execute_batch(
+            self.rt, [(node.task, fut) for node, (_, _, fut)
+                      in zip(nodes, payloads)], pe)
+        done = sum(not isinstance(r, BaseException) for r in results)
+        if done and self.metrics is not None and self.rt._in_process(pe):
+            self.metrics.counter(f"launch/{pe.name}/launches").inc()
+            self.metrics.counter(f"launch/{pe.name}/tasks").inc(done)
+        for node, (i, pe_assigned, _), res in zip(nodes, payloads, results):
             self._unprotect(node, pe_assigned)
-            with self._cv:
-                self._fail_node(i, e, root=True)
-            return
-        self._unprotect(node, pe_assigned)
-        self._complete(node, pe_assigned, *result)
+            if isinstance(res, BaseException):
+                with self._cv:
+                    self._fail_node(i, res, root=True)
+            else:
+                self._complete(node, pe_assigned, *res)
 
     def _fail_node(self, i: int, exc: BaseException, *, root: bool) -> None:
         """Mark node ``i`` failed and cascade to its admitted dependent

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import os
 import time
 import warnings
@@ -150,6 +151,10 @@ def _register_builtin_platforms() -> None:
         )
 
 SCHEDULERS = ("round_robin", "data_affinity", "heft")
+
+#: kernel-launch ids, unique in the process: runtimes traced into one
+#: collector may name their PEs alike
+_LAUNCH_IDS = itertools.count(1)
 
 
 @dataclasses.dataclass
@@ -406,7 +411,7 @@ class Runtime:
         Demand mode (default): inputs stay hard-pinned at ``pe`` until
         :meth:`_unpin_inputs` — callers release after commit.  Only one
         PE worker reserves per arena, so pinned bytes are bounded by one
-        task's working set.
+        launch's working set (:meth:`batch_fits`).
 
         Prefetch mode: *speculative warming* — runs under the context's
         prefetch guard (raises :class:`~repro.core.hete.PrefetchDeferred`
@@ -461,34 +466,80 @@ class Runtime:
         space = self.context.spaces.get(pe.location)
         return space is not None and getattr(space, "proc_exec", False)
 
+    def _in_process(self, pe: PE) -> bool:
+        return not (self.backend == "process" and self._proc_eligible(pe))
+
     def _run_kernel(self, task: Task, pe: PE, ins: List[Any]) -> Tuple[tuple, float]:
-        """Execute the kernel; returns (outputs, measured seconds).  Blocks
-        async (JAX) dispatch so timings feed the cost model honestly.
+        """Call one task's kernel inside a :meth:`_launch`, its one
+        caller; returns (outputs, seconds).  In process the call returns
+        once the kernel is dispatched — JAX outputs may still be
+        computing, the launch waits for them and times itself — and the
+        seconds are 0.
 
         Backend dispatch (ISSUE 7): under ``backend="process"`` the call
         runs on ``pe``'s subprocess worker — shared-memory inputs map
         zero-copy, the parent thread blocks GIL-free on the reply — for
-        every PE whose space holds host payloads; other PEs (real JAX
-        devices) execute in-process as before."""
-        with self._region("compute", task, pe):
-            if self.backend == "process" and self._proc_eligible(pe):
-                outs, dt = self._run_kernel_process(task, pe, ins)
-            else:
-                fn, params, _ = self._select_kernel(task, pe)
-                t0 = time.perf_counter()
-                outs = _as_tuple(fn(ins, **params))
-                if pe.location != HOST:
-                    try:
-                        import jax
-                        outs = tuple(jax.block_until_ready(o) for o in outs)
-                    except ImportError:  # pragma: no cover - jax is baked in
-                        pass
-                dt = time.perf_counter() - t0
-                self.cost_model.observe(task.op, pe.kind, task.in_bytes, dt)
-        self.divergence.observe(
-            "compute", task.op, pe.kind, task.in_bytes, dt,
-            self.cost_model.prior_estimate(task.op, pe.kind, task.in_bytes))
-        return outs, dt
+        every PE whose space holds host payloads, and the seconds are the
+        worker's; other PEs (real JAX devices) execute in-process."""
+        if not self._in_process(pe):
+            return self._run_kernel_process(task, pe, ins)
+        fn, params, _ = self._select_kernel(task, pe)
+        return _as_tuple(fn(ins, **params)), 0.0
+
+    def batch_fits(self, tasks: Sequence[Task], pe: PE) -> int:
+        """How many of ``tasks``, taken in order, one launch on ``pe``
+        carries: as many as ``pe``'s arena holds without evicting — the
+        roots their inputs and outputs would newly reserve fit in its
+        free bytes.  The first task always goes."""
+        n = len(tasks)
+        space = self.context.spaces.get(pe.location)
+        if space is None or space.arena is None:
+            return n
+        free = space.arena.free_bytes
+        seen: set = set()
+        for k, task in enumerate(tasks):
+            for hd in task.inputs + task.outputs:
+                root = hd.root
+                if id(root) not in seen and pe.location not in root.extents:
+                    seen.add(id(root))
+                    free -= root.nbytes
+            if free < 0 and k > 0:
+                return k
+        return n
+
+    def _launch(self, tasks: Sequence[Task], pe: PE,
+                ins_list: Sequence[List[Any]]) -> list:
+        """One kernel launch on ``pe`` carrying ``tasks`` with their
+        staged ``ins_list``: each task's ``compute`` region is open
+        around it, with the launch's id and size; each task's kernel is
+        called (:meth:`_run_kernel`), then an in-process device PE waits
+        once for all their outputs.  Returns, per task, ``(outputs,
+        seconds)`` — in process, the launch's seconds over its number of
+        tasks — or the exception that failed that task alone.  Those
+        seconds feed the cost model and the divergence monitor."""
+        n = len(tasks)
+        launch = next(_LAUNCH_IDS)
+        in_process = self._in_process(pe)
+        with contextlib.ExitStack() as regions:
+            for task in tasks:
+                regions.enter_context(self._region(
+                    "compute", task, pe, launch=launch, batch=n))
+            t0 = time.perf_counter()
+            runs = [_attempt(self._run_kernel, task, pe, ins)
+                    for task, ins in zip(tasks, ins_list)]
+            if in_process and pe.location != HOST:
+                runs = _wait_for_device(runs)
+            dt = (time.perf_counter() - t0) / n
+        for k, (task, run) in enumerate(zip(tasks, runs)):
+            if isinstance(run, BaseException):
+                continue
+            if in_process:
+                run = runs[k] = (run[0], dt)
+            self.cost_model.observe(task.op, pe.kind, task.in_bytes, run[1])
+            self.divergence.observe(
+                "compute", task.op, pe.kind, task.in_bytes, run[1],
+                self.cost_model.prior_estimate(task.op, pe.kind, task.in_bytes))
+        return runs
 
     def _select_kernel(self, task: Task, pe: PE) -> Tuple[Callable, dict, str]:
         """Variant-aware kernel lookup (ISSUE 10): the attached
@@ -521,7 +572,6 @@ class Runtime:
         worker.ensure_kernel(key, fn)
         outs, w0, w1, k0, k1 = worker.run(key, ins, params)
         dt = w1 - w0
-        self.cost_model.observe(task.op, pe.kind, task.in_bytes, dt)
         tracer = self.context.tracer
         if tracer is not None:
             tracer.forward_span(
@@ -554,20 +604,19 @@ class Runtime:
                     ctx.mark_written(hd, loc, val)
         return model_s, ctx.take_spill_seconds()
 
-    def _region(self, cat: str, task: Task, pe: PE, prefetch: bool = False):
+    def _region(self, cat: str, task: Task, pe: PE, **stats):
         """The tracer's region for one phase of ``task`` on ``pe`` —
-        ``stage``, ``compute`` or ``writeback``, on the PE's track — or
-        the shared null context when tracing is off.  Both executors
-        and the serial path enter it."""
+        ``stage``, ``compute`` or ``writeback``, on the PE's track, with
+        ``stats`` beside the task's own (``prefetch=1``; a compute's
+        ``launch`` and ``batch``) — or the shared null context when
+        tracing is off.  Both executors and the serial path enter it."""
         tracer = self.context.tracer
         if tracer is None:
             return NULL_REGION
         tname = task.name or task.op
         track = f"pe:{pe.name}:stage" if cat == "stage" else f"pe:{pe.name}"
-        if prefetch:
-            return tracer.region(tname, cat, track, task=tname, op=task.op,
-                                 pe=pe.name, prefetch=1)
-        return tracer.region(tname, cat, track, task=tname, op=task.op, pe=pe.name)
+        return tracer.region(tname, cat, track, task=tname, op=task.op,
+                             pe=pe.name, **stats)
 
     def _add_transfer_lanes(self, topo, task: Task, moves: Sequence[tuple],
                             start: float, node: int = -1) -> float:
@@ -629,7 +678,10 @@ class Runtime:
                 ins, tr_s, sp_s, moves = self._stage_inputs(task, pe)
             w_staged = time.perf_counter()
             try:
-                outs, comp_s = self._run_kernel(task, pe, ins)
+                (run,) = self._launch([task], pe, [ins])
+                if isinstance(run, BaseException):
+                    raise run
+                outs, comp_s = run
                 out_s, sp2_s = self._commit_outputs(task, pe, outs)
             finally:
                 self._unpin_inputs(task, pe.location)
@@ -711,6 +763,33 @@ class Runtime:
         report = ex.run(tasks)
         self.last_report = report
         return report["wall_s"]
+
+
+def _attempt(fn: Callable, *args) -> Any:
+    """``fn(*args)``, or the exception it raised."""
+    try:
+        return fn(*args)
+    except Exception as e:
+        return e
+
+
+def _wait_for_device(runs: list) -> list:
+    """Wait once for the outputs of every ``(outputs, seconds)`` in
+    ``runs``; if that wait raises, wait for each alone, so that only the
+    runs whose outputs failed become their exception."""
+    import jax
+
+    def ready(run: tuple) -> tuple:
+        jax.block_until_ready(run[0])
+        return run
+
+    try:
+        jax.block_until_ready([r[0] for r in runs
+                               if not isinstance(r, BaseException)])
+        return runs
+    except Exception:
+        return [r if isinstance(r, BaseException) else _attempt(ready, r)
+                for r in runs]
 
 
 def _as_tuple(x: Any) -> tuple:

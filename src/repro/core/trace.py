@@ -847,7 +847,9 @@ def trace_lint(trace_or_path: Union[dict, str], eps: float = 1e-9) -> List[str]:
     1. well-formedness — every complete span has ``dur >= 0``;
     2. exclusivity — spans on exclusive resource tracks (categories
        ``compute``/``writeback``/``transfer``) never overlap within a
-       track (``eps`` microseconds of float tolerance);
+       track (``eps`` microseconds of float tolerance); the compute
+       spans of the tasks one kernel launch carried (the same
+       ``args.launch``) hold the track together, as one span;
     3. conservation — wall transfer events in the current ledger epoch
        sum *exactly* (count and bytes per link) to the embedded
        ``TransferLedger`` per-link counters, net of the pre-attach
@@ -879,9 +881,23 @@ def trace_lint(trace_or_path: Union[dict, str], eps: float = 1e-9) -> List[str]:
 
     # 2. per-track exclusivity for resource categories
     by_track: Dict[Tuple[int, int], List[dict]] = {}
+    launches: Dict[tuple, dict] = {}  # one span per shared launch
     for e in spans:
-        if e.get("cat") in EXCLUSIVE_CATS:
-            by_track.setdefault((e.get("pid"), e.get("tid")), []).append(e)
+        if e.get("cat") not in EXCLUSIVE_CATS:
+            continue
+        track = (e.get("pid"), e.get("tid"))
+        launch = e.get("args", {}).get("launch")
+        if launch is None:
+            by_track.setdefault(track, []).append(e)
+            continue
+        held = launches.get(track + (launch,))
+        if held is None:
+            held = launches[track + (launch,)] = dict(e)
+            by_track.setdefault(track, []).append(held)
+        else:
+            end = max(held["ts"] + held.get("dur", 0), e["ts"] + e.get("dur", 0))
+            held["ts"] = min(held["ts"], e["ts"])
+            held["dur"] = end - held["ts"]
     names = {
         (e.get("pid"), e.get("tid")): e.get("args", {}).get("name", "?")
         for e in events
