@@ -4,9 +4,22 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
-__all__ = ["ArchConfig", "ShapeSpec", "SHAPES", "get_config", "list_archs", "cells_for"]
+__all__ = ["ArchConfig", "YarnRope", "ShapeSpec", "SHAPES", "get_config", "list_archs",
+           "cells_for"]
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN scaling of rotary frequencies, with the fields of a
+    ``rope_type: "yarn"`` entry of a Hugging Face config."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,6 +52,11 @@ class ArchConfig:
     # hybrid / ssm block structure; () → all attention blocks
     block_pattern: Tuple[str, ...] = ()
     window: int = 0  # local-attention window (0 = full causal)
+    # per-layer attention kind, "sliding_attention" (``window`` keys) or
+    # "full_attention", in published order; () → the family's default
+    layer_types: Tuple[str, ...] = ()
+    # YaRN on full-attention layers (sliding layers keep plain RoPE)
+    full_rope_yarn: Optional[YarnRope] = None
     conv_width: int = 4
     # numerics
     dtype: str = "bfloat16"

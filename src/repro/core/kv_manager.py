@@ -40,6 +40,12 @@ class KVManager:
     pages_per_group`` groups; group ``g`` holds pages ``[g * gp, (g + 1)
     * gp)``.  Each group is two Session buffers (K and V) of shape
     ``(n_layers, pages_per_group, page_size, kv_heads, head_dim)``.
+
+    A model whose layers keep different KV state has one manager per
+    layer type, each with its own pages, block tables, quotas and
+    scratch page: ``ring > 0`` makes this one a sliding-window pool whose
+    sequences each hold a fixed ring of ``ring`` pages
+    (:class:`~repro.core.paged_kv.PagedKVPool`).
     """
 
     def __init__(
@@ -55,6 +61,7 @@ class KVManager:
         dtype=np.float32,
         allocator: str = "bitset",
         owner: str = "kv-cache",
+        ring: int = 0,
     ) -> None:
         if num_pages % pages_per_group != 0:
             raise ValueError(
@@ -68,7 +75,7 @@ class KVManager:
         self.n_groups = num_pages // pages_per_group
         self.pool = PagedKVPool(
             num_pages=num_pages, page_size=page_size,
-            allocator=allocator, scratch=True,
+            allocator=allocator, scratch=True, ring=ring,
         )
         shape = (n_layers, pages_per_group, page_size, kv_heads, head_dim)
         # hete_Malloc zeroes the host planes, matching init_pool_arrays.

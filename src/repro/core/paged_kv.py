@@ -35,6 +35,7 @@ from .qos import QuotaExceeded
 __all__ = [
     "PagedKVPool",
     "SCRATCH_SEQ",
+    "ring_pages",
     "init_pool_arrays",
     "write_token",
     "gather_kv",
@@ -44,6 +45,13 @@ __all__ = [
 #: slots and block-table padding point at it so full-batch scatter/gather
 #: kernels never touch live pages.
 SCRATCH_SEQ = -1
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages of a sliding-window layer's ring: the window's pages plus
+    one, so that the page being written never holds a key still inside
+    the window."""
+    return -(-window // page_size) + 1
 
 
 @dataclasses.dataclass
@@ -63,6 +71,11 @@ class PagedKVPool:
     tenant.  Per-tenant page quotas (``set_quota``) turn over-budget
     allocations into :class:`~repro.core.qos.QuotaExceeded` instead of
     silently eating the shared pool.
+
+    With ``ring > 0`` the pool backs sliding-window layers: every
+    sequence holds exactly ``ring`` pages from admission to completion,
+    whatever its length, and its token at position ``p`` goes to ring
+    slot ``p mod (ring * page_size)`` (:func:`write_token` of that slot).
     """
 
     def __init__(
@@ -72,9 +85,11 @@ class PagedKVPool:
         page_size: int,
         allocator: str = "bitset",
         scratch: bool = False,
+        ring: int = 0,
     ) -> None:
         self.num_pages = num_pages
         self.page_size = page_size
+        self.ring = ring
         # Arena in units of pages: block_size=1 page.
         self.arena = make_allocator(allocator, capacity=num_pages, block_size=1)
         self._seqs: Dict[int, _SeqInfo] = {}
@@ -116,10 +131,13 @@ class PagedKVPool:
     def alloc_sequence(
         self, seq_id: int, n_tokens: int, *, tenant: Optional[str] = None
     ) -> np.ndarray:
-        """Reserve pages for ``n_tokens`` tokens; returns int32 page ids."""
+        """Reserve pages for ``n_tokens`` tokens (a ring pool: its ring,
+        except for the one-page scratch sequence); returns int32 page ids."""
         if seq_id in self._seqs:
             raise KeyError(f"sequence {seq_id} already allocated")
         n_pages = max(1, -(-n_tokens // self.page_size))
+        if self.ring and seq_id != SCRATCH_SEQ:
+            n_pages = self.ring
         self._charge(tenant, n_pages)  # quota check before touching arena
         try:
             extents, page_ids = self._grab(n_pages)
@@ -134,6 +152,8 @@ class PagedKVPool:
         """Grow a sequence (decode appends); returns the full page table."""
         info = self._seqs[seq_id]
         need = -(-(info.n_tokens + n_new_tokens) // self.page_size)
+        if self.ring:
+            need = self.ring  # a ring never grows
         if need > len(info.page_ids):
             grow = need - len(info.page_ids)
             self._charge(info.tenant, grow)

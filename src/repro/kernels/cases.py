@@ -10,7 +10,9 @@ of ``chip_smoke.py``, which runs each case on the chip against its
 * ``flash_attention``/``paged_attention``: yi-9b (32 query heads, 4 KV
   heads, head_dim 128, bf16; 16-token pages);
 * ``rg_lru``: recurrentgemma-2b's recurrence width (2560);
-* ``mlstm``: xlstm-350m's heads (4 heads of 256, chunk 64).
+* ``mlstm``: xlstm-350m's heads (4 heads of 256, chunk 64);
+* ``moe``: Mellum2-12B-A2.5B's routed experts (64 of hidden 2,304 and
+  width 896, bf16) for a decode batch of 16, 8 experts a row.
 """
 
 from __future__ import annotations
@@ -147,10 +149,36 @@ def _mlstm_case() -> KernelCase:
                       make, ref.mlstm_sequential, 1e-2)
 
 
+def _moe_case() -> KernelCase:
+    from .moe import ref
+    from .moe.moe import moe_experts
+
+    B, D, F, E, K = 16, 2304, 896, 64, 8
+
+    def make(rng):
+        comb = np.zeros((B, E), np.float32)
+        for b in range(B):
+            comb[b, rng.choice(E, K, replace=False)] = rng.dirichlet(np.ones(K))
+        hit = comb.sum(0) > 0
+        ids = np.concatenate([np.flatnonzero(hit), np.full(E - hit.sum(), np.flatnonzero(hit)[-1])])
+        return [_normal(rng, (B, D), BF16), comb, ids.astype(np.int32),
+                np.array([hit.sum()], np.int32),
+                _normal(rng, (E, D, F), BF16, 1 / math.sqrt(D)),
+                _normal(rng, (E, D, F), BF16, 1 / math.sqrt(D)),
+                _normal(rng, (E, F, D), BF16, 1 / math.sqrt(F))]
+
+    # bf16 weights and activations, f32 accumulation; the kernel rounds
+    # the SwiGLU product to bf16 before the down projection
+    return KernelCase("moe", moe_experts,
+                      (((B, D), BF16), ((B, E), F32), ((E,), I32), ((1,), I32),
+                       ((E, D, F), BF16), ((E, D, F), BF16), ((E, F, D), BF16)),
+                      make, ref.moe_experts, 2e-2)
+
+
 def cases() -> List[KernelCase]:
     """Every Pallas kernel in this package, once per deployed shape."""
     return [_fft_case(512, 256), _fft_case(256, 512), _zip_case(),
-            _flash_case(), _paged_case(), _rg_lru_case(), _mlstm_case()]
+            _flash_case(), _paged_case(), _rg_lru_case(), _mlstm_case(), _moe_case()]
 
 
 def max_rel_error(got, want) -> float:

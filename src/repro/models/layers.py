@@ -94,13 +94,47 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
 
 
-def apply_rope(x, pos, theta: float):
-    """x: (..., S, H, D); pos: broadcastable to (..., S)."""
+def yarn_freqs(head_dim: int, theta: float, yarn):
+    """YaRN's frequencies and its cos/sin scale (``attention_factor``),
+    as transformers' ``_compute_yarn_parameters`` computes them: each
+    frequency blends the plain one with the plain one over ``factor``,
+    by a linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position``."""
+    def correction_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low) / (high - low), 0, 1)
+    extrapolation = 1.0 - ramp
+    plain = rope_freqs(head_dim, theta)
+    freqs = plain / yarn.factor * (1 - extrapolation) + plain * extrapolation
+    return freqs, yarn.attention_factor
+
+
+def layer_rope(cfg, layer_type: str):
+    """(frequencies, cos/sin scale) of one layer type's rotary embedding."""
+    if layer_type == "full_attention" and cfg.full_rope_yarn is not None:
+        return yarn_freqs(cfg.head_dim_, cfg.rope_theta, cfg.full_rope_yarn)
+    return rope_freqs(cfg.head_dim_, cfg.rope_theta), 1.0
+
+
+def apply_rope(x, pos, theta: float, *, freqs=None, scale: float = 1.0):
+    """x: (..., S, H, D); pos: broadcastable to (..., S).  ``freqs``
+    replaces the plain frequencies of ``theta``; ``scale`` multiplies
+    cos and sin (YaRN's attention factor)."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(d, theta), jnp.float32)
+    if freqs is None:
+        freqs = rope_freqs(d, theta)
+    freqs = jnp.asarray(freqs, jnp.float32)
     angles = pos[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     angles = angles[..., None, :]  # broadcast over heads
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

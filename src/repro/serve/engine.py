@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.paged_kv import PagedKVPool, init_pool_arrays, write_token
+from repro.kernels.moe import ops as moe_ops
 from repro.kernels.paged_attention import ref as pa_ref
 from repro.models import layers as L
 
@@ -187,3 +188,60 @@ def _paged_decode_step(cfg, params, k_pools, v_pools, block_tables,
     logits = L.lm_logits(cfg, params["embed"], x)
     nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
     return nxt, jnp.stack(new_k), jnp.stack(new_v)
+
+
+def _paged_hybrid_step(cfg, params, k_full, v_full, k_win, v_win, full_tables,
+                       win_tables, tokens, pos, lengths):
+    """One batched paged decode step for sliding-window / full attention
+    layers with sparse-expert MLPs (family ``swa_moe``).
+
+    ``params["layers"]`` holds one dict per layer, so no layer's weights
+    are sliced out of a stack.  Full layers keep every key in
+    ``k_full``/``v_full`` (one plane per full layer) under
+    ``full_tables``; sliding layers keep a ring of pages in
+    ``k_win``/``v_win`` under ``win_tables``.  Rows with ``lengths`` 0
+    are inactive: their writes land in the scratch pages and the router
+    sends them nowhere.  Returns the next tokens, the (layers, experts)
+    count of active rows routed to each expert, and the new planes."""
+    x = L.embed_tokens(cfg, params["embed"], tokens[:, None])
+    dims = L.attn_dims(cfg)
+    active = lengths > 0
+    ring = win_tables.shape[1] * k_win.shape[2]
+    kv_out = {"full_attention": ([], []), "sliding_attention": ([], [])}
+    counts = []
+    for li, p in enumerate(params["layers"]):
+        kind = cfg.layer_types[li]
+        h = L.norm_apply(cfg, p["norm1"], x)
+        q, k, v = L._project_qkv(cfg, p["attn"], h, pos[:, None], rope=False)
+        freqs, scale = L.layer_rope(cfg, kind)
+        q = L.apply_rope(q, pos[:, None], cfg.rope_theta, freqs=freqs, scale=scale)
+        k = L.apply_rope(k, pos[:, None], cfg.rope_theta, freqs=freqs, scale=scale)
+        q = q[:, 0].reshape(q.shape[0], dims.n_q, dims.head_dim)
+        new_k, new_v = kv_out[kind]
+        i = len(new_k)
+        if kind == "full_attention":
+            with jax.named_scope("attn_full"):
+                kp = write_token(k_full[i], full_tables, pos, k[:, 0])
+                vp = write_token(v_full[i], full_tables, pos, v[:, 0])
+                attn = pa_ref.paged_attention(q, kp, vp, full_tables, lengths)
+        else:
+            with jax.named_scope("attn_window"):
+                kp = write_token(k_win[i], win_tables, pos % ring, k[:, 0])
+                vp = write_token(v_win[i], win_tables, pos % ring, v[:, 0])
+                attn = pa_ref.paged_window_attention(q, kp, vp, win_tables, pos,
+                                                     lengths, cfg.window)
+        new_k.append(kp)
+        new_v.append(vp)
+        attn = attn.reshape(x.shape[0], 1, dims.n_q * dims.head_dim)
+        x = x + attn @ p["attn"]["wo"].astype(x.dtype)
+        h = L.norm_apply(cfg, p["norm2"], x)
+        with jax.named_scope("moe"):
+            y, c = moe_ops.moe_mlp(h[:, 0], p["moe"], cfg.top_k, active)
+        x = x + y[:, None]
+        counts.append(c)
+    x = L.norm_apply(cfg, params["final_norm"], x)
+    logits = L.lm_logits(cfg, params["embed"], x)
+    nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+    full, win = kv_out["full_attention"], kv_out["sliding_attention"]
+    return (nxt, jnp.stack(counts), jnp.stack(full[0]), jnp.stack(full[1]),
+            jnp.stack(win[0]), jnp.stack(win[1]))

@@ -27,6 +27,13 @@ submission order: the per-tenant masked sub-steps write the same values
 into the same pages (KV entries are deterministic, idempotent functions
 of ``(token, position, params)``, and every per-row output depends only
 on that row's inputs plus its own gathered pages).
+
+A model with sliding-window and full attention layers and sparse-expert
+MLPs (family ``swa_moe``) runs through the same loop with two KV
+managers: full layers page the whole sequence, sliding layers hold a
+fixed ring of pages per sequence.  Its decode and prefill tasks carry
+both pools' page groups and block tables, and each also returns the
+count of tokens routed to each expert of each layer.
 """
 
 from __future__ import annotations
@@ -42,12 +49,17 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.api import OpRegistry, Session
 from repro.core.kv_manager import KVManager
+from repro.core.paged_kv import ring_pages
 from repro.core.trace import NULL_REGION
 from repro.models import layers as L
 
-from .engine import SUPPORTED_FAMILIES, Request, _paged_decode_step
+from .engine import SUPPORTED_FAMILIES, Request, _paged_decode_step, _paged_hybrid_step
 
-__all__ = ["SessionServeEngine", "TenantRequest"]
+__all__ = ["SessionServeEngine", "TenantRequest", "SESSION_FAMILIES"]
+
+#: families the Session engine serves: the paged engines' dense ones,
+#: and sliding-window / full attention with sparse experts
+SESSION_FAMILIES = SUPPORTED_FAMILIES + ("swa_moe",)
 
 
 @dataclasses.dataclass
@@ -73,6 +85,27 @@ def _jit_grouped_step(cfg: ArchConfig, n_groups: int):
         cuts = [gp * i for i in range(1, n_groups)]
         return (nxt, tuple(jnp.split(k_pool, cuts, axis=1)),
                 tuple(jnp.split(v_pool, cuts, axis=1)))
+
+    return jax.jit(serve_step)
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_hybrid_step(cfg: ArchConfig, n_full: int, n_win: int):
+    """:func:`_jit_grouped_step` for family ``swa_moe``: the full and the
+    window pool each concatenated from their groups, one step, split
+    back; also returns the step's (layers, experts) routing counts."""
+
+    def serve_step(params, kf, vf, kw, vw, full_tables, win_tables, tokens, pos,
+                   lengths):
+        nxt, counts, *pools = _paged_hybrid_step(
+            cfg, params, jnp.concatenate(kf, axis=1), jnp.concatenate(vf, axis=1),
+            jnp.concatenate(kw, axis=1), jnp.concatenate(vw, axis=1),
+            full_tables, win_tables, tokens, pos, lengths)
+        out = []
+        for pool, n, groups in zip(pools, (n_full, n_full, n_win, n_win), (kf, vf, kw, vw)):
+            cuts = [groups[0].shape[1] * i for i in range(1, n)]
+            out.append(tuple(jnp.split(pool, cuts, axis=1)))
+        return (nxt, counts, *out)
 
     return jax.jit(serve_step)
 
@@ -103,10 +136,11 @@ class SessionServeEngine:
                  kv_owner: str = "kv-cache",
                  decode_weight: float = 4.0, decode_window: int = 4,
                  prefill_weight: float = 1.0, prefill_window: int = 8):
-        if cfg.family not in SUPPORTED_FAMILIES:
+        if cfg.family not in SESSION_FAMILIES:
             raise ValueError(
-                f"serve engine supports full-attention dense decoder "
-                f"families {SUPPORTED_FAMILIES}, got {cfg.family!r}"
+                f"session serve engine supports the dense decoder families "
+                f"{SUPPORTED_FAMILIES} and {SESSION_FAMILIES[len(SUPPORTED_FAMILIES):]}, "
+                f"got {cfg.family!r}"
             )
         self.cfg = cfg
         self.params = params
@@ -133,18 +167,43 @@ class SessionServeEngine:
                                    extend_supports=("cpu", "gpu"))
             self._owns_session = False
         self.session = session
+        self.hybrid = cfg.family == "swa_moe"
+        kinds = cfg.layer_types[:cfg.n_layers] if self.hybrid else ()
         self.kv = KVManager(
-            session, n_layers=cfg.n_layers, kv_heads=cfg.n_kv_heads,
+            session, n_layers=kinds.count("full_attention") if self.hybrid else cfg.n_layers,
+            kv_heads=cfg.n_kv_heads,
             head_dim=cfg.head_dim_, num_pages=num_pages,
             page_size=page_size, pages_per_group=pages_per_group,
             dtype=L.cdtype(cfg), allocator=allocator, owner=kv_owner,
         )
+        #: one KV manager per layer type (full first), and their tables
+        self.kvs = [self.kv]
+        if self.hybrid:
+            ring = ring_pages(cfg.window, page_size)
+            window_pages = max_batch * ring + 1  # every slot's ring and the scratch page
+            self.kv_window = KVManager(
+                session, n_layers=kinds.count("sliding_attention"),
+                kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim_,
+                num_pages=window_pages, page_size=page_size,
+                pages_per_group=window_pages, dtype=L.cdtype(cfg),
+                allocator=allocator, owner=f"{kv_owner}-window", ring=ring,
+            )
+            self.kvs.append(self.kv_window)
+            self.window_tables = np.full(
+                (max_batch, ring), self.kv_window.scratch_page, np.int32)
+            self._ring_tokens = ring * page_size
+            #: routing of the last ``step()``: ``"prefill"``, a (prompt
+            #: length, (layers, experts) counts) pair per prompt taken
+            #: in; ``"decode"``, the counts summed over its sub-steps
+            self.last_routing = {"prefill": [], "decode": None}
         self._prefill_client = session.client(
             "prefill", weight=prefill_weight, window=prefill_window)
         self._tenants: Dict[str, object] = {}  # name -> SessionClient
 
         self.block_tables = np.full(
             (max_batch, max_pages_per_seq), self.kv.scratch_page, np.int32)
+        self.tables = [self.block_tables] + ([self.window_tables] if self.hybrid else [])
+        self._pending_prefill = []  # (prompt length, routing-count future)
         self.slot_req: List[Optional[TenantRequest]] = [None] * max_batch
         self.slot_pos = np.zeros((max_batch,), np.int32)
         self.slot_tok = np.zeros((max_batch,), np.int32)
@@ -152,33 +211,53 @@ class SessionServeEngine:
         self.waiting: List[TenantRequest] = []
 
     # -- kernels -------------------------------------------------------------
-    def _register_kernels(self) -> None:
-        cfg = self.cfg
+    def _run_step(self, tables, groups, tokens, pos, lengths):
+        """One step program over every pool: ``tables`` one block table
+        per pool, ``groups`` its K groups then its V groups.  Returns
+        the next tokens, the routing counts (None for a dense model) and
+        the new groups in the order given."""
+        if not self.hybrid:
+            (k_groups, v_groups), = groups
+            nxt, k_groups, v_groups = _jit_grouped_step(self.cfg, len(k_groups))(
+                self.params, k_groups, v_groups, tables[0], tokens, pos, lengths)
+            return nxt, None, [(k_groups, v_groups)]
+        (kf, vf), (kw, vw) = groups
+        nxt, counts, kf, vf, kw, vw = _jit_hybrid_step(self.cfg, len(kf), len(kw))(
+            self.params, kf, vf, kw, vw, tables[0], tables[1], tokens, pos, lengths)
+        return nxt, counts, [(kf, vf), (kw, vw)]
 
+    @staticmethod
+    def _split_groups(bufs, n_groups):
+        """Kernel inputs (each pool's K groups, then its V groups) as a
+        (K groups, V groups) pair per pool."""
+        out, i = [], 0
+        for n in n_groups:
+            out.append((tuple(bufs[i:i + n]), tuple(bufs[i + n:i + 2 * n])))
+            i += 2 * n
+        return out
+
+    def _register_kernels(self) -> None:
         def decode_kernel(ins, *, mask, n_groups):
-            tokens, pos, tables = ins[0], ins[1], ins[2]
-            k_groups = tuple(ins[3:3 + n_groups])
-            v_groups = tuple(ins[3 + n_groups:3 + 2 * n_groups])
+            tokens, pos = ins[0], ins[1]
+            tables = ins[2:2 + len(n_groups)]
+            groups = self._split_groups(ins[2 + len(n_groups):], n_groups)
             lengths = jnp.where(
                 jnp.asarray(mask, bool), jnp.asarray(pos) + 1, 0
             ).astype(jnp.int32)
-            step = _jit_grouped_step(cfg, n_groups)
-            nxt, k_groups, v_groups = step(
-                self.params, k_groups, v_groups, tables,
-                jnp.asarray(tokens, jnp.int32), jnp.asarray(pos, jnp.int32),
-                lengths,
-            )
-            return (nxt, *k_groups, *v_groups)
+            nxt, counts, groups = self._run_step(
+                tables, groups, jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(pos, jnp.int32), lengths)
+            routed = () if counts is None else (counts,)
+            return (nxt, *routed, *(g for kv in groups for g in kv[0] + kv[1]))
 
         def prefill_kernel(ins, *, slot, prompt, base_toks, base_pos,
                            n_groups):
-            tables = ins[0]
-            k_groups = tuple(ins[1:1 + n_groups])
-            v_groups = tuple(ins[1 + n_groups:1 + 2 * n_groups])
+            tables = ins[:len(n_groups)]
+            groups = self._split_groups(ins[len(n_groups):], n_groups)
             toks = np.array(base_toks, np.int32)
             poss = np.array(base_pos, np.int32)
             onehot = np.eye(1, len(toks), slot, dtype=bool)[0]
-            step = _jit_grouped_step(cfg, n_groups)
+            routed = None
             # Teacher-forced prefill: one masked decode per prompt token,
             # reusing the decode step's compiled trace.  Each dispatch
             # gets fresh copies of toks/poss: jnp.asarray can alias the
@@ -188,12 +267,13 @@ class SessionServeEngine:
                 toks[slot], poss[slot] = tok, i
                 lengths = jnp.asarray(
                     np.where(onehot, poss + 1, 0), jnp.int32)
-                _, k_groups, v_groups = step(
-                    self.params, k_groups, v_groups, tables,
-                    jnp.asarray(toks.copy()), jnp.asarray(poss.copy()),
-                    lengths,
-                )
-            return (*k_groups, *v_groups)
+                _, counts, groups = self._run_step(
+                    tables, groups, jnp.asarray(toks.copy()),
+                    jnp.asarray(poss.copy()), lengths)
+                if counts is not None:
+                    routed = counts if routed is None else routed + counts
+            return (*(g for kv in groups for g in kv[0] + kv[1]),
+                    *(() if routed is None else (routed,)))
 
         from repro.core.api import op
 
@@ -219,7 +299,8 @@ class SessionServeEngine:
         if name not in self._tenants:
             self._tenants[name] = cl
         if quota_pages is not None:
-            self.kv.set_quota(name, quota_pages)
+            for kv in self.kvs:  # the quota holds in each pool
+                kv.set_quota(name, quota_pages)
         return cl
 
     # -- request admission ---------------------------------------------------
@@ -261,8 +342,7 @@ class SessionServeEngine:
             for i, cand in enumerate(self.waiting):
                 n_tokens = len(cand.prompt) + cand.max_new_tokens
                 try:
-                    table = self.kv.alloc(cand.rid, n_tokens,
-                                          tenant=cand.tenant)
+                    tables = self._alloc(cand, n_tokens)
                 except QuotaExceeded:
                     self.session.metrics.counter(
                         "serve_quota_deferrals").inc()
@@ -277,52 +357,89 @@ class SessionServeEngine:
                 break
             if req is None:
                 return
-            self.block_tables[slot, :] = self.kv.scratch_page
-            self.block_tables[slot, : len(table)] = table
+            for kv, bt, table in zip(self.kvs, self.tables, tables):
+                bt[slot, :] = kv.scratch_page
+                bt[slot, : len(table)] = table
             self.slot_req[slot] = req
             if len(req.prompt) > 1:
                 self._submit_prefill(slot, req)
             self.slot_pos[slot] = len(req.prompt) - 1
             self.slot_tok[slot] = req.prompt[-1]
 
+    def _alloc(self, req: TenantRequest, n_tokens: int) -> List[np.ndarray]:
+        """Pages of every pool for ``req``, or none of them: a pool that
+        refuses (quota or exhaustion) hands back what the others gave."""
+        from repro.core.allocator import AllocError
+        from repro.core.qos import QuotaExceeded
+
+        tables = []
+        try:
+            for kv in self.kvs:
+                tables.append(kv.alloc(req.rid, n_tokens, tenant=req.tenant))
+        except (QuotaExceeded, AllocError):
+            for kv in self.kvs[:len(tables)]:
+                kv.free(req.rid)
+            raise
+        return tables
+
+    def _pool_inputs(self, client):
+        """Block tables (as Session buffers of ``client``, to free after
+        submission) and KV group buffers of every pool, and the group
+        count per pool, for the slots' current tables."""
+        tbs, bufs, n_groups = [], [], []
+        for kv, bt in zip(self.kvs, self.tables):
+            groups = kv.referenced_groups(bt)
+            tables = kv.compact_tables(bt, groups)
+            tb = self.session.malloc(tables.shape, np.int32, client=client)
+            tb.data[...] = tables
+            tbs.append(tb)
+            bufs += kv.buffers(groups)
+            n_groups.append(len(groups))
+        return tbs, bufs, tuple(n_groups)
+
+    def _routing_buffer(self, client):
+        return self.session.malloc((self.cfg.n_layers, self.cfg.n_experts), np.int32,
+                                   client=client)
+
     def _submit_prefill(self, slot: int, req: TenantRequest) -> None:
-        groups = self.kv.referenced_groups(self.block_tables)
-        tables = self.kv.compact_tables(self.block_tables, groups)
-        bufs = self.kv.buffers(groups)
-        tb = self.session.malloc(tables.shape, np.int32,
-                                 client=self._prefill_client)
-        tb.data[...] = tables
-        self._prefill_client.submit(
-            "llm_prefill", [tb, *bufs], out=list(bufs),
+        tbs, bufs, n_groups = self._pool_inputs(self._prefill_client)
+        routed = [self._routing_buffer(self._prefill_client)] if self.hybrid else []
+        futs = self._prefill_client.submit(
+            "llm_prefill", [*tbs, *bufs], out=list(bufs) + routed,
             name=f"prefill#{req.rid}",
             slot=slot, prompt=tuple(req.prompt[:-1]),
             base_toks=tuple(int(t) for t in self.slot_tok),
             base_pos=tuple(int(p) for p in self.slot_pos),
-            n_groups=len(groups),
+            n_groups=n_groups,
         )
-        self.session.free(tb)  # deferred to the prefill's completion
+        for tb in tbs:
+            self.session.free(tb)  # deferred to the prefill's completion
+        if self.hybrid:
+            self._pending_prefill.append((len(req.prompt), futs[-1]))
 
     # -- decode --------------------------------------------------------------
     def _decode_substep(self, mask: np.ndarray, client) -> np.ndarray:
-        groups = self.kv.referenced_groups(self.block_tables)
-        tables = self.kv.compact_tables(self.block_tables, groups)
-        bufs = self.kv.buffers(groups)
         sess = self.session
         tok = sess.malloc((self.max_batch,), np.int32, client=client)
         tok.data[...] = self.slot_tok
         pos = sess.malloc((self.max_batch,), np.int32, client=client)
         pos.data[...] = self.slot_pos
-        tb = sess.malloc(tables.shape, np.int32, client=client)
-        tb.data[...] = tables
+        tbs, bufs, n_groups = self._pool_inputs(client)
         nxt = sess.malloc((self.max_batch,), np.int32, client=client)
+        routed = [self._routing_buffer(client)] if self.hybrid else []
         futs = client.submit(
-            "llm_decode", [tok, pos, tb, *bufs], out=[nxt, *bufs],
-            mask=tuple(bool(m) for m in mask), n_groups=len(groups),
+            "llm_decode", [tok, pos, *tbs, *bufs], out=[nxt, *routed, *bufs],
+            mask=tuple(bool(m) for m in mask), n_groups=n_groups,
         )
-        for b in (tok, pos, tb):
+        for b in (tok, pos, *tbs):
             sess.free(b)
         out = futs[0].result()
         sess.free(nxt)
+        if routed:
+            counts = futs[1].result().copy()
+            sess.free(routed[0])
+            decode = self.last_routing["decode"]
+            self.last_routing["decode"] = counts if decode is None else decode + counts
         return out
 
     def step(self) -> int:
@@ -332,14 +449,18 @@ class SessionServeEngine:
             return self._step()
 
     def _step(self) -> int:
+        if self.hybrid:
+            self.last_routing = {"prefill": [], "decode": None}
         with self._region("admit"):
             self._admit()
         active = np.array([r is not None for r in self.slot_req])
         if not active.any():
-            self.kv.publish_metrics()
+            self._finish_step()
             return 0
         n_active = int(active.sum())
         metrics = self.session.metrics
+        if self.hybrid:
+            self._count_pages(active)
         for tname, client in self._tenants.items():
             slots = [s for s in range(self.max_batch)
                      if self.slot_req[s] is not None
@@ -359,12 +480,49 @@ class SessionServeEngine:
                 if (len(req.generated) >= req.max_new_tokens
                         or tok == self.eos_id):
                     req.done = True
-                    self.kv.free(req.rid)
+                    for kv, bt in zip(self.kvs, self.tables):
+                        kv.free(req.rid)
+                        bt[slot, :] = kv.scratch_page
                     self.slot_req[slot] = None
-                    self.block_tables[slot, :] = self.kv.scratch_page
                     metrics.counter("serve_requests_completed").inc()
-        self.kv.publish_metrics()
+        self._finish_step()
         return n_active
+
+    def _count_pages(self, active: np.ndarray) -> None:
+        """Counters of the pages live sequences hold in each pool (added
+        once per decoding step, so over a window they integrate to
+        page-steps) and of ring wraps at the positions written now."""
+        metrics = self.session.metrics
+        for name, kv in (("full", self.kv), ("window", self.kv_window)):
+            metrics.counter(f"kv/{name}/pages_held").inc(kv.used_pages - 1)  # less scratch
+        ring = self._ring_tokens
+        wraps = sum(1 for s in np.flatnonzero(active)
+                    if self.slot_pos[s] > 0 and self.slot_pos[s] % ring == 0)
+        if wraps:
+            metrics.counter("kv/window/ring_wraps").inc(wraps)
+
+    def _finish_step(self) -> None:
+        """Publish the pool gauges; for a sparse-expert model, collect the
+        step's prefill routing and count what the router did."""
+        self.kv.publish_metrics()
+        if not self.hybrid:
+            return
+        metrics = self.session.metrics
+        for n_prompt, fut in self._pending_prefill:
+            self.last_routing["prefill"].append((n_prompt, fut.result().copy()))
+            self.session.free(fut)
+            # prompt positions 1 .. n_prompt - 2 were written by the prefill
+            wraps = max(0, n_prompt - 2) // self._ring_tokens
+            if wraps:
+                metrics.counter("kv/window/ring_wraps").inc(wraps)
+        self._pending_prefill = []
+        groups = [c for _, c in self.last_routing["prefill"]]
+        if self.last_routing["decode"] is not None:
+            groups.append(self.last_routing["decode"])
+        for counts in groups:
+            for layer, row in enumerate(counts):
+                metrics.counter(f"moe/{layer}/tokens_routed").inc(int(row.sum()))
+                metrics.counter(f"moe/{layer}/experts_hit").inc(int((row > 0).sum()))
 
     def run(self, max_steps: int = 10000) -> None:
         for _ in range(max_steps):
