@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +27,7 @@ from repro.kernels.moe import ops as moe_ops
 from repro.kernels.paged_attention import ref as pa_ref
 from repro.models import layers as L
 
-__all__ = ["ServeEngine", "Request", "SUPPORTED_FAMILIES"]
+__all__ = ["ServeEngine", "Request", "SUPPORTED_FAMILIES", "chunked_prefill"]
 
 #: full-attention dense decoder families the paged engines support.
 SUPPORTED_FAMILIES = ("dense", "vlm")
@@ -107,19 +107,14 @@ class ServeEngine:
             self.block_tables[slot, :] = self.scratch_page
             self.block_tables[slot, : len(table)] = table
             self.slot_req[slot] = req
-            # prefill by teacher-forced decode over the prompt
-            for i, tok in enumerate(req.prompt[:-1]):
-                self._decode_one(slot, tok, i)
+            chunked_prefill(self._prefill_call, [self.block_tables], slot,
+                            [self.scratch_page], req.prompt[:-1], self.max_batch)
             self.slot_pos[slot] = len(req.prompt) - 1
             self.slot_tok[slot] = req.prompt[-1]
 
-    def _decode_one(self, slot: int, token: int, pos: int) -> int:
-        toks = self.slot_tok.copy()
-        poss = self.slot_pos.copy()
-        toks[slot], poss[slot] = token, pos
-        nxt = self._step(toks, poss, active_mask=np.eye(1, self.max_batch,
-                                                        slot, dtype=bool)[0])
-        return int(nxt[slot])
+    def _prefill_call(self, tables, tokens, pos, lengths) -> None:
+        _, self.k_pools, self.v_pools = self._step_fn(
+            self.params, self.k_pools, self.v_pools, tables[0], tokens, pos, lengths)
 
     # -- decode ----------------------------------------------------------------
     def _step(self, tokens: np.ndarray, pos: np.ndarray, active_mask) -> np.ndarray:
@@ -158,6 +153,49 @@ class ServeEngine:
         for _ in range(max_steps):
             if self.step() == 0 and not self.waiting:
                 break
+
+
+def chunked_prefill(step, tables: Sequence, slot: int, scratch: Sequence[int],
+                    prompt: Sequence[int], rows: int):
+    """Teacher-forced prefill of ``prompt`` (token ``i`` at position
+    ``i``) into the sequence of ``slot``, through the batch-wide decode
+    step: up to ``rows`` prompt tokens a call, one to a row.
+
+    ``tables`` holds one (batch, pages) block table per pool and
+    ``scratch`` each pool's scratch page.  Every used row reads and
+    writes through ``slot``'s table row in each pool, with ``lengths``
+    its position plus one; the rest point at the scratch page with
+    ``lengths`` 0.  Each layer of the step writes every row's keys before
+    its attention reads them, and a row attends up to its own position,
+    so one call is causal prefill of its rows.  A sliding-window pool
+    needs ``rows`` at most its ring's slots less the window, so that no
+    row overwrites a key an earlier row of the same call still reads.
+
+    ``step(tables, tokens, pos, lengths)`` runs one call and returns its
+    (layers, experts) routing counts, or None.  Returns the number of
+    calls and their counts summed on the host (None for a dense model):
+    an addition on the device would be a program of its own, compiled
+    at the first prompt that takes two calls."""
+    tables = [np.asarray(t) for t in tables]
+    batch = tables[0].shape[0]
+    row = np.arange(batch)
+    starts = range(0, len(prompt), rows)
+    routed = []
+    for start in starts:
+        chunk = prompt[start:start + rows]
+        used = row < len(chunk)
+        tokens = np.zeros((batch,), np.int32)
+        tokens[:len(chunk)] = chunk
+        pos = np.where(used, start + row, 0).astype(np.int32)
+        call_tables = [np.where(used[:, None], t[slot], s).astype(np.int32)
+                       for t, s in zip(tables, scratch)]
+        counts = step(call_tables, tokens, pos,
+                      np.where(used, pos + 1, 0).astype(np.int32))
+        if counts is not None:
+            routed.append(counts)
+    if not routed:
+        return len(starts), None
+    return len(starts), np.sum([np.asarray(c) for c in routed], axis=0, dtype=np.int32)
 
 
 def _paged_decode_step(cfg, params, k_pools, v_pools, block_tables,

@@ -53,7 +53,8 @@ from repro.core.paged_kv import ring_pages
 from repro.core.trace import NULL_REGION
 from repro.models import layers as L
 
-from .engine import SUPPORTED_FAMILIES, Request, _paged_decode_step, _paged_hybrid_step
+from .engine import (SUPPORTED_FAMILIES, Request, _paged_decode_step, _paged_hybrid_step,
+                     chunked_prefill)
 
 __all__ = ["SessionServeEngine", "TenantRequest", "SESSION_FAMILIES"]
 
@@ -196,6 +197,11 @@ class SessionServeEngine:
             #: length, (layers, experts) counts) pair per prompt taken
             #: in; ``"decode"``, the counts summed over its sub-steps
             self.last_routing = {"prefill": [], "decode": None}
+        #: prompt tokens one prefill call takes in, a row each; with window
+        #: rings, no more than a ring's slots less the window, so that no
+        #: row of a call overwrites a key an earlier row still reads
+        self.prefill_rows = (min(max_batch, self._ring_tokens - cfg.window)
+                             if self.hybrid else max_batch)
         self._prefill_client = session.client(
             "prefill", weight=prefill_weight, window=prefill_window)
         self._tenants: Dict[str, object] = {}  # name -> SessionClient
@@ -250,30 +256,22 @@ class SessionServeEngine:
             routed = () if counts is None else (counts,)
             return (nxt, *routed, *(g for kv in groups for g in kv[0] + kv[1]))
 
-        def prefill_kernel(ins, *, slot, prompt, base_toks, base_pos,
-                           n_groups):
+        def prefill_kernel(ins, *, slot, prompt, scratch, n_groups):
             tables = ins[:len(n_groups)]
             groups = self._split_groups(ins[len(n_groups):], n_groups)
-            toks = np.array(base_toks, np.int32)
-            poss = np.array(base_pos, np.int32)
-            onehot = np.eye(1, len(toks), slot, dtype=bool)[0]
-            routed = None
-            # Teacher-forced prefill: one masked decode per prompt token,
-            # reusing the decode step's compiled trace.  Each dispatch
-            # gets fresh copies of toks/poss: jnp.asarray can alias the
-            # numpy buffer zero-copy, and the async XLA execution must
-            # not observe the next iteration's in-place mutation.
-            for i, tok in enumerate(prompt):
-                toks[slot], poss[slot] = tok, i
-                lengths = jnp.asarray(
-                    np.where(onehot, poss + 1, 0), jnp.int32)
-                _, counts, groups = self._run_step(
-                    tables, groups, jnp.asarray(toks.copy()),
-                    jnp.asarray(poss.copy()), lengths)
-                if counts is not None:
-                    routed = counts if routed is None else routed + counts
+
+            def call(tables, tokens, pos, lengths):
+                nonlocal groups
+                _, counts, groups = self._run_step(tables, groups, tokens, pos, lengths)
+                return counts
+
+            calls, routed = chunked_prefill(call, tables, slot, scratch, prompt,
+                                            self.prefill_rows)
+            metrics = self.session.metrics
+            metrics.counter("serve/prefill_calls").inc(calls)
+            metrics.counter("serve/prefill_tokens").inc(len(prompt))
             return (*(g for kv in groups for g in kv[0] + kv[1]),
-                    *(() if routed is None else (routed,)))
+                    *(() if routed is None else (jnp.asarray(routed),)))
 
         from repro.core.api import op
 
@@ -384,9 +382,10 @@ class SessionServeEngine:
 
     def _pool_inputs(self, client):
         """Block tables (as Session buffers of ``client``, to free after
-        submission) and KV group buffers of every pool, and the group
-        count per pool, for the slots' current tables."""
-        tbs, bufs, n_groups = [], [], []
+        submission) and KV group buffers of every pool, the group count
+        per pool, and each pool's scratch page in the kernel-side view,
+        for the slots' current tables."""
+        tbs, bufs, n_groups, scratch = [], [], [], []
         for kv, bt in zip(self.kvs, self.tables):
             groups = kv.referenced_groups(bt)
             tables = kv.compact_tables(bt, groups)
@@ -395,21 +394,20 @@ class SessionServeEngine:
             tbs.append(tb)
             bufs += kv.buffers(groups)
             n_groups.append(len(groups))
-        return tbs, bufs, tuple(n_groups)
+            scratch.append(int(kv.compact_tables(np.int32(kv.scratch_page), groups)))
+        return tbs, bufs, tuple(n_groups), tuple(scratch)
 
     def _routing_buffer(self, client):
         return self.session.malloc((self.cfg.n_layers, self.cfg.n_experts), np.int32,
                                    client=client)
 
     def _submit_prefill(self, slot: int, req: TenantRequest) -> None:
-        tbs, bufs, n_groups = self._pool_inputs(self._prefill_client)
+        tbs, bufs, n_groups, scratch = self._pool_inputs(self._prefill_client)
         routed = [self._routing_buffer(self._prefill_client)] if self.hybrid else []
         futs = self._prefill_client.submit(
             "llm_prefill", [*tbs, *bufs], out=list(bufs) + routed,
             name=f"prefill#{req.rid}",
-            slot=slot, prompt=tuple(req.prompt[:-1]),
-            base_toks=tuple(int(t) for t in self.slot_tok),
-            base_pos=tuple(int(p) for p in self.slot_pos),
+            slot=slot, prompt=tuple(req.prompt[:-1]), scratch=scratch,
             n_groups=n_groups,
         )
         for tb in tbs:
@@ -424,7 +422,7 @@ class SessionServeEngine:
         tok.data[...] = self.slot_tok
         pos = sess.malloc((self.max_batch,), np.int32, client=client)
         pos.data[...] = self.slot_pos
-        tbs, bufs, n_groups = self._pool_inputs(client)
+        tbs, bufs, n_groups, _ = self._pool_inputs(client)
         nxt = sess.malloc((self.max_batch,), np.int32, client=client)
         routed = [self._routing_buffer(client)] if self.hybrid else []
         futs = client.submit(

@@ -206,6 +206,17 @@ def fresh_programs():
     session_engine._jit_hybrid_step.cache_clear()
 
 
+def _per_position(seen, eng, n_prompt):
+    """One row of logits per position served, from the step calls' logits
+    of one request in slot 0: the prefill's calls carry a position in
+    each of their first ``prefill_rows`` rows, then every decode call one
+    in slot 0."""
+    n_pre, rows = n_prompt - 1, eng.prefill_rows
+    calls = -(-n_pre // rows)
+    pre = [a[r] for a in seen[:calls] for r in range(rows)][:n_pre]
+    return np.stack(pre + [a[0] for a in seen[calls:]]), calls
+
+
 def _serve_one(cfg, w, prompt, n_new, **kw):
     with engine(cfg, w, **kw) as eng:
         req = eng.submit(prompt, n_new)
@@ -221,10 +232,11 @@ def test_engine_logits_match_the_reference(monkeypatch, fresh_programs):
     w = make_weights(cfg, SEED)
     seen = _program_logits(monkeypatch)
     prompt = [int(t) for t in np.random.default_rng(1).integers(0, 256, 23)]
-    req, _ = _serve_one(cfg, w, prompt, 18)
+    req, eng = _serve_one(cfg, w, prompt, 18)
     seq = prompt + req.generated[:-1]
-    assert len(seen) == len(seq)  # 22 prefill calls, then 18 decodes
-    got = np.stack([a[0] for a in seen])
+    got, calls = _per_position(seen, eng, len(prompt))
+    assert eng.prefill_rows == 2 and calls == 11  # 22 prompt positions, 2 a call
+    assert len(seen) == calls + 18 and len(got) == len(seq)
     ref = Reference(cfg, w, seq_len=64, n_rows=64)
     want = ref.logits(seq, list(range(len(seq))))
     # float32 throughout; the program sums attention over ring slots and
@@ -241,10 +253,10 @@ def test_bfloat16_engine_stays_near_the_reference(monkeypatch, fresh_programs):
     w = make_weights(cfg, SEED + 1)
     seen = _program_logits(monkeypatch)
     prompt = [int(t) for t in np.random.default_rng(2).integers(0, 256, 21)]
-    req, _ = _serve_one(cfg, w, prompt, 20)
+    req, eng = _serve_one(cfg, w, prompt, 20)
     seq = prompt + req.generated[:-1]
-    assert len(seen) == len(seq)
-    got = np.stack([a[0] for a in seen])
+    got, calls = _per_position(seen, eng, len(prompt))
+    assert len(seen) == calls + 20 and len(got) == len(seq)
     want = Reference(cfg, w, seq_len=64, n_rows=64).logits(seq, list(range(len(seq))))
     # logits of order 1 through 8 bf16 layers (2**-8 relative each) and
     # an occasional router near-tie that flips one expert
