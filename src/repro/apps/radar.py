@@ -3,9 +3,10 @@ RIMMS runtime: 2FFT, 2FZF, 3ZIP reference chains and the real-world
 RC / PD / SAR workloads.
 
 Every app builds (buffers, tasks) against a :class:`HeteContext`; the
-caller runs them under a :class:`Runtime` with either the ``reference``
-(host-owned) or ``rimms`` memory policy — the paper's comparisons fall
-out of the transfer ledger.
+caller submits them to a :class:`~repro.core.api.Session` (its
+``context``) with either the ``reference`` (host-owned) or ``rimms``
+memory policy — the paper's comparisons fall out of the transfer
+ledger.
 
 PE kernels: numpy on the CPU PE; jitted jnp on accelerator PEs (the
 Pallas zip/fft kernels are the TPU-deployment versions, validated in
@@ -14,7 +15,7 @@ tests; the emulated SoC uses the XLA path for speed).
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -23,12 +24,11 @@ import numpy as np
 from repro.core import api as rimms
 from repro.core.api import Session
 from repro.core.hete import HeteContext, HeteData
-from repro.core.runtime import Runtime, Task, make_emulated_soc
+from repro.core.runtime import Task
 
 __all__ = [
-    "register_kernels", "build_2fft", "build_2fzf", "build_3zip",
-    "build_rc", "build_pd", "build_sar", "make_runtime", "make_session",
-    "run_pipeline", "submit_2fzf",
+    "build_2fft", "build_2fzf", "build_3zip", "build_rc", "build_pd",
+    "build_sar", "make_session", "submit_2fzf",
 ]
 
 C64 = np.complex64
@@ -36,8 +36,8 @@ C64 = np.complex64
 
 # ---------------------------------------------------------------------------
 # PE kernels — registered as per-kind op variants (ISSUE 4): importing
-# this module fills the default registry, so `Session.emulated()` (and
-# `register_kernels` for batch runtimes) get the radar op set.
+# this module fills the default registry, so `Session.emulated()` gets
+# the radar op set.
 # ---------------------------------------------------------------------------
 
 
@@ -101,30 +101,6 @@ def _zip_device(ins):
     return _jzip(ins[0], ins[1])
 
 
-def register_kernels(rt: Runtime) -> None:
-    """Install the radar op registry into a batch runtime (compat shim —
-    sessions install the registry themselves)."""
-    rimms.default_registry.install(rt)
-
-
-def make_runtime(*, policy: str, scheduler: str = "round_robin",
-                 n_cpu: int = 1, accelerators: Sequence[str] = ("gpu0",),
-                 allocator: str = "nextfit", tracking: str = "flag",
-                 backend: Optional[str] = None):
-    """Build (Runtime, HeteContext) for an emulated SoC.  ``scheduler``
-    may be any of :data:`repro.core.runtime.SCHEDULERS`, including the
-    transfer-aware ``"heft"`` used by the graph executor; ``backend``
-    is the kernel-execution backend (thread | process | auto)."""
-    pes, ctx = make_emulated_soc(
-        n_cpu=n_cpu, accelerators=tuple(accelerators), allocator=allocator,
-        tracking=tracking, backend=backend,
-    )
-    rt = Runtime(pes, ctx, policy=policy, scheduler=scheduler,
-                 backend=backend)
-    register_kernels(rt)
-    return rt, ctx
-
-
 def make_session(*, policy: str = "rimms", scheduler: str = "heft",
                  n_cpu: int = 1, accelerators: Sequence[str] = ("gpu0",),
                  **kwargs) -> Session:
@@ -136,21 +112,6 @@ def make_session(*, policy: str = "rimms", scheduler: str = "heft",
         policy=policy, scheduler=scheduler, n_cpu=n_cpu,
         accelerators=tuple(accelerators), **kwargs,
     )
-
-
-def run_pipeline(rt: Runtime, tasks, *, mode: str = "serial",
-                 scheduler: Optional[str] = None) -> float:
-    """Execute a built task list either serially (CEDR-style submission
-    order) or on the async task-graph executor (automatic DAG, per-PE
-    queues, transfer/compute overlap).  Returns wall seconds."""
-    # internal calls go through the private impls: the DeprecationWarning
-    # on run/run_graph is for user code migrating to Session, not for the
-    # compat helpers themselves
-    if mode == "serial":
-        return rt._run_impl(tasks)
-    if mode == "graph":
-        return rt._run_graph_impl(tasks, scheduler=scheduler)
-    raise ValueError(f"unknown execution mode {mode!r} (serial|graph)")
 
 
 def _fill(hd: HeteData, rng: np.random.Generator) -> None:
@@ -201,7 +162,7 @@ def submit_2fzf(session: Session, n: int, *, pins=(None,) * 4, seed=0,
     """The 2FZF chain (Fig 4b) through the streaming session API: four
     submissions, zero explicit sync — ``out.result()`` is the only sync
     point.  ``tag`` disambiguates task names when many clients submit
-    chains against one session (bench_stream)."""
+    chains against one session."""
     rng = np.random.default_rng(seed)
     a, b = session.malloc((n,), C64), session.malloc((n,), C64)
     _fill(a.hete, rng)

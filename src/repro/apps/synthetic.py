@@ -1,4 +1,4 @@
-"""Synthetic DAG workloads for the graph executor benchmarks/tests.
+"""Synthetic DAG workloads for the stream executor's tests.
 
 The radar chains (:mod:`repro.apps.radar`) are mostly linear per way;
 these builders produce *fork-join* structures whose width is what the
@@ -18,7 +18,7 @@ from repro.apps.radar import _fill
 from repro.core.hete import HeteContext, HeteData
 from repro.core.runtime import Task
 
-__all__ = ["build_fork_join", "build_diamonds", "submit_fork_join"]
+__all__ = ["build_fork_join", "submit_fork_join"]
 
 C64 = np.complex64
 
@@ -85,11 +85,11 @@ def submit_fork_join(
 ) -> Dict[str, "BufferFuture"]:
     """:func:`build_fork_join` through the streaming session API
     (ISSUE 4): identical DAG structure, buffer sizes, fill seeds and
-    submission order, so a single-threaded session with static
-    ``round_robin`` placement is bit-identical — outputs *and* per-pair
-    copy counts — to batch ``run_graph``/serial ``run`` on the same
-    build.  Returns ``{"src", "out"}`` futures; ``out.result()`` is the
-    only sync point."""
+    submission order, so a session with static ``round_robin``
+    placement is bit-identical — outputs *and* per-pair copy counts —
+    to the built task list submitted to a session of the same
+    configuration.  Returns ``{"src", "out"}`` futures;
+    ``out.result()`` is the only sync point."""
     if ways < 1 or ways & (ways - 1):
         raise ValueError(f"ways must be a power of two, got {ways}")
     rng = np.random.default_rng(seed)
@@ -119,30 +119,3 @@ def submit_fork_join(
         level += 1
 
     return {"src": src, "out": branch_outs[0]}
-
-
-def build_diamonds(
-    ctx: HeteContext,
-    *,
-    count: int = 8,
-    n: int = 2048,
-    seed: int = 0,
-) -> Tuple[Dict[str, HeteData], List[Task]]:
-    """``count`` independent diamond DAGs (fft → two zips → zip join) —
-    maximal inter-diamond parallelism, for scheduler stress tests."""
-    rng = np.random.default_rng(seed)
-    outs: List[HeteData] = []
-    tasks: List[Task] = []
-    for c in range(count):
-        a = ctx.malloc((n,), C64)
-        _fill(a, rng)
-        fa = ctx.malloc((n,), C64)
-        left, right, out = (ctx.malloc((n,), C64) for _ in range(3))
-        tasks += [
-            Task("fft", [a], [fa], name=f"d{c}_top"),
-            Task("zip", [fa, a], [left], name=f"d{c}_left"),
-            Task("zip", [fa, fa], [right], name=f"d{c}_right"),
-            Task("zip", [left, right], [out], name=f"d{c}_join"),
-        ]
-        outs.append(out)
-    return {"outs": outs}, tasks

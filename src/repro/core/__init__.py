@@ -5,7 +5,7 @@ from .api import (
     BufferFuture, OpRegistry, Session, SessionClient, SessionClosedError,
     default_registry, op,
 )
-from .executor import GraphExecutor, StreamExecutor, WorkerPool, replay_schedule
+from .executor import StreamExecutor, WorkerPool, replay_schedule
 from .graph import CostModel, GraphBuilder, TaskGraph, TaskNode, build_graph
 from .hete import (
     HeteContext, HeteData, PrefetchDeferred, default_context,
@@ -41,7 +41,7 @@ __all__ = [
     "SessionClosedError", "default_registry", "op",
     "BackpressureFull", "ClientState", "QoSManager", "QuotaExceeded",
     "admission_cost", "fair_replay", "jain_index",
-    "GraphExecutor", "StreamExecutor", "WorkerPool", "replay_schedule",
+    "StreamExecutor", "WorkerPool", "replay_schedule",
     "CostModel", "GraphBuilder", "TaskGraph", "TaskNode", "build_graph",
     "HeteContext", "HeteData", "PrefetchDeferred", "default_context",
     "hete_free", "hete_malloc", "hete_sync",

@@ -58,7 +58,7 @@ class Extent:
 
 
 class _AllocatorBase:
-    """Shared bookkeeping: capacity, counters for benchmarks.
+    """Shared bookkeeping: capacity and allocation counters.
 
     Eviction support (ISSUE 2): ``alloc`` accepts an optional opaque
     ``tag`` (the owning buffer identity, set by the eviction engine in
@@ -75,7 +75,7 @@ class _AllocatorBase:
             raise ValueError(f"capacity must be positive, got {capacity}")
         self.capacity = int(capacity)
         self.used_bytes = 0
-        # Instrumentation for the paper's Fig 7 / Fig 10 benchmarks.
+        # Instrumentation for the paper's Fig 7 / Fig 10 measurements.
         self.n_allocs = 0
         self.n_frees = 0
         self.n_steps = 0  # search steps taken (comparisons / node visits)
@@ -322,7 +322,7 @@ class NextFitAllocator(_AllocatorBase):
             seg = seg.next
         return largest
 
-    # -- introspection (tests / benchmarks) ------------------------------
+    # -- introspection (tests) ------------------------------
     def segments(self) -> list[tuple[int, int, bool]]:
         out = []
         seg = self._head

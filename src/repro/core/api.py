@@ -1,11 +1,8 @@
 """Streaming session API — RIMMS's primary entry point (ISSUE 4).
 
 The paper's promise (§3.2) is that application code names *work* and
-*data* while the runtime owns placement, movement, and completion.  The
-batch entry points (:meth:`Runtime.run` / :meth:`Runtime.run_graph`)
-still made callers hand-assemble static ``Task`` lists, pick an
-execution mode, and ``hete_sync`` by hand.  This module is the
-redesigned front door:
+*data* while the runtime owns placement, movement, and completion.
+This module is the front door, and the only way a task reaches a PE:
 
 * :func:`op` — decorator registering a kernel *variant per PE kind*
   into an :class:`OpRegistry` (``@rimms.op("fft", kinds=("cpu",))``);
@@ -518,7 +515,7 @@ class Session:
         ``soc_kwargs``: ``arena_bytes``, ``topology``, ``acc_ops``, …).
         The default scheduler is the windowed ``heft`` — the streaming
         placement the session exists for; pass ``"round_robin"`` for
-        bit-identical-to-serial static placement.
+        static placement in submission order.
 
         ``platform`` names a preset from the shorthand registry
         (:func:`~repro.core.runtime.register_platform`; built-ins listed

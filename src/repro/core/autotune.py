@@ -29,7 +29,7 @@ Usage::
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -222,11 +222,3 @@ def autotune(session, *, nbytes: Sequence[int] = DEFAULT_LADDER, k: int = 5,
     session.calibration = tab
     session.runtime.set_calibration(tab)
     return tab
-
-
-def tuned_summary(table: CalibrationTable) -> Dict[str, Dict[str, Any]]:
-    """Winner rows for the tuned ops only — ``{op/kind/bucket: winner}``
-    (what ``bench_calibrate`` and the CLI report print)."""
-    tuned = {t.op for t in tunables()}
-    return {key: dict(win) for key, win in table.winners()
-            if key.split("/", 1)[0] in tuned}

@@ -22,17 +22,12 @@ the loop:
   thread backend *or* through the PE's subprocess worker under
   ``backend="process"``, verifying every non-default variant's outputs
   are **bit-identical** to the default variant before it may win.
-* :func:`heft_plan` / :func:`simulate_plan` — a deterministic static
-  HEFT planner + plan evaluator over the runtime's cost basis, used by
-  ``bench_calibrate`` to gate *calibrated placement ≤ prior placement*
-  without wall-clock noise: plan once with the prior model, once with a
-  calibrated model, and price both plans under the measured truth.
 
 Consumption: :meth:`CostModel.prior_estimate
 <repro.core.graph.CostModel.prior_estimate>` consults an attached table
-before falling back to ``BASE_THROUGHPUT``, so serial dispatch, the
-windowed-HEFT stream placement, and the modeled replays all price work
-from measured throughput; :meth:`Runtime._run_kernel
+before falling back to ``BASE_THROUGHPUT``, so the windowed-HEFT
+stream placement and the modeled replays price work from measured
+throughput; :meth:`Runtime._run_kernel
 <repro.core.runtime.Runtime._run_kernel>` consults the table's winner
 rows to dispatch the fastest bit-identical kernel variant.
 """
@@ -47,12 +42,11 @@ from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
 
 import numpy as np
 
-from .graph import build_graph
 from .telemetry import shape_bucket
 
 __all__ = [
     "FORMAT", "DEFAULT_VARIANT", "DEFAULT_LADDER", "CalibrationTable",
-    "calibrate", "resolve_calibration", "heft_plan", "simulate_plan",
+    "calibrate", "resolve_calibration",
 ]
 
 #: on-disk format tag — bump on incompatible cell/winner layout changes
@@ -509,87 +503,3 @@ def calibrate(target, *, registry=None, ops: Optional[Iterable[str]] = None,
         prev = table.meta.get("skipped_ops", [])
         table.meta["skipped_ops"] = sorted(set(prev) | set(skipped))
     return table
-
-
-# ---------------------------------------------------------------------------
-# Deterministic static HEFT planner — the bench_calibrate gate's core
-# ---------------------------------------------------------------------------
-
-
-def _src_location(hd, out_loc: Dict[int, Any]):
-    return out_loc.get(id(hd), hd.last_location)
-
-
-def heft_plan(rt, tasks, *, cost_model=None) -> List[str]:
-    """Static HEFT over ``tasks`` on ``rt``'s PEs under ``cost_model``
-    (default: the runtime's): upward ranks, then earliest-finish-time
-    placement in rank order.  Pure planning — nothing executes, no
-    wall-clock enters, so the same inputs always produce the same plan.
-    Returns the placed PE name per task (submission order)."""
-    cm = cost_model or rt.cost_model
-    graph = build_graph(tasks)
-    bw = rt.context.ledger.bandwidth_model
-
-    def compute_cost(task) -> float:
-        kinds = sorted({pe.kind for pe in rt._eligible(task)})
-        return cm.mean_estimate(task.op, kinds, task.in_bytes)
-
-    graph.compute_ranks(compute_cost, lambda t: bw.typical(t.in_bytes))
-    order = sorted(graph.nodes, key=lambda n: (-n.rank, n.index))
-    pe_free: Dict[str, float] = {pe.name: 0.0 for pe in rt.pes}
-    finish: Dict[int, float] = {}
-    out_loc: Dict[int, Any] = {}
-    placement: Dict[int, str] = {}
-    for node in order:
-        task = node.task
-        pes = ([rt.by_name[task.pin]] if task.pin is not None
-               else rt._eligible(task))
-        ready = max((finish[d] for d in node.deps), default=0.0)
-
-        def eft(pe) -> float:
-            tr = sum(
-                bw.seconds(_src_location(hd, out_loc), pe.location, hd.nbytes)
-                for hd in task.inputs
-                if _src_location(hd, out_loc) != pe.location
-            )
-            start = max(pe_free[pe.name], ready + tr)
-            return start + cm.estimate(task.op, pe.kind, task.in_bytes)
-
-        best = min(pes, key=lambda pe: (eft(pe), pe.name))
-        f = eft(best)
-        pe_free[best.name] = f
-        finish[node.index] = f
-        placement[node.index] = best.name
-        for hd in task.outputs:
-            out_loc[id(hd)] = best.location
-    return [placement[i] for i in range(len(graph.nodes))]
-
-
-def simulate_plan(rt, tasks, placement: Sequence[str], *,
-                  cost_model=None) -> float:
-    """Modeled makespan of executing ``tasks`` under a fixed
-    ``placement`` (PE name per task), priced by ``cost_model`` —
-    evaluate plans from *different* models under one truth model to
-    compare placement quality.  Deterministic; nothing executes."""
-    cm = cost_model or rt.cost_model
-    graph = build_graph(tasks)
-    bw = rt.context.ledger.bandwidth_model
-    pe_free: Dict[str, float] = {pe.name: 0.0 for pe in rt.pes}
-    finish: Dict[int, float] = {}
-    out_loc: Dict[int, Any] = {}
-    for node in graph.nodes:  # builder order: deps have lower indices
-        task = node.task
-        pe = rt.by_name[placement[node.index]]
-        ready = max((finish[d] for d in node.deps), default=0.0)
-        tr = sum(
-            bw.seconds(_src_location(hd, out_loc), pe.location, hd.nbytes)
-            for hd in task.inputs
-            if _src_location(hd, out_loc) != pe.location
-        )
-        start = max(pe_free[pe.name], ready + tr)
-        end = start + cm.estimate(task.op, pe.kind, task.in_bytes)
-        pe_free[pe.name] = end
-        finish[node.index] = end
-        for hd in task.outputs:
-            out_loc[id(hd)] = pe.location
-    return max(finish.values(), default=0.0)

@@ -1,30 +1,24 @@
-"""Async task executors: persistent per-PE workers, prefetch, HEFT-lite.
+"""The stream executor: persistent per-PE workers, prefetch, HEFT-lite.
 
-Two engines share one persistent :class:`WorkerPool` (one worker thread
-per PE plus a transfer pool, owned by the
-:class:`~repro.core.runtime.Runtime`, reused across runs — ISSUE 2):
+:class:`StreamExecutor` is the engine behind
+:class:`repro.core.api.Session` and the only way a task reaches a PE.
+Tasks are admitted one at a time as the application submits them and
+a persistent :class:`WorkerPool` (one worker thread per PE plus a
+transfer pool, owned by the :class:`~repro.core.runtime.Runtime` and
+reused by every session on it — ISSUE 2) consumes the stream
+continuously: a task dispatches the moment its dependencies complete,
+placement is a **windowed HEFT** over the ready frontier (upward ranks
+recomputed over the admitted, incomplete window), there is no global
+barrier, and a failing task fails only its dependent subtree (futures
+carry the cause) while independent chains keep flowing.
 
-* :class:`StreamExecutor` — **streaming admission** (ISSUE 4): the
-  engine behind the primary :class:`repro.core.api.Session` API.  Tasks
-  are admitted one at a time as the application submits them and the
-  pool consumes the stream continuously — a task dispatches the moment
-  its dependencies complete, placement is a **windowed HEFT** over the
-  ready frontier (upward ranks recomputed over the admitted, incomplete
-  window), there is no global barrier, and a failing task fails only its
-  dependent subtree (futures carry the cause) while independent chains
-  keep flowing.
-* :class:`GraphExecutor` — batch intake for the
-  :meth:`~repro.core.runtime.Runtime.run_graph` compat wrapper: takes a
-  whole task list, runs it to completion, and tears the run down on the
-  first failure (nothing commits after an error).
-
-Shared mechanics (both engines):
+Mechanics:
 
 * **input prefetch**: the moment a task's dependencies complete, its
   input staging (``hete_Data`` flag checks + src→PE copies) is submitted
   to the transfer pool, so the copy overlaps whatever the target PE is
   still computing — the paper's §3.2.2 premise (the runtime knows where
-  valid bytes live) finally buys wall-clock, not just copy counts;
+  valid bytes live) buys wall-clock, not just copy counts;
 * **topology-aware prefetch ordering** (ISSUE 4 satellite): when a
   batch of tasks becomes ready together under an interconnect topology,
   their prefetch stagings are issued least-contended-route-first —
@@ -40,11 +34,13 @@ Shared mechanics (both engines):
   when the warmed bytes survived, a demand fetch otherwise — so
   concurrent prefetches can never pin an arena full and starve a
   worker's reservation;
-* scheduling: ``round_robin`` (static, bit-identical to serial dispatch),
-  ``data_affinity`` (dynamic, flag-aware), or ``heft`` — a HEFT
-  list scheduler that ranks ready tasks by upward rank and places each
-  with an **insertion-based slot search** (ISSUE 3): a task may slide
-  into an idle gap on a PE's modeled timeline left by earlier
+* **launch batching**: a worker on an in-process device PE launches
+  every ready task queued for it together (:func:`_execute_batch`);
+* scheduling: ``round_robin`` (static, placed in submission order at
+  admission), ``data_affinity`` (dynamic, flag-aware), or ``heft`` — a
+  HEFT list scheduler that ranks ready tasks by upward rank and places
+  each with an **insertion-based slot search** (ISSUE 3): a task may
+  slide into an idle gap on a PE's modeled timeline left by earlier
   placements, not just append after the last one.  Costs come from the
   bandwidth model — routed and **contention-aware** when the context
   uses a :class:`~repro.core.topology.TopologyBandwidthModel` — and the
@@ -52,14 +48,8 @@ Shared mechanics (both engines):
 * **deterministic replay** (:func:`replay_schedule`): modeled makespans
   and Gantt lanes are produced by re-simulating the executed schedule in
   (ready-time, submission-index) order — per-link busy-until contention
-  applied when a topology is active — so gated metrics stay exact across
-  runs even though worker wall-clock interleaving varies.
-
-Because every PE here is emulated on one physical CPU, the *measured*
-wall clock understates the win; the executors therefore also simulate
-the schedule they actually executed (modeled transfer + spill-stall
-seconds + static compute estimates) and report a modeled makespan,
-directly comparable to the serial :meth:`Runtime.run` modeled makespan.
+  applied when a topology is active — so modeled metrics stay exact
+  across runs even though worker wall-clock interleaving varies.
 """
 
 from __future__ import annotations
@@ -73,7 +63,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
                     Tuple, TYPE_CHECKING)
 
-from .graph import TaskGraph, TaskNode, build_graph
+from .graph import TaskNode
 from .hete import PrefetchDeferred
 from .instrument import Timeline, TimelineEvent, TransferEvent
 from .locations import HOST
@@ -81,8 +71,7 @@ from .locations import HOST
 if TYPE_CHECKING:  # pragma: no cover - typing only, avoids import cycle
     from .runtime import PE, Runtime, Task
 
-__all__ = ["GraphExecutor", "StreamExecutor", "WorkerPool", "insert_slot",
-           "replay_schedule"]
+__all__ = ["StreamExecutor", "WorkerPool", "insert_slot", "replay_schedule"]
 
 _SHUTDOWN = None
 
@@ -111,10 +100,9 @@ def commit_slot(busy: List[Tuple[float, float]], start: float,
 class WorkerPool:
     """Persistent per-PE worker threads + transfer pool (ISSUE 2).
 
-    Lives on the :class:`Runtime` and is reused by every run —
-    batch ``run_graph`` calls and streaming sessions alike; each queue
-    item is ``(executor_run, payload)`` so the same threads serve
-    successive runs.  ``shutdown`` is only needed for explicit teardown —
+    Lives on the :class:`Runtime` and is reused by every session on it;
+    each queue item is ``(executor_run, payload)`` so the same threads
+    serve successive streams.  ``shutdown`` is only needed for explicit teardown —
     threads are daemons.
     """
 
@@ -256,9 +244,8 @@ def _execute_batch(rt: "Runtime", items: Sequence[Tuple["Task", Optional[Future]
     together (:meth:`Runtime._launch`), then commit each task's outputs
     and release its pins.  A task whose staging, kernel or commit fails
     fails alone.  Returns, per task, ``(w0, w1, tr_s, spill_s, comp_s,
-    out_s, moves)`` — wall bounds plus the modeled accounting both
-    executors feed their schedule simulations — or the exception that
-    failed it."""
+    out_s, moves)`` — wall bounds plus the modeled accounting the stream
+    feeds its schedule simulation — or the exception that failed it."""
     results: list = [None] * len(items)
     staged = []
     for k, (task, fut) in enumerate(items):
@@ -295,9 +282,9 @@ def replay_schedule(rt: "Runtime", nodes: Sequence[TaskNode],
                     topo=None) -> Tuple[Timeline, float]:
     """Deterministically re-simulate an executed schedule.
 
-    The executors' online accounting runs in worker completion order,
-    which varies run to run — fine for scalar sums but not for gated
-    metrics.  This replay processes the recorded placements, transfers
+    The stream's online accounting runs in worker completion order,
+    which varies run to run — fine for scalar sums but not for exact
+    modeled metrics.  This replay processes the recorded placements, transfers
     and compute estimates in (ready-time, submission-index) order: a
     task's input copies are issued the moment its dependencies finish,
     its compute starts when both the staged bytes and the PE are free.
@@ -359,14 +346,58 @@ def replay_schedule(rt: "Runtime", nodes: Sequence[TaskNode],
     return timeline, max(finish.values(), default=0.0)
 
 
-class _ExecutorBase:
-    """Scheduling + prefetch machinery shared by the batch and streaming
-    engines.  Subclasses own run lifecycle and completion bookkeeping;
-    they must provide ``_nodes`` (admitted :class:`TaskNode` list),
-    ``_model_finish``, ``_pe_slots`` and ``_pool``."""
+class StreamExecutor:
+    """Continuous task-stream engine (ISSUE 4) — the execution half of
+    the :class:`repro.core.api.Session` API, and the only way a task
+    reaches a PE.
 
-    def __init__(self, rt: "Runtime", *, scheduler: Optional[str] = None,
-                 prefetch: bool = True) -> None:
+    It **admits** tasks one at a time as the session submits them, and
+    the persistent :class:`WorkerPool` consumes the stream continuously:
+
+    * :meth:`admit` wires a freshly built
+      :class:`~repro.core.graph.TaskNode` into the live run — it
+      dispatches immediately when its dependencies are already complete,
+      otherwise the completion of its last dependency dispatches it.
+      There is **no global barrier**: the ready frontier flows straight
+      onto the PE queues;
+    * **windowed HEFT**: ``heft`` placement ranks only the admitted,
+      incomplete window of the DAG (upward ranks recomputed over what is
+      known *now*, bounded by ``window`` admissions), then places each
+      ready task with the contention-aware insertion-based slot search;
+    * **per-subtree failure**: a failing task fails its dependent
+      subtree — every transitively dependent node is marked failed with
+      the same root cause, surfaced through
+      :class:`~repro.core.api.BufferFuture` results — while independent
+      chains keep flowing.  :meth:`barrier` re-raises the first
+      *unobserved* root failure;
+    * an ``on_done`` callback (index, exception-or-None), invoked under
+      the stream lock at every completion or failure, lets the session
+      resolve futures and release buffer lifecycles out of order, as
+      tasks actually finish.
+    * **launch batching**: on an in-process device PE the worker takes
+      every ready payload queued for it at once, and their tasks share
+      one kernel launch — each task's kernel dispatched, one wait for
+      the device (:meth:`_process_ready`).  Every task keeps its own
+      stage, compute and write-back regions and its own failure;
+      ``metrics`` counts ``launch/<pe>/launches`` and
+      ``launch/<pe>/tasks``.
+
+    Modeled evidence: online accounting (per-PE model clocks, task log,
+    timeline events); :meth:`report` re-simulates everything completed
+    so far with the deterministic :func:`replay_schedule` — call it at a
+    sync point for exact, machine-independent makespans.
+    """
+
+    def __init__(
+        self,
+        rt: "Runtime",
+        *,
+        scheduler: Optional[str] = None,
+        prefetch: bool = True,
+        on_done: Optional[Callable[[int, Optional[BaseException]], None]] = None,
+        window: int = 64,
+        metrics=None,
+    ) -> None:
         from .runtime import SCHEDULERS  # local: no cycle at module load
 
         self.rt = rt
@@ -380,9 +411,40 @@ class _ExecutorBase:
         )
         # cross-client interference (ISSUE 5): ready-but-unplaced tasks of
         # the current dispatch batch, index -> (client, eligible PE names).
-        # The streaming engine fills it so heft placement can charge a
-        # candidate the delay it imposes on *other* clients' ready tasks.
+        # _dispatch fills it so heft placement can charge a candidate the
+        # delay it imposes on *other* clients' ready tasks.
         self._copending: Dict[int, Tuple[Optional[str], frozenset]] = {}
+        self.window = window
+        #: the session's MetricsRegistry, or None
+        self.metrics = metrics
+        self._on_done = on_done
+        # Reentrant: the session serializes GraphBuilder mutations under
+        # this same lock (see state_lock) and admit() re-enters it.
+        self._lock = threading.RLock()
+        self._cv = threading.Condition(self._lock)
+        self._nodes: List[TaskNode] = []
+        self._admitted = 0
+        self._completed: Set[int] = set()
+        self._failed: Dict[int, BaseException] = {}
+        # root failures no barrier/result() raised yet (dependents
+        # cascade-fail with the same exception but count as observed —
+        # the root cause is what the caller must see exactly once)
+        self._unobserved: List[int] = []
+        self._remaining: Dict[int, int] = {}
+        self._static_pe: Dict[int, "PE"] = {}
+        self._model_finish: Dict[int, float] = {}
+        self._pe_model: Dict[str, float] = {pe.name: 0.0 for pe in rt.pes}
+        self._pe_slots: Dict[str, List[Tuple[float, float]]] = {
+            pe.name: [] for pe in rt.pes
+        }
+        self._records: Dict[int, tuple] = {}
+        self.timeline = Timeline()
+        self._closed = False
+        if self._topo is not None:
+            self._topo.reset_contention()
+        self._pool = rt._get_worker_pool()
+        self._pool.runs_served += 1
+        self._t0 = time.perf_counter()
 
     # -- placement ----------------------------------------------------------
     def _staging_delay(self, task: "Task", pe: "PE", at: float) -> float:
@@ -518,357 +580,6 @@ class _ExecutorBase:
         _reap_future(fut)
         self._unprotect(self._nodes[i], pe)
 
-
-class GraphExecutor(_ExecutorBase):
-    """Executes one task list as a DAG on a :class:`Runtime`'s PEs
-    (batch intake — the engine behind the ``run_graph`` compat wrapper;
-    the streaming :class:`StreamExecutor` is the primary entry point)."""
-
-    # -- public entry -------------------------------------------------------
-    def run(self, tasks: Sequence["Task"]) -> Dict[str, Any]:
-        rt = self.rt
-        rt.timeline = Timeline()
-        graph = build_graph(tasks)
-        if not len(graph):
-            rt.last_makespan_model = 0.0
-            return self._report(graph, 0.0)
-
-        self._graph = graph
-        self._nodes = graph.nodes
-        self._lock = threading.Lock()
-        self._done = threading.Event()
-        self._error: Optional[BaseException] = None
-        self._remaining = [len(n.deps) for n in graph.nodes]
-        self._completed = 0
-        self._model_finish: Dict[int, float] = {}
-        self._pe_model: Dict[str, float] = {pe.name: 0.0 for pe in rt.pes}
-        # HEFT insertion-based slot search (ISSUE 3): per-PE sorted busy
-        # intervals on the scheduler's modeled timeline.
-        self._pe_slots: Dict[str, List[Tuple[float, float]]] = {
-            pe.name: [] for pe in rt.pes
-        }
-        if self._topo is not None:
-            self._topo.reset_contention()
-        # per-task execution records feeding the deterministic replay:
-        # (pe name, moves, comp_m, spill_s, out_s, tr_s, comp_s, w0, w1)
-        self._records: Dict[int, tuple] = {}
-        # run lifecycle: late items (after teardown) are abandoned, and
-        # teardown waits until in-flight items leave the workers
-        self._finished = False
-        self._inflight = 0
-        self._quiet = threading.Condition()
-
-        if self.scheduler == "heft":
-            self._rank(graph)
-        # Static policies assign in submission order so placement (and
-        # therefore rimms copy counts) is bit-identical to serial run().
-        self._static: Optional[List["PE"]] = None
-        if self.scheduler == "round_robin":
-            self._static = [rt._schedule(n.task) for n in graph.nodes]
-
-        pool = rt._get_worker_pool()
-        pool.runs_served += 1
-        self._pool = pool
-
-        self._t0 = time.perf_counter()
-        try:
-            with self._lock:
-                ready = [n.index for n in graph.nodes if not n.deps]
-                self._schedule_ready(ready)
-            self._done.wait()
-        finally:
-            with self._quiet:
-                self._finished = True
-                # Wait out in-flight workers FIRST: a completing peer can
-                # still enqueue dependents and prefetch futures (failure
-                # teardown); only after quiescence is the queue content
-                # final.
-                while self._inflight:
-                    self._quiet.wait()
-            # Reap items abandoned on any queue: cancel their prefetch
-            # futures — or wait out started ones — and release their pins
-            # and protection, so no staging outlives the run unaccounted.
-            # (Workers popping later see _finished and abandon likewise.)
-            for payload in pool.drain(self):
-                self._abandon(payload)
-        wall = time.perf_counter() - self._t0
-        if self._error is not None:
-            raise self._error
-        if self._topo is not None:
-            rt.timeline, rt.last_makespan_model = replay_schedule(
-                rt, graph.nodes, self._records, self._topo
-            )
-        else:
-            rt.last_makespan_model = max(
-                self._model_finish.values(), default=0.0
-            )
-        tracer = rt.context.tracer
-        if tracer is not None:
-            run_label = tracer.add_timeline(rt.timeline, label="graph")
-            tracer.add_edges(graph.edges(), run_label)
-        return self._report(graph, wall)
-
-    # -- scheduling ---------------------------------------------------------
-    def _rank(self, graph: TaskGraph) -> None:
-        rt, cm = self.rt, self.rt.cost_model
-        bw = rt.context.ledger.bandwidth_model
-
-        def compute_cost(task: "Task") -> float:
-            kinds = sorted({pe.kind for pe in rt._eligible(task)})
-            return cm.mean_estimate(task.op, kinds, task.in_bytes)
-
-        def comm_cost(task: "Task") -> float:
-            return bw.typical(task.in_bytes)
-
-        graph.compute_ranks(compute_cost, comm_cost)
-
-    def _schedule_ready(self, indices: List[int]) -> None:
-        """Assign + enqueue newly-ready nodes (under the state lock).
-        HEFT processes the batch highest-upward-rank first.  Each node's
-        inputs are protected at its PE until completion — the contract
-        behind capacity-aware prefetch.  Prefetch stagings are issued
-        least-contended-route-first (ISSUE 4 satellite); PE queue order
-        keeps the assignment order."""
-        nodes = self._graph.nodes
-        ctx = self.rt.context
-        if self.scheduler == "heft":
-            indices = sorted(indices, key=lambda i: -nodes[i].rank)
-        assigned: List[Tuple[int, "PE"]] = []
-        for i in indices:
-            node = nodes[i]
-            pe = self._static[i] if self._static is not None else self._pick_pe(node)
-            for hd in node.task.inputs:
-                ctx.protect(hd, pe.location)
-            assigned.append((i, pe))
-        futs: Dict[int, Future] = {}
-        if self.prefetch:
-            # Prefetch: stage inputs now, possibly while the PE is still
-            # busy with an earlier task — transfer/compute overlap.
-            for i, pe in self._prefetch_order(assigned):
-                futs[i] = self._pool.transfer.submit(
-                    self._prefetch_stage, nodes[i].task, pe
-                )
-        for i, pe in assigned:
-            self._pool.submit(self, pe.name, (i, pe, futs.get(i)))
-
-    # -- workers ------------------------------------------------------------
-    def _process_ready(self, pe: "PE", payloads: List[tuple]) -> None:
-        """One launch per payload: the batch engine halts at its first
-        failure, so a task queued behind a failed one never runs."""
-        for payload in payloads:
-            self._process(pe, payload)
-
-    def _process(self, pe: "PE", payload: tuple) -> None:
-        """Execute one queued payload on its PE worker thread.  Called by
-        the persistent pool; must never kill the worker thread."""
-        with self._quiet:
-            if self._finished:
-                live = False
-            else:
-                live = True
-                self._inflight += 1
-        if not live:
-            self._abandon(payload)
-            return
-        try:
-            if self._error is not None:
-                # A peer already failed: drain without executing.
-                self._abandon(payload)
-                return
-            i, pe_assigned, fut = payload
-            node = self._graph.nodes[i]
-            (res,) = _execute_batch(self.rt, [(node.task, fut)], pe_assigned)
-            # This task no longer reads its inputs: release the queued-reader
-            # claim exactly once, before dependents are scheduled (inside
-            # _complete).
-            self._unprotect(node, pe_assigned)
-            try:
-                if isinstance(res, BaseException):
-                    raise res
-                # _complete can itself raise while scheduling newly-ready
-                # dependents (unknown pin, op with no eligible PE) — it
-                # must stay inside the except so the run never hangs.
-                self._complete(node, pe_assigned, *res)
-            except BaseException as e:  # surface to the caller, stop the run
-                with self._lock:
-                    if self._error is None:
-                        self._error = e
-                self._done.set()
-        finally:
-            with self._quiet:
-                self._inflight -= 1
-                self._quiet.notify_all()
-
-    def _complete(
-        self,
-        node: TaskNode,
-        pe: "PE",
-        w0: float,
-        w1: float,
-        tr_s: float,
-        spill_s: float,
-        comp_s: float,
-        out_s: float,
-        moves: Sequence[tuple] = (),
-    ) -> None:
-        rt = self.rt
-        with self._lock:
-            # Schedule simulation: this task's transfers could start once
-            # its inputs existed (ready_m), overlapping the PE's previous
-            # compute; its compute starts when both the PE and the staged
-            # inputs are available.  Spill stalls extend staging.
-            ready_m = self._ready_m(node)
-            # Static compute estimate, not contended measured seconds —
-            # keeps the simulation comparable to serial run() (see
-            # CostModel.prior_estimate).
-            comp_m = rt.cost_model.prior_estimate(
-                node.task.op, pe.kind, node.task.in_bytes
-            )
-            stage_s = tr_s + spill_s
-            compute_start_m = max(self._pe_model[pe.name], ready_m + stage_s)
-            finish_m = compute_start_m + comp_m + out_s
-            self._pe_model[pe.name] = finish_m
-            self._model_finish[node.index] = finish_m
-            rt.timeline.add(TimelineEvent(
-                task=node.name, pe=pe.name,
-                wall_start=w0 - self._t0, wall_end=w1 - self._t0,
-                model_start=max(ready_m, compute_start_m - stage_s),
-                model_end=finish_m,
-                transfer_s=tr_s, compute_s=comp_s, out_transfer_s=out_s,
-                spill_s=spill_s, compute_start_m=compute_start_m,
-                node=node.index,
-            ))
-            rt.task_log.append((node.name, pe.name))
-            self._records[node.index] = (
-                pe.name, tuple(moves), comp_m, spill_s, out_s, tr_s,
-                comp_s, w0 - self._t0, w1 - self._t0,
-            )
-            self._completed += 1
-            newly_ready: List[int] = []
-            for s in node.dependents:
-                self._remaining[s] -= 1
-                if self._remaining[s] == 0:
-                    newly_ready.append(s)
-            # A peer failed: the run is tearing down — don't feed new
-            # work (or prefetch staging) into a dying run.
-            if newly_ready and self._error is None:
-                self._schedule_ready(newly_ready)
-            if self._completed == len(self._graph):
-                self._done.set()
-
-    # -- reporting ----------------------------------------------------------
-    def _report(self, graph: TaskGraph, wall: float) -> Dict[str, Any]:
-        rt = self.rt
-        per_pe: Dict[str, float] = {}
-        for ev in rt.timeline.events():
-            per_pe[ev.pe] = per_pe.get(ev.pe, 0.0) + (ev.model_end - ev.model_start)
-        ledger = rt.context.ledger
-        return {
-            "wall_s": wall,
-            "makespan_model": rt.last_makespan_model,
-            "n_tasks": len(graph),
-            "n_edges": graph.n_edges,
-            "critical_path": graph.critical_path_len,
-            "scheduler": self.scheduler,
-            "policy": rt.policy,
-            "prefetch": self.prefetch,
-            "topology": self._topo.name if self._topo is not None else None,
-            "per_pe_busy_model_s": per_pe,
-            "timeline": rt.timeline,
-            "spill_stall_model_s": rt.timeline.total_spill_s,
-            "evictions": ledger.total_evictions,
-            "prefetch_deferrals": ledger.prefetch_deferrals,
-        }
-
-
-class StreamExecutor(_ExecutorBase):
-    """Continuous task-stream engine (ISSUE 4) — the execution half of
-    the primary :class:`repro.core.api.Session` API.
-
-    Where :class:`GraphExecutor` takes a whole task list and runs it to
-    completion, this engine **admits** tasks one at a time as the
-    session submits them, and the persistent :class:`WorkerPool`
-    consumes the stream continuously:
-
-    * :meth:`admit` wires a freshly built
-      :class:`~repro.core.graph.TaskNode` into the live run — it
-      dispatches immediately when its dependencies are already complete,
-      otherwise the completion of its last dependency dispatches it.
-      There is **no global barrier**: the ready frontier flows straight
-      onto the PE queues;
-    * **windowed HEFT**: ``heft`` placement ranks only the admitted,
-      incomplete window of the DAG (upward ranks recomputed over what is
-      known *now*, bounded by ``window`` admissions), then places each
-      ready task with the shared contention-aware insertion-based slot
-      search;
-    * **per-subtree failure**: a failing task fails its dependent
-      subtree — every transitively dependent node is marked failed with
-      the same root cause, surfaced through
-      :class:`~repro.core.api.BufferFuture` results — while independent
-      chains keep flowing.  :meth:`barrier` re-raises the first
-      *unobserved* root failure;
-    * an ``on_done`` callback (index, exception-or-None), invoked under
-      the stream lock at every completion or failure, lets the session
-      resolve futures and release buffer lifecycles out of order, as
-      tasks actually finish.
-    * **launch batching**: on an in-process device PE the worker takes
-      every ready payload queued for it at once, and their tasks share
-      one kernel launch — each task's kernel dispatched, one wait for
-      the device (:meth:`_process_ready`).  Every task keeps its own
-      stage, compute and write-back regions and its own failure;
-      ``metrics`` counts ``launch/<pe>/launches`` and
-      ``launch/<pe>/tasks``.
-
-    Modeled evidence: online accounting mirrors the batch engine
-    (per-PE model clocks, task log, timeline events); :meth:`report`
-    re-simulates everything completed so far with the deterministic
-    :func:`replay_schedule` — call it at a sync point for exact,
-    machine-independent makespans (the bench_stream CI gate does).
-    """
-
-    def __init__(
-        self,
-        rt: "Runtime",
-        *,
-        scheduler: Optional[str] = None,
-        prefetch: bool = True,
-        on_done: Optional[Callable[[int, Optional[BaseException]], None]] = None,
-        window: int = 64,
-        metrics=None,
-    ) -> None:
-        super().__init__(rt, scheduler=scheduler, prefetch=prefetch)
-        self.window = window
-        #: the session's MetricsRegistry, or None
-        self.metrics = metrics
-        self._on_done = on_done
-        # Reentrant: the session serializes GraphBuilder mutations under
-        # this same lock (see state_lock) and admit() re-enters it.
-        self._lock = threading.RLock()
-        self._cv = threading.Condition(self._lock)
-        self._nodes: List[TaskNode] = []
-        self._admitted = 0
-        self._completed: Set[int] = set()
-        self._failed: Dict[int, BaseException] = {}
-        # root failures no barrier/result() raised yet (dependents
-        # cascade-fail with the same exception but count as observed —
-        # the root cause is what the caller must see exactly once)
-        self._unobserved: List[int] = []
-        self._remaining: Dict[int, int] = {}
-        self._static_pe: Dict[int, "PE"] = {}
-        self._model_finish: Dict[int, float] = {}
-        self._pe_model: Dict[str, float] = {pe.name: 0.0 for pe in rt.pes}
-        self._pe_slots: Dict[str, List[Tuple[float, float]]] = {
-            pe.name: [] for pe in rt.pes
-        }
-        self._records: Dict[int, tuple] = {}
-        self.timeline = Timeline()
-        self._closed = False
-        if self._topo is not None:
-            self._topo.reset_contention()
-        self._pool = rt._get_worker_pool()
-        self._pool.runs_served += 1
-        self._t0 = time.perf_counter()
-
     # -- admission ----------------------------------------------------------
     @property
     def state_lock(self) -> threading.Condition:
@@ -892,9 +603,9 @@ class StreamExecutor(_ExecutorBase):
             self._nodes.append(node)
             self._admitted += 1
             if self.scheduler == "round_robin":
-                # Static placement at admission (submission order), so a
-                # single-threaded stream is bit-identical to serial
-                # dispatch — same contract as batch round_robin.
+                # Static placement at admission (submission order), so
+                # the stream places exactly as the same submissions
+                # driven one task at a time do.
                 try:
                     self._static_pe[node.index] = self.rt._schedule(node.task)
                 except BaseException as e:
@@ -999,10 +710,10 @@ class StreamExecutor(_ExecutorBase):
             payloads = payloads[n:]
 
     def _process(self, pe: "PE", payloads: List[tuple]) -> None:
-        """Execute payloads as one launch on their PE worker thread.
-        Unlike the batch engine, a peer's failure does not drain the
-        stream — only the failing task's dependent subtree is failed.  A
-        launch that completed tasks counts in :attr:`metrics`."""
+        """Execute payloads as one launch on their PE worker thread.  A
+        peer's failure does not drain the stream — only the failing
+        task's dependent subtree is failed.  A launch that completed
+        tasks counts in :attr:`metrics`."""
         if self._closed:
             for payload in payloads:
                 self._abandon(payload)
@@ -1060,8 +771,11 @@ class StreamExecutor(_ExecutorBase):
                   moves: Sequence[tuple]) -> None:
         rt = self.rt
         with self._cv:
-            # Online schedule simulation — same arithmetic as the batch
-            # engine, so modeled makespans stay directly comparable.
+            # Online schedule simulation: this task's transfers could
+            # start once its inputs existed (ready_m), overlapping the
+            # PE's previous compute; its compute starts when both the PE
+            # and the staged inputs are free.  Spill stalls extend
+            # staging.
             ready_m = self._ready_m(node)
             comp_m = rt.cost_model.prior_estimate(
                 node.task.op, pe.kind, node.task.in_bytes

@@ -250,9 +250,9 @@ class CostModel:
 
     def prior_estimate(self, op: str, pe_kind: str, nbytes: int) -> float:
         """Static estimate — deterministic, used for the schedule
-        *simulation* so serial and graph modeled makespans are directly
-        comparable (measured kernel times on this box are inflated by
-        cross-PE CPU contention in graph mode).  A measured calibration
+        *simulation* so modeled makespans are exact from run to run
+        (measured kernel times on this box are inflated by cross-PE CPU
+        contention).  A measured calibration
         cell for this exact (op, kind, bucket) wins; missing cells fall
         back to the throughput prior."""
         if self._calibration is not None:

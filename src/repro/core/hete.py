@@ -25,7 +25,7 @@ benchmarked separately; default is the paper's flag-only behaviour).
 
 Thread safety: each :class:`HeteData` carries a lock serializing
 ``ensure``/``mark_written`` on that buffer, and arena reservations go
-through a context-wide lock — the graph executor stages inputs from a
+through a context-wide lock — the stream executor stages inputs from a
 transfer pool concurrently with PE workers committing outputs.
 
 Capacity pressure (ISSUE 2): device arenas behave like a managed cache
@@ -36,7 +36,7 @@ writes dirty bytes back to host *through the existing coherence paths*
 (fragment aliasing preserved), frees their extents and retries.
 ``AllocError`` surfaces only when the pinned working set genuinely
 exceeds capacity.  ``pin``/``unpin`` (and the ``pinned`` context
-manager) bound eviction; the graph executor additionally *protects*
+manager) bound eviction; the stream executor additionally *protects*
 bytes that queued tasks still read so prefetch never spills them
 (prefetch under pressure defers instead — :class:`PrefetchDeferred`).
 
@@ -102,7 +102,7 @@ __all__ = [
 class PrefetchDeferred(Exception):
     """Raised inside a :meth:`HeteContext.prefetch_guard` scope when a
     reservation would have to evict pinned or *protected* bytes (bytes a
-    queued task still reads).  The graph executor catches it and falls
+    queued task still reads).  The stream executor catches it and falls
     back to staging on the PE worker at execute time, when earlier tasks
     have released their claims."""
 
@@ -342,7 +342,7 @@ class HeteContext:
         # so process PE workers map them zero-copy.  None -> heap numpy.
         self.host_arena = None
         # -- tracing (ISSUE 6): off by default; a process-global collector
-        # (benchmarks/run.py --trace-dir) captures contexts at creation.
+        # (trace.install_global) captures contexts at creation.
         self.tracer = None
         _global_tracer = trace_mod.global_collector()
         if _global_tracer is not None:
@@ -1003,7 +1003,7 @@ class HeteContext:
 
     def stage(self, hd: HeteData, dst: Location) -> Tuple[Any, float]:
         """:meth:`ensure` + report of the modeled seconds of the copy it
-        performed (0.0 on a flag hit).  The graph executor uses the
+        performed (0.0 on a flag hit).  The stream executor uses the
         second element for schedule simulation."""
         self.ledger.record_flag_check()
         if hd.freed:

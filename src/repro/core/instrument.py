@@ -7,7 +7,7 @@ ledger we can assert those counts exactly, and additionally integrate a
 modeled transfer time under configurable link bandwidths.
 
 Both :class:`TransferLedger` and :class:`Timeline` are thread-safe: the
-graph executor (:mod:`repro.core.executor`) records from one worker
+stream executor (:mod:`repro.core.executor`) records from one worker
 thread per PE plus a transfer pool concurrently.
 """
 
@@ -190,8 +190,8 @@ class TransferLedger:
         weight``): with equal weights it measures how equally the runtime
         served the clients; with configured weights, 1.0 means service
         landed exactly in the weight ratios.  ``clients`` restricts the
-        index to a subset (e.g. the equal-demand light tenants in
-        ``bench_multitenant`` — comparing tenants with deliberately
+        index to a subset (e.g. the equal-demand light tenants of a
+        mix — comparing tenants with deliberately
         unequal demands would conflate demand with unfairness);
         ``weights`` default to 1.0 per client.
         """

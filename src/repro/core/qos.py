@@ -33,8 +33,7 @@ arbitration layer between submitters and the
   through its window and the DRR queue in *modeled* time, so per-client
   latency and fairness metrics depend only on every client's own
   submission order (deterministic) — never on wall-clock thread
-  interleaving — and can be gated in CI byte-exactly
-  (``benchmarks/bench_multitenant.py``).
+  interleaving — and are exact from run to run.
 
 Per-client observability (task/byte/stall/eviction counters and the
 Jain's-index ``fairness_report``) lives on the
@@ -496,7 +495,7 @@ def fair_replay(
 
     Returns ``(timeline, modeled makespan, finish, release)`` with
     ``finish``/``release`` keyed by node index — the per-chain latency
-    evidence ``bench_multitenant`` gates on.
+    evidence of :meth:`~repro.core.api.Session.qos_report`.
     """
     params = qos.params() if isinstance(qos, QoSManager) else dict(qos or {})
     cfg = params.get("clients", {})

@@ -15,24 +15,14 @@ Two memory policies, both first-class so every experiment reports the pair:
   check, direct src→PE copy only when the flag names another location,
   output flag update to the executing PE (Fig 1b).
 
-The **primary public entry point is the streaming session API**
-(:mod:`repro.core.api`, ISSUE 4): ``@rimms.op``-registered kernels,
+Tasks reach PEs through one path: the streaming session API
+(:mod:`repro.core.api`) — ``@rimms.op``-registered kernels,
 ``Session.malloc``/``Session.submit`` returning
-:class:`~repro.core.api.BufferFuture` handles, and the persistent
-:class:`~repro.core.executor.StreamExecutor` consuming the task stream
-continuously.  This class is the **dispatch engine behind it** — the
-session drives the same stage → execute → commit pipeline, scheduler
-cost bases, and kernel registry defined here.
-
-Two batch execution modes are kept as thin compat wrappers over that
-pipeline:
-
-* :meth:`Runtime.run` — serial, submission order (CEDR's API-level
-  serialization);
-* :meth:`Runtime.run_graph` — the batch task-graph executor
-  (:class:`~repro.core.executor.GraphExecutor`): automatic DAG
-  construction, one worker per PE, input prefetch overlapping transfers
-  with compute.
+:class:`~repro.core.api.BufferFuture` handles — whose persistent
+:class:`~repro.core.executor.StreamExecutor` consumes the task stream
+continuously.  This class is the **dispatch engine behind it**: the
+stage → launch → commit steps each task goes through, the scheduler
+cost bases, and the kernel registry.
 
 PEs are emulated on this CPU-only box: a "cpu" PE executes numpy
 callables against host memory; accelerator PEs ("fft_acc", "zip_acc",
@@ -49,7 +39,6 @@ import dataclasses
 import itertools
 import os
 import time
-import warnings
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -57,7 +46,6 @@ import numpy as np
 
 from .graph import CostModel
 from .hete import HeteContext, HeteData, MemorySpace
-from .instrument import Timeline, TimelineEvent
 from .locations import HOST, Location
 from .telemetry import DivergenceMonitor
 from .trace import NULL_REGION
@@ -71,7 +59,7 @@ __all__ = ["PE", "Task", "Runtime", "make_emulated_soc", "SCHEDULERS",
 # ---------------------------------------------------------------------------
 
 #: valid values for the ``backend=`` knob (Session / Session.emulated /
-#: Runtime / make_emulated_soc / benchmarks).
+#: Runtime / make_emulated_soc).
 BACKENDS = ("thread", "process", "auto")
 
 
@@ -238,11 +226,8 @@ class Runtime:
         #: and benches assert selection through this log
         self.variant_log: List[tuple] = []
         self.task_log: List[tuple] = []  # (task name/op, pe name) for tests
-        self.timeline = Timeline()  # replaced per run/run_graph
-        self.last_makespan_model = 0.0
-        self.last_report: Optional[Dict[str, Any]] = None  # set by run_graph
-        # persistent per-PE worker pool, created lazily by run_graph and
-        # reused across calls (ISSUE 2); close() releases it
+        # persistent per-PE worker pool, created lazily by the first
+        # session and reused by later ones (ISSUE 2); close() releases it
         self._worker_pool = None
         # per-PE subprocess workers (ISSUE 7), created lazily on the
         # first process-dispatched kernel; close() reaps them
@@ -292,23 +277,6 @@ class Runtime:
             self._process_pool.shutdown()
             self._process_pool = None
 
-    def reset_stats(self) -> None:
-        """Clear per-run diagnostics and dispatch state: the task log,
-        round-robin rotation, timeline, and last modeled makespan/report.
-        Called at the start of every :meth:`run`/:meth:`run_graph`, so
-        repeated batch runs neither accumulate log entries nor leak
-        round-robin placement state across runs (ISSUE 4 satellite) —
-        ``task_log`` after a run is exactly that run's placements, and
-        identical task lists place identically on every run.  Streaming
-        sessions deliberately do *not* reset between barriers: the
-        stream is one continuous run."""
-        self.task_log = []
-        self.variant_log = []
-        self._rr_state = {}
-        self.timeline = Timeline()
-        self.last_makespan_model = 0.0
-        self.last_report = None
-
     # -- registration -------------------------------------------------------
     def register_kernel(self, op: str, pe_kind: str, fn: Callable, *,
                         variant: Optional[str] = None,
@@ -348,26 +316,19 @@ class Runtime:
         return pes
 
     def _schedule(self, task: Task) -> PE:
+        """Static placement at admission under ``round_robin`` (paper
+        §5.4): the pinned PE, else the next eligible PE in a per-op
+        rotation."""
         if task.pin is not None:
             return self.by_name[task.pin]
         pes = self._eligible(task)
-        if self.scheduler == "round_robin":
-            i = self._rr_state.get(task.op, 0)
-            self._rr_state[task.op] = (i + 1) % len(pes)
-            return pes[i % len(pes)]
-        if self.scheduler == "heft":
-            # Transfer-aware greedy pick: minimize modeled staging cost +
-            # estimated compute (per-PE availability is the executor's
-            # refinement; serial dispatch has no queues to account for).
-            return min(pes, key=lambda pe: (sum(self._heft_costs(task, pe)),
-                                            pe.name))
-        # data_affinity (beyond-paper)
-        return self._affinity_pick(task, pes)
+        i = self._rr_state.get(task.op, 0)
+        self._rr_state[task.op] = (i + 1) % len(pes)
+        return pes[i % len(pes)]
 
     def _affinity_pick(self, task: Task, pes: Sequence[PE]) -> PE:
         """Most input bytes already valid at the PE; ties broken by stable
-        PE-name ordering (deterministic).  Shared by serial dispatch and
-        the graph executor."""
+        PE-name ordering (deterministic)."""
         def score(pe: PE) -> int:
             return sum(
                 hd.nbytes for hd in task.inputs if hd.last_location == pe.location
@@ -376,8 +337,8 @@ class Runtime:
 
     def _heft_costs(self, task: Task, pe: PE) -> Tuple[float, float]:
         """(modeled input-transfer seconds, estimated compute seconds) for
-        placing ``task`` on ``pe`` — the shared EFT cost basis for serial
-        heft dispatch and the graph executor's placement."""
+        placing ``task`` on ``pe`` — the EFT cost basis of the stream's
+        HEFT placement."""
         bw = self.context.ledger.bandwidth_model
         tr = sum(
             bw.seconds(hd.last_location, pe.location, hd.nbytes)
@@ -386,7 +347,7 @@ class Runtime:
         )
         return tr, self.cost_model.estimate(task.op, pe.kind, task.in_bytes)
 
-    # -- stage → execute → commit (shared by serial and graph modes) ---------
+    # -- stage → launch → commit ---------------------------------------------
     def _pin_inputs(self, task: Task, loc: Location) -> None:
         """Hard-pin every input's root at ``loc`` so eviction triggered by
         a concurrent (or this task's own output) reservation can never
@@ -609,7 +570,7 @@ class Runtime:
         ``stage``, ``compute`` or ``writeback``, on the PE's track, with
         ``stats`` beside the task's own (``prefetch=1``; a compute's
         ``launch`` and ``batch``) — or the shared null context when
-        tracing is off.  Both executors and the serial path enter it."""
+        tracing is off."""
         tracer = self.context.tracer
         if tracer is None:
             return NULL_REGION
@@ -617,152 +578,6 @@ class Runtime:
         track = f"pe:{pe.name}:stage" if cat == "stage" else f"pe:{pe.name}"
         return tracer.region(tname, cat, track, task=tname, op=task.op,
                              pe=pe.name, **stats)
-
-    def _add_transfer_lanes(self, topo, task: Task, moves: Sequence[tuple],
-                            start: float, node: int = -1) -> float:
-        """Record per-link :class:`TransferEvent` lanes for ``moves``
-        issued *concurrently* at modeled time ``start``, walking each
-        copy's route through per-link busy-until contention (ISSUE 4
-        satellite): copies on disjoint routes overlap, copies sharing a
-        link queue behind each other — and behind earlier tasks' traffic,
-        since link state persists across the run.  This is exactly the
-        pricing the graph executor's replay applies, so serial vs graph
-        topology comparisons are apples-to-apples (previously serial
-        summed uncontended store-and-forward hop times).  Returns the
-        modeled staging duration (last byte delivered − ``start``)."""
-        from .instrument import TransferEvent
-
-        end_max = start
-        for src, dst, nbytes in moves:
-            _, end, hops = topo.transfer(src, dst, nbytes, at=start,
-                                         commit=True)
-            for link, hs, he in hops:
-                self.timeline.add_transfer(TransferEvent(
-                    link=link.label, task=task.name or task.op,
-                    nbytes=nbytes, model_start=hs, model_end=he,
-                    node=node,
-                ))
-            end_max = max(end_max, end)
-        return end_max - start
-
-    # -- execution --------------------------------------------------------------
-    def run(self, tasks: Sequence[Task]) -> float:
-        """Execute tasks serially in submission order (data deps are
-        submission-ordered by the apps, matching CEDR's API-level
-        serialization).  Returns wall seconds; fills :attr:`timeline` and
-        :attr:`last_makespan_model` for comparison against graph mode.
-
-        .. deprecated:: ISSUE 7
-           Compat wrapper — prefer the streaming session API
-           (:class:`repro.core.api.Session`).  Emits one
-           :class:`DeprecationWarning` per process; internal callers
-           (the session, benchmarks' serial baselines) use
-           :meth:`_run_impl` directly, so the warning always points at
-           user code."""
-        _warn_deprecated("run")
-        return self._run_impl(tasks)
-
-    def _run_impl(self, tasks: Sequence[Task]) -> float:
-        """Serial dispatch body — the reference every equivalence/
-        copy-count claim compares against (no deprecation warning)."""
-        self.reset_stats()
-        topo = getattr(self.context.ledger.bandwidth_model, "topology", None)
-        if topo is not None:
-            topo.reset_contention()
-        model_t = 0.0
-        t0 = time.perf_counter()
-        for node_i, task in enumerate(tasks):
-            pe = self._schedule(task)
-            w0 = time.perf_counter()
-            with self._region("stage", task, pe):
-                ins, tr_s, sp_s, moves = self._stage_inputs(task, pe)
-            w_staged = time.perf_counter()
-            try:
-                (run,) = self._launch([task], pe, [ins])
-                if isinstance(run, BaseException):
-                    raise run
-                outs, comp_s = run
-                out_s, sp2_s = self._commit_outputs(task, pe, outs)
-            finally:
-                self._unpin_inputs(task, pe.location)
-            w1 = time.perf_counter()
-            self.divergence.observe(
-                "stage", task.op, pe.kind, task.in_bytes,
-                w_staged - w0, tr_s + sp_s)
-            spill_s = sp_s + sp2_s
-            stage_m = tr_s
-            if topo is not None:
-                # Routed transfer lanes over modeled time: this task's
-                # copies issue concurrently at model_t and queue on
-                # shared links (per-link contention, like graph replay).
-                stage_m = self._add_transfer_lanes(topo, task, moves,
-                                                   model_t, node=node_i)
-            # Model simulation uses the static compute estimate so serial
-            # and graph modeled makespans are directly comparable (see
-            # CostModel.prior_estimate).  Spill stalls (eviction
-            # write-backs under capacity pressure) extend the task's
-            # modeled interval exactly like transfers do.
-            comp_m = self.cost_model.prior_estimate(task.op, pe.kind, task.in_bytes)
-            dur_m = stage_m + spill_s + comp_m + out_s
-            self.timeline.add(TimelineEvent(
-                task=task.name or task.op, pe=pe.name,
-                wall_start=w0 - t0, wall_end=w1 - t0,
-                model_start=model_t, model_end=model_t + dur_m,
-                transfer_s=tr_s, compute_s=comp_s, out_transfer_s=out_s,
-                spill_s=spill_s,
-                compute_start_m=model_t + stage_m + spill_s, node=node_i,
-            ))
-            model_t += dur_m
-            self.task_log.append((task.name or task.op, pe.name))
-        self.last_makespan_model = model_t
-        tracer = self.context.tracer
-        if tracer is not None:
-            tracer.add_timeline(self.timeline, label="serial")
-        return time.perf_counter() - t0
-
-    def run_graph(
-        self,
-        tasks: Sequence[Task],
-        *,
-        scheduler: Optional[str] = None,
-        prefetch: bool = True,
-    ) -> float:
-        """Execute ``tasks`` on the async task-graph executor: automatic
-        RAW/WAR/WAW DAG, one worker per PE, input prefetch overlapping
-        transfers with compute, and transfer-aware placement when
-        ``scheduler='heft'``.  Same ledger and memory policies as
-        :meth:`run`; under the ``rimms`` policy with static scheduling the
-        copy counts and outputs are identical to serial execution.
-
-        Returns wall seconds; :attr:`timeline`, :attr:`last_makespan_model`
-        and :attr:`last_report` carry the schedule evidence.
-
-        .. deprecated:: ISSUE 7
-           Compat wrapper — prefer the streaming session API
-           (:class:`repro.core.api.Session`), which drives the same
-           worker pool continuously.  Emits one
-           :class:`DeprecationWarning` per process; internal callers use
-           :meth:`_run_graph_impl`.
-        """
-        _warn_deprecated("run_graph")
-        return self._run_graph_impl(tasks, scheduler=scheduler,
-                                    prefetch=prefetch)
-
-    def _run_graph_impl(
-        self,
-        tasks: Sequence[Task],
-        *,
-        scheduler: Optional[str] = None,
-        prefetch: bool = True,
-    ) -> float:
-        """Batch graph-executor body (no deprecation warning)."""
-        from .executor import GraphExecutor  # local import: avoids cycle
-
-        self.reset_stats()
-        ex = GraphExecutor(self, scheduler=scheduler, prefetch=prefetch)
-        report = ex.run(tasks)
-        self.last_report = report
-        return report["wall_s"]
 
 
 def _attempt(fn: Callable, *args) -> Any:
@@ -794,26 +609,6 @@ def _wait_for_device(runs: list) -> list:
 
 def _as_tuple(x: Any) -> tuple:
     return x if isinstance(x, tuple) else (x,)
-
-
-# One DeprecationWarning per process (ISSUE 7 satellite): the first
-# Runtime.run / run_graph call warns, later ones stay quiet so batch
-# loops don't flood stderr.
-_deprecation_warned = False
-
-
-def _warn_deprecated(which: str) -> None:
-    global _deprecation_warned
-    if _deprecation_warned:
-        return
-    _deprecation_warned = True
-    warnings.warn(
-        f"Runtime.{which}() is a compat wrapper and is deprecated; use the "
-        f"streaming session API instead (repro.core.api.Session / "
-        f"Session.emulated — see the README migration table).",
-        DeprecationWarning,
-        stacklevel=3,
-    )
 
 
 # ---------------------------------------------------------------------------

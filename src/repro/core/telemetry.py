@@ -13,8 +13,7 @@ except :mod:`repro.core.trace`, so every layer may import it):
     :meth:`DivergenceMonitor.save_json` and fold it back with
     :meth:`DivergenceMonitor.load_json`.  Each :class:`Runtime` owns one
     monitor; :func:`aggregate_divergence` merges every monitor created
-    since a serial mark (how ``benchmarks/run.py --metrics-dir`` scopes
-    tables per bench).
+    since a serial mark.
 
 ``Sampler``
     A bounded-overhead background sampler over one :class:`Session`:
@@ -262,8 +261,7 @@ class DivergenceMonitor:
         return mon
 
 
-# One DeprecationWarning per process (same pattern as the ISSUE-7
-# Runtime.run/run_graph deprecation): the first raw divergence-JSON call
+# One DeprecationWarning per process: the first raw divergence-JSON call
 # warns, later ones stay quiet.
 _divergence_json_warned = False
 
@@ -298,7 +296,7 @@ class Sampler:
     sample dict to the bounded :attr:`samples` ring.  The work per tick
     is O(PEs + arenas + links + tenants) dictionary reads — no kernel
     path is touched, so overhead is bounded by the period, not the task
-    rate (gated in ``bench_overhead.py``).
+    rate.
     """
 
     def __init__(self, session, *, period: float = 0.0,

@@ -11,10 +11,10 @@ module imports only the stdlib, so every other layer may import it):
     ``jax.profiler.TraceAnnotation`` named ``rimms.<category>`` (so a
     profiler trace holds them on the device's clock, with their stats);
     on exit, the same span in the thread's ring.
-    Modeled-time events are derived in bulk from ``Timeline`` objects
-    pushed at sync points (end of ``Runtime.run`` / ``GraphExecutor.run``
-    / ``Session.close``), so the deterministic replay timebase costs
-    nothing while tasks execute.  ``export()`` writes Chrome/Perfetto
+    Modeled-time events are derived in bulk from the ``Timeline`` a
+    session pushes at its sync point (``Session.close`` or
+    ``Session.export_trace``), so the deterministic replay timebase
+    costs nothing while tasks execute.  ``export()`` writes Chrome/Perfetto
     trace-event JSON with two process groups — pid 1 "wall clock",
     pid 2 "modeled time" — and one track per PE, per interconnect link,
     and per tenant in each group.  Open the file in ui.perfetto.dev.
@@ -33,13 +33,13 @@ module imports only the stdlib, so every other layer may import it):
     (conservation holds by construction — the ledger itself emits the
     trace event under its lock), and no modeled compute span starting
     before its staging spans end.  ``python -m repro.core.trace f.json``
-    runs it from the command line; CI uses it as a fail-fast gate.
+    runs it from the command line.
 
 Tracing is off by default.  Enable per session via
 ``Session(trace=True)``, scoped via the ``trace()`` context manager, or
 process-wide via ``install_global()`` (newly created ``HeteContext``
-objects auto-attach — this is how ``benchmarks/run.py --trace-dir``
-traces every benchmark without touching bench internals).
+objects auto-attach, so a whole program is traced without touching its
+internals).
 """
 
 from __future__ import annotations
@@ -570,8 +570,8 @@ def install_global(collector: Optional[TraceCollector]) -> None:
     """Install a process-global collector (or None to uninstall).
 
     ``HeteContext`` instances created while one is installed attach to
-    it automatically — used by ``benchmarks/run.py --trace-dir`` to
-    trace whole benchmarks without touching their internals.
+    it automatically, so a whole program is traced without touching
+    its internals.
     """
     global _global
     _global = collector

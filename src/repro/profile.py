@@ -16,9 +16,7 @@ trace, a markdown report:
   :meth:`~repro.core.trace.TraceCollector.set_divergence`) rendered as
   markdown.
 
-CI runs this over every smoke-bench trace and posts the output to the
-job summary; a missing/malformed trace exits non-zero so the gate
-fails fast.
+A missing or malformed trace exits non-zero.
 """
 
 from __future__ import annotations

@@ -26,8 +26,7 @@ from repro.core.api import (
 )
 from repro.core.autotune import Tunable, autotune, register_tunables, tunables
 from repro.core.calibrate import (
-    DEFAULT_VARIANT, CalibrationTable, calibrate, heft_plan,
-    resolve_calibration, simulate_plan,
+    DEFAULT_VARIANT, CalibrationTable, calibrate, resolve_calibration,
 )
 from repro.core.graph import CostModel
 from repro.core.locations import HOST, Location
@@ -44,8 +43,7 @@ __all__ = [
     "op", "OpRegistry", "OpVariant", "default_registry", "DEFAULT_VARIANT",
     # calibration + autotuning (ISSUE 10)
     "CalibrationTable", "calibrate", "resolve_calibration", "autotune",
-    "register_tunables", "tunables", "Tunable", "heft_plan",
-    "simulate_plan", "CostModel",
+    "register_tunables", "tunables", "Tunable", "CostModel",
     # platforms / backends
     "register_platform", "platform_names", "BACKENDS", "resolve_backend",
     "HOST", "Location",

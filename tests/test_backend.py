@@ -1,11 +1,10 @@
 """Process PE-worker backend (ISSUE 7): backend selection, shared-memory
 host arenas, thread↔process bit-identity + copy-count parity, worker
-failure containment, subprocess lifecycle, platform presets, deprecation
-of the batch wrappers, and closed-loop think time in the QoS replay."""
+failure containment, subprocess lifecycle, platform presets, and
+closed-loop think time in the QoS replay."""
 
 import os
 import time
-import warnings
 
 import numpy as np
 import pytest
@@ -328,46 +327,6 @@ def test_register_platform_custom_and_duplicate():
     with pytest.raises(ValueError):
         register_platform(name)
     register_platform(name, arena_bytes=2 << 20, replace=True)
-
-
-# ---------------------------------------------------------------------------
-# deprecation of the batch wrappers
-# ---------------------------------------------------------------------------
-
-
-def test_run_wrappers_warn_once(monkeypatch):
-    from repro.apps.radar import make_runtime
-    from repro.core.runtime import Task
-
-    monkeypatch.setattr(runtime_mod, "_deprecation_warned", False)
-    rt, ctx = make_runtime(policy="rimms", n_cpu=1, accelerators=())
-    a = ctx.malloc((16,), np.complex64)
-    b = ctx.malloc((16,), np.complex64)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        rt.run([Task("fft", [a], [b])])
-        rt.run([Task("fft", [a], [b])])
-    dep = [w for w in caught if issubclass(w.category, DeprecationWarning)
-           and "Session" in str(w.message)]
-    assert len(dep) == 1, "run() must warn exactly once per process"
-    rt.close()
-
-
-def test_internal_impls_do_not_warn(monkeypatch):
-    from repro.apps.radar import make_runtime
-    from repro.core.runtime import Task
-
-    monkeypatch.setattr(runtime_mod, "_deprecation_warned", False)
-    rt, ctx = make_runtime(policy="rimms", n_cpu=1, accelerators=())
-    a = ctx.malloc((16,), np.complex64)
-    b = ctx.malloc((16,), np.complex64)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        rt._run_impl([Task("fft", [a], [b])])
-        rt._run_graph_impl([Task("fft", [a], [b])])
-    assert not [w for w in caught
-                if issubclass(w.category, DeprecationWarning)]
-    rt.close()
 
 
 # ---------------------------------------------------------------------------

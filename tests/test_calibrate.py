@@ -357,3 +357,17 @@ def test_calibrate_process_backend_roundtrip_and_metric_drain(tmp_path):
     assert tasks > 0
     back = CalibrationTable.load(str(path))
     assert back.state()["cells"] == table.state()["cells"]
+
+
+def test_cli_run_show_diff_round_trip(tmp_path):
+    """``python -m repro.calibrate`` measures a table, prints it, and
+    diffs it against itself as equal."""
+    from repro import calibrate as calibrate_cli
+
+    path = str(tmp_path / "calib.json")
+    assert calibrate_cli.main(["run", "--out", path, "--ladder", "64KiB",
+                               "-k", "2", "--warmup", "1",
+                               "--n-cpu", "1"]) == 0
+    assert len(CalibrationTable.load(path)) > 0
+    assert calibrate_cli.main(["show", path]) == 0
+    assert calibrate_cli.main(["diff", path, path, "--exit-code"]) == 0

@@ -1,15 +1,16 @@
-"""Task-graph construction + async executor: dependency correctness,
-serial-equivalence (bitwise outputs, identical copy counts), HEFT-lite
-placement, and modeled-makespan wins on fork-join DAGs."""
+"""Task-graph construction + the stream executor: dependency
+correctness, equivalence with the stream driven one task at a time
+(bitwise outputs, identical copy counts), and HEFT-lite placement."""
 
 import numpy as np
 import pytest
 
-from repro.apps.radar import build_2fzf, build_3zip, build_pd, make_runtime
-from repro.apps.synthetic import build_diamonds, build_fork_join
+from repro.apps.radar import build_2fzf, build_3zip, build_pd, build_sar, make_session
+from repro.apps.synthetic import build_fork_join
 from repro.core.graph import CostModel, build_graph
 from repro.core.hete import HeteContext, hete_sync
 from repro.core.runtime import Task
+from session_tasks import submit_tasks
 
 
 # ---------------------------------------------------------------------------
@@ -106,191 +107,130 @@ def test_independent_tasks_have_no_edges():
 
 
 # ---------------------------------------------------------------------------
-# Executor: serial equivalence (acceptance criterion)
+# Stream executor: equivalence with the stream driven one task at a time
 # ---------------------------------------------------------------------------
 
 
-def _run_both(build, *, policy, scheduler="round_robin", graph_kw=None,
-              accelerators=("gpu0",), n_cpu=1):
-    """Build the same workload twice; run serial and graph; return
-    (out_serial, out_graph, snap_serial, snap_graph, rt_s, rt_g)."""
-    rt_s, ctx_s = make_runtime(policy=policy, scheduler=scheduler,
-                               n_cpu=n_cpu, accelerators=accelerators)
-    bufs_s, tasks_s = build(ctx_s)
-    rt_g, ctx_g = make_runtime(policy=policy, scheduler=scheduler,
-                               n_cpu=n_cpu, accelerators=accelerators)
-    bufs_g, tasks_g = build(ctx_g)
-    rt_s.run(tasks_s)
-    rt_g.run_graph(tasks_g, **(graph_kw or {}))
-    out_s = hete_sync(bufs_s["out"], context=ctx_s).copy()
-    out_g = hete_sync(bufs_g["out"], context=ctx_g).copy()
-    return (out_s, out_g, ctx_s.ledger.snapshot(), ctx_g.ledger.snapshot(),
-            rt_s, rt_g)
+def _sar_outs(pts):
+    return [pts["phase1"]["out"][0], pts["phase2"]["out"][0]]
 
 
-def test_run_graph_matches_serial_radar_rimms():
-    """2FZF radar chain: bitwise-identical outputs + identical per-pair
-    copy counts under rimms/round_robin."""
-    out_s, out_g, snap_s, snap_g, *_ = _run_both(
-        lambda c: build_2fzf(c, 256, seed=7), policy="rimms")
-    assert np.array_equal(out_s, out_g)
-    assert snap_s["by_pair"] == snap_g["by_pair"]
-    assert snap_s["total_copies"] == snap_g["total_copies"]
+# name -> (build(ctx) -> (buffers, tasks), buffers -> output buffers,
+#          CPU PEs, accelerators)
+WORKLOADS = {
+    "2fzf": (lambda c: build_2fzf(c, 256, seed=7), lambda b: [b["out"]],
+             1, ("gpu0",)),
+    "forkjoin": (lambda c: build_fork_join(c, ways=4, n=1024, depth=2, seed=3),
+                 lambda b: [b["out"]], 0, ("gpu0", "gpu1")),
+    "3zip": (lambda c: build_3zip(c, 256, seed=11), lambda b: [b["out"]],
+             0, ("gpu0", "gpu1")),
+    "pd_fragmented": (lambda c: build_pd(c, ways=4, n=64, use_fragment=True),
+                      lambda p: [p["out"][0]], 0, ("gpu0", "gpu1")),
+    "sar_scale8": (lambda c: build_sar(c, scale=8, seed=3), _sar_outs,
+                   1, ("gpu0",)),
+}
 
 
-def test_run_graph_matches_serial_forkjoin_rimms():
-    """Synthetic fork-join DAG: bitwise outputs + identical copy counts."""
-    out_s, out_g, snap_s, snap_g, *_ = _run_both(
-        lambda c: build_fork_join(c, ways=4, n=1024, depth=2, seed=3),
-        policy="rimms", n_cpu=0, accelerators=("gpu0", "gpu1"))
-    assert np.array_equal(out_s, out_g)
-    assert snap_s["by_pair"] == snap_g["by_pair"]
+@pytest.mark.parametrize("prefetch", [True, False],
+                         ids=["prefetch", "no_prefetch"])
+@pytest.mark.parametrize("policy", ["rimms", "reference"])
+@pytest.mark.parametrize("workload", list(WORKLOADS))
+def test_stream_matches_one_task_at_a_time(workload, policy, prefetch):
+    """A Session with everything on (concurrent PE workers, launch
+    batching, prefetch when asked) produces bitwise-identical outputs
+    and identical per-pair copy counts to the same Session driven one
+    task at a time, on the same static round-robin placement."""
+    build, outputs, n_cpu, accs = WORKLOADS[workload]
+    outs, snaps, launches = {}, {}, {}
+    for one in (False, True):
+        with make_session(policy=policy, scheduler="round_robin",
+                          n_cpu=n_cpu, accelerators=accs,
+                          prefetch=prefetch) as s:
+            bufs, tasks = build(s.context)
+            submit_tasks(s, tasks, one_at_a_time=one)
+            snaps[one] = s.ledger.snapshot()
+            outs[one] = [hete_sync(hd, context=s.context).copy()
+                         for hd in outputs(bufs)]
+            launches[one] = {k: v["value"]
+                             for k, v in s.metrics.snapshot().items()
+                             if k.startswith("launch/")}
+        s.runtime.close()
+    for got, want in zip(outs[False], outs[True]):
+        np.testing.assert_array_equal(got, want)
+    assert snaps[False]["by_pair"] == snaps[True]["by_pair"]
+    assert snaps[False]["total_copies"] == snaps[True]["total_copies"]
+    # the reference really launched one task at a time
+    for pe in accs:
+        assert (launches[True][f"launch/{pe}/launches"]
+                == launches[True][f"launch/{pe}/tasks"])
 
 
-def test_run_graph_matches_serial_3zip():
-    """3-stage ZIP pipeline (Fig 4c/8) ported to graph mode: the two leaf
-    zips parallelize, the join zip orders after both; results and copy
-    counts match serial."""
-    out_s, out_g, snap_s, snap_g, rt_s, rt_g = _run_both(
-        lambda c: build_3zip(c, 256, seed=11), policy="rimms",
-        n_cpu=0, accelerators=("gpu0", "gpu1"))
-    assert np.array_equal(out_s, out_g)
-    assert snap_s["by_pair"] == snap_g["by_pair"]
-    assert rt_g.last_report["critical_path"] == 2  # zip0/zip1 ∥ then zip2
+def test_stream_fragmented_pd():
+    """Pulse-Doppler with fragment() (§3.2.3) runs correctly through a
+    session: every way's IFFT(FFT(a)*FFT(b)) matches numpy."""
+    with make_session(policy="rimms", scheduler="round_robin", n_cpu=0,
+                      accelerators=("gpu0", "gpu1")) as s:
+        points, tasks = build_pd(s.context, ways=4, n=64, use_fragment=True)
+        submit_tasks(s, tasks)
+        for i in range(4):
+            a = points["a"][1][i].data.copy()
+            b = points["b"][1][i].data.copy()
+            want = np.fft.ifft(np.fft.fft(a) * np.fft.fft(b)).astype(np.complex64)
+            got = hete_sync(points["out"][1][i], context=s.context)
+            np.testing.assert_allclose(got, want, atol=1e-4)
+    s.runtime.close()
 
 
-def test_run_graph_matches_serial_reference_policy():
-    out_s, out_g, snap_s, snap_g, *_ = _run_both(
-        lambda c: build_2fzf(c, 128, seed=5), policy="reference")
-    assert np.array_equal(out_s, out_g)
-    assert snap_s["by_pair"] == snap_g["by_pair"]
+def test_stream_empty_task_list():
+    with make_session(policy="rimms") as s:
+        submit_tasks(s, [])
+        rep = s.report()
+    s.runtime.close()
+    assert rep["n_tasks"] == 0 and rep["makespan_model"] == 0.0
 
 
-def test_run_graph_fragmented_pd():
-    """Pulse-Doppler with fragment() (§3.2.3) runs correctly in graph
-    mode: every way's IFFT(FFT(a)*FFT(b)) matches numpy."""
-    rt, ctx = make_runtime(policy="rimms", n_cpu=0,
-                           accelerators=("gpu0", "gpu1"))
-    points, tasks = build_pd(ctx, ways=4, n=64, use_fragment=True)
-    rt.run_graph(tasks)
-    for i in range(4):
-        a = points["a"][1][i].data.copy()
-        b = points["b"][1][i].data.copy()
-        want = np.fft.ifft(np.fft.fft(a) * np.fft.fft(b)).astype(np.complex64)
-        got = hete_sync(points["out"][1][i], context=ctx)
-        np.testing.assert_allclose(got, want, atol=1e-4)
-
-
-def test_run_graph_without_prefetch():
-    out_s, out_g, snap_s, snap_g, *_ = _run_both(
-        lambda c: build_2fzf(c, 128, seed=2), policy="rimms",
-        graph_kw={"prefetch": False})
-    assert np.array_equal(out_s, out_g)
-    assert snap_s["by_pair"] == snap_g["by_pair"]
-
-
-def test_run_graph_empty_task_list():
-    rt, ctx = make_runtime(policy="rimms")
-    assert rt.run_graph([]) == 0.0
-
-
-def test_run_graph_propagates_kernel_errors():
-    rt, ctx = make_runtime(policy="rimms", accelerators=("gpu0",))
-    def boom(ins):
-        raise RuntimeError("kernel exploded")
-    rt.register_kernel("fft", "gpu", boom)
-    rt.register_kernel("fft", "cpu", boom)
-    a, b = ctx.malloc((8,), np.complex64), ctx.malloc((8,), np.complex64)
-    with pytest.raises(RuntimeError, match="kernel exploded"):
-        rt.run_graph([Task("fft", [a], [b])])
-
-
-def test_run_graph_raises_on_bad_pin_of_dependent_task():
+def test_stream_raises_on_bad_pin_of_dependent_task():
     """Regression: a scheduling error for a *non-root* task (raised while
-    completing its dependency) must propagate, not hang the run."""
-    rt, ctx = make_runtime(policy="rimms", scheduler="heft",
-                           accelerators=("gpu0",))
-    a, b, c = (_mk(ctx, 32) for _ in range(3))
+    completing its dependency) must surface, not hang the stream."""
+    s = make_session(policy="rimms", scheduler="heft", accelerators=("gpu0",))
+    a, b, c = (_mk(s.context, 32) for _ in range(3))
     tasks = [
         Task("fft", [a], [b], name="ok"),
         Task("ifft", [b], [c], pin="no_such_pe", name="bad_pin"),
     ]
     with pytest.raises(KeyError):
-        rt.run_graph(tasks)
-
-
-def test_run_graph_halts_after_failure():
-    """After a task fails, tasks already queued behind it on the same PE
-    must not execute (and the error must reach the caller)."""
-    rt, ctx = make_runtime(policy="rimms", n_cpu=0, accelerators=("gpu0",))
-    def boom(ins):
-        raise RuntimeError("boom")
-    rt.register_kernel("fft", "gpu", boom)
-    bufs = [_mk(ctx, 32) for _ in range(6)]
-    tasks = [Task("fft", [bufs[0]], [bufs[1]], pin="gpu0", name="dies")] + [
-        Task("zip", [bufs[i], bufs[i]], [bufs[i + 1]], pin="gpu0",
-             name=f"queued{i}")
-        for i in range(2, 5)
-    ]
-    with pytest.raises(RuntimeError, match="boom"):
-        rt.run_graph(tasks)
-    assert rt.task_log == []  # nothing committed after the failure
+        submit_tasks(s, tasks)
+    s.close()
+    s.runtime.close()
 
 
 # ---------------------------------------------------------------------------
-# Scheduling: HEFT-lite + makespan
+# Scheduling: HEFT-lite
 # ---------------------------------------------------------------------------
 
 
-def test_heft_serial_and_graph_produce_correct_results():
-    for mode in ("serial", "graph"):
-        rt, ctx = make_runtime(policy="rimms", scheduler="heft",
-                               n_cpu=1, accelerators=("gpu0", "gpu1"))
-        bufs, tasks = build_2fzf(ctx, 128, seed=9)
-        (rt.run if mode == "serial" else rt.run_graph)(tasks)
+@pytest.mark.parametrize("one_at_a_time", [False, True],
+                         ids=["concurrent", "one_at_a_time"])
+def test_heft_stream_produces_correct_results(one_at_a_time):
+    with make_session(policy="rimms", scheduler="heft", n_cpu=1,
+                      accelerators=("gpu0", "gpu1")) as s:
+        bufs, tasks = build_2fzf(s.context, 128, seed=9)
+        submit_tasks(s, tasks, one_at_a_time=one_at_a_time)
         want = np.fft.ifft(
             np.fft.fft(bufs["a"].data) * np.fft.fft(bufs["b"].data)
         ).astype(np.complex64)
         np.testing.assert_allclose(
-            hete_sync(bufs["out"], context=ctx), want, atol=1e-4)
-
-
-def test_heft_graph_uses_multiple_pes_on_wide_dag():
-    rt, ctx = make_runtime(policy="rimms", scheduler="heft",
-                           n_cpu=0, accelerators=("gpu0", "gpu1"))
-    _, tasks = build_diamonds(ctx, count=8, n=1024)
-    rt.run_graph(tasks)
-    used = {pe for _, pe in rt.task_log}
-    assert used == {"gpu0", "gpu1"}
-
-
-def test_graph_modeled_makespan_beats_serial_on_forkjoin():
-    """Acceptance: lower modeled makespan than serial dispatch on a
-    ≥2-accelerator fork-join workload."""
-    def build(ctx):
-        return build_fork_join(ctx, ways=4, n=4096, depth=2, seed=1)
-    rt_s, ctx_s = make_runtime(policy="rimms", n_cpu=0,
-                               accelerators=("gpu0", "gpu1"))
-    bufs, tasks = build(ctx_s)
-    rt_s.run(tasks)
-    rt_g, ctx_g = make_runtime(policy="rimms", n_cpu=0,
-                               accelerators=("gpu0", "gpu1"))
-    bufs_g, tasks_g = build(ctx_g)
-    rt_g.run_graph(tasks_g)
-    assert rt_g.last_makespan_model < rt_s.last_makespan_model
-    # and the executor's report carries the schedule evidence
-    rep = rt_g.last_report
-    assert rep["n_tasks"] == len(tasks_g)
-    assert rep["critical_path"] < rep["n_tasks"]
-    assert len(rep["timeline"]) == len(tasks_g)
+            hete_sync(bufs["out"], context=s.context), want, atol=1e-4)
+    s.runtime.close()
 
 
 def test_timeline_gantt_renders():
-    rt, ctx = make_runtime(policy="rimms", n_cpu=0,
-                           accelerators=("gpu0", "gpu1"))
-    _, tasks = build_fork_join(ctx, ways=2, n=512, depth=1)
-    rt.run_graph(tasks)
-    txt = rt.timeline.gantt(40)
+    with make_session(policy="rimms", scheduler="round_robin", n_cpu=0,
+                      accelerators=("gpu0", "gpu1")) as s:
+        _, tasks = build_fork_join(s.context, ways=2, n=512, depth=1)
+        submit_tasks(s, tasks)
+        txt = s.report()["timeline"].gantt(40)
+    s.runtime.close()
     assert "gpu0" in txt and "gpu1" in txt and "#" in txt
 
 

@@ -11,6 +11,7 @@ import pytest
 from repro.apps import radar
 from repro.core import api as rimms
 from repro.core.hete import hete_sync
+from session_tasks import submit_tasks
 
 C64 = np.complex64
 
@@ -33,16 +34,18 @@ def frame():
 
 
 def test_sar_frame_bitwise_equal_to_one_task_per_launch(frame):
-    """The serial path launches one task at a time, on the same
-    placement."""
-    rt, ctx = radar.make_runtime(policy="rimms", scheduler="round_robin",
-                                 backend="thread")
-    bufs, tasks = radar.build_sar(ctx, scale=8, seed=3)
-    rt._run_impl(tasks)
-    for p, batched in zip(("phase1", "phase2"), frame[0]):
-        np.testing.assert_array_equal(
-            hete_sync(bufs[p]["out"][0], context=ctx), batched)
-    rt.close()
+    """The same session driven one task at a time launches one task at a
+    time, on the same placement."""
+    with radar.make_session(scheduler="round_robin", backend="thread") as s:
+        bufs, tasks = radar.build_sar(s.context, scale=8, seed=3)
+        submit_tasks(s, tasks, one_at_a_time=True)
+        for p, batched in zip(("phase1", "phase2"), frame[0]):
+            np.testing.assert_array_equal(
+                hete_sync(bufs[p]["out"][0], context=s.context), batched)
+        snap = s.metrics.snapshot()
+        assert (snap["launch/gpu0/launches"]["value"]
+                == snap["launch/gpu0/tasks"]["value"])
+    s.runtime.close()
 
 
 def test_launch_counters_per_pe(frame):

@@ -1,6 +1,6 @@
 """Capacity-pressure subsystem (ISSUE 2): transparent eviction,
 spill-to-host write-back, pin/protect semantics, spill counters, and the
-executor's persistent worker pool + capacity-aware prefetch."""
+stream executor's persistent worker pool + capacity-aware prefetch."""
 
 import numpy as np
 import pytest
@@ -8,6 +8,7 @@ import pytest
 from repro.core.allocator import AllocError
 from repro.core.hete import HeteContext, MemorySpace, hete_sync
 from repro.core.locations import HOST, Location
+from session_tasks import submit_tasks
 
 ACC = Location("device", "acc0")
 
@@ -220,18 +221,11 @@ def test_allocator_tags_name_residents():
 # ---------------------------------------------------------------------------
 
 
-def _pressure_runtime(arena_bytes, **kw):
-    from repro.core.runtime import make_emulated_soc
-    from repro.apps.radar import register_kernels
-    from repro.core.runtime import Runtime
+def _pressure_session(arena_bytes):
+    from repro.apps.radar import make_session
 
-    pes, ctx = make_emulated_soc(
-        n_cpu=0, accelerators=("gpu0",), arena_bytes=arena_bytes,
-    )
-    rt = Runtime(pes, ctx, policy="rimms", scheduler=kw.get(
-        "scheduler", "round_robin"))
-    register_kernels(rt)
-    return rt, ctx
+    return make_session(policy="rimms", scheduler="round_robin", n_cpu=0,
+                        accelerators=("gpu0",), arena_bytes=arena_bytes)
 
 
 def _radar_tasks(ctx, ways=4, n=512, seed=0):
@@ -240,57 +234,50 @@ def _radar_tasks(ctx, ways=4, n=512, seed=0):
     return _parallel_fzf(ctx, ways, n, use_fragment=True, seed=seed)
 
 
-def test_serial_pipeline_bit_identical_under_pressure():
+@pytest.mark.parametrize("one_at_a_time", [True, False],
+                         ids=["one_at_a_time", "concurrent"])
+def test_pipeline_bit_identical_under_pressure(one_at_a_time):
     """A radar pipeline whose working set exceeds the arena completes
-    with outputs bit-identical to an unconstrained run (serial mode)."""
+    with outputs bit-identical to an unconstrained run — driven one task
+    at a time, and concurrently (prefetch + protection)."""
     ways, n = 4, 512
     parent_bytes = ways * n * 8  # complex64
-    roomy, _ = _pressure_runtime(arena_bytes=64 << 20)
-    tight, _ = _pressure_runtime(arena_bytes=3 * parent_bytes)
-
-    pts_r, tasks_r = _radar_tasks(roomy.context, ways, n)
-    pts_t, tasks_t = _radar_tasks(tight.context, ways, n)
-    roomy.run(tasks_r)
-    tight.run(tasks_t)
-    assert tight.context.ledger.total_evictions > 0
-    out_r = hete_sync(pts_r["out"][0], context=roomy.context)
-    out_t = hete_sync(pts_t["out"][0], context=tight.context)
-    np.testing.assert_array_equal(out_r, out_t)
-    # spill stalls surfaced in the timeline + modeled makespan
-    assert tight.timeline.total_spill_s > 0.0
-    assert tight.last_makespan_model > roomy.last_makespan_model
-
-
-def test_graph_pipeline_bit_identical_under_pressure():
-    """Graph mode (prefetch + protection) under the same pressure."""
-    ways, n = 4, 512
-    parent_bytes = ways * n * 8
-    roomy, _ = _pressure_runtime(arena_bytes=64 << 20)
-    tight, _ = _pressure_runtime(arena_bytes=3 * parent_bytes)
-
-    pts_r, tasks_r = _radar_tasks(roomy.context, ways, n)
-    pts_t, tasks_t = _radar_tasks(tight.context, ways, n)
-    roomy.run_graph(tasks_r)
-    tight.run_graph(tasks_t)
-    assert tight.context.ledger.total_evictions > 0
-    out_r = hete_sync(pts_r["out"][0], context=roomy.context)
-    out_t = hete_sync(pts_t["out"][0], context=tight.context)
-    np.testing.assert_array_equal(out_r, out_t)
-    # all protection claims released at run end
-    assert not tight.context._protected
+    outs, reps = {}, {}
+    for arena in (64 << 20, 3 * parent_bytes):
+        with _pressure_session(arena) as s:
+            pts, tasks = _radar_tasks(s.context, ways, n)
+            submit_tasks(s, tasks, one_at_a_time=one_at_a_time)
+            outs[arena] = hete_sync(pts["out"][0], context=s.context).copy()
+            reps[arena] = s.report()
+            snap = s.ledger.snapshot()
+            # all protection claims released once the stream drains
+            assert not s.context._protected
+        s.runtime.close()
+    assert snap["total_evictions"] > 0  # the tight arena, last
+    assert snap["writeback_bytes"] > 0  # dirty bytes spilled to the host
+    np.testing.assert_array_equal(*outs.values())
+    # spill stalls surfaced in the replayed timeline
+    assert reps[64 << 20]["spill_stall_model_s"] == 0.0
+    assert reps[3 * parent_bytes]["spill_stall_model_s"] > 0.0
 
 
-def test_worker_pool_persists_across_run_graph_calls():
+def test_worker_pool_persists_across_sessions():
     import threading
 
-    rt, ctx = _pressure_runtime(arena_bytes=64 << 20)
+    from repro.core.api import Session
+    from repro.core.runtime import Runtime, make_emulated_soc
+
+    pes, ctx = make_emulated_soc(n_cpu=0, accelerators=("gpu0",))
+    rt = Runtime(pes, ctx, policy="rimms", scheduler="round_robin")
     _, tasks1 = _radar_tasks(ctx, 2, 256, seed=1)
-    rt.run_graph(tasks1)
+    with Session(rt) as s:
+        submit_tasks(s, tasks1)
     pool = rt._worker_pool
     assert pool is not None and pool.runs_served == 1
     before = threading.active_count()
     _, tasks2 = _radar_tasks(ctx, 2, 256, seed=2)
-    rt.run_graph(tasks2)
+    with Session(rt) as s:
+        submit_tasks(s, tasks2)
     assert rt._worker_pool is pool and pool.runs_served == 2
     assert threading.active_count() == before  # no new threads spun up
     rt.close()

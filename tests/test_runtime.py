@@ -7,16 +7,22 @@ from repro.apps.radar import (
     build_2fzf,
     build_3zip,
     build_pd,
-    make_runtime,
+    make_session,
 )
 from repro.core.hete import hete_sync
+from session_tasks import submit_tasks
 
 
-def run_chain(builder, policy, pins_key="pins", **kw):
-    rt, ctx = make_runtime(policy=policy, accelerators=("gpu0",))
-    bufs, tasks = builder(ctx, **kw)
-    rt.run(tasks)
-    return rt, ctx, bufs
+def run_chain(builder, policy, scheduler="round_robin", **kw):
+    """Build a chain on a fresh session's context and run it through the
+    session; returns (runtime, context, buffers) with the session and
+    its worker threads closed."""
+    kw.setdefault("accelerators", ("gpu0",))
+    with make_session(policy=policy, scheduler=scheduler, **kw) as s:
+        bufs, tasks = builder(s.context)
+        submit_tasks(s, tasks)
+    s.runtime.close()
+    return s.runtime, s.context, bufs
 
 
 def test_2fft_copy_elimination_acc_acc():
@@ -72,19 +78,17 @@ def test_3zip_gpu_only_counts():
 
 
 def test_round_robin_batches_of_four():
-    """Paper §5.4: 3 CPUs + 1 GPU round robin."""
-    rt, ctx = make_runtime(policy="rimms", n_cpu=3, accelerators=("gpu0",))
-    bufs, tasks = build_pd(ctx, ways=8, n=64)
-    rt.run(tasks)
-    fft_pes = [pe for name, pe in rt.task_log if name.startswith("fft")]
-    assert fft_pes[:4] == ["cpu0", "cpu1", "cpu2", "gpu0"]
+    """Paper §5.4: 3 CPUs + 1 GPU round robin, in submission order."""
+    rt, _, _ = run_chain(lambda c: build_pd(c, ways=8, n=64), "rimms",
+                         n_cpu=3)
+    placed = dict(rt.task_log)
+    assert [placed[n] for n in ("fftA0", "fftB0", "fftA1", "fftB1")] == [
+        "cpu0", "cpu1", "cpu2", "gpu0"]
 
 
 def test_data_affinity_scheduler_prefers_data_location():
-    rt, ctx = make_runtime(policy="rimms", n_cpu=1,
-                           accelerators=("gpu0",), scheduler="data_affinity")
-    bufs, tasks = build_2fft(ctx, 128)
-    rt.run(tasks)
+    rt, _, _ = run_chain(lambda c: build_2fft(c, 128), "rimms",
+                         scheduler="data_affinity", n_cpu=1)
     # second task should follow the data produced by the first
     assert rt.task_log[0][1] == rt.task_log[1][1]
 
@@ -95,12 +99,10 @@ def test_data_affinity_tie_break_is_deterministic():
     orderings."""
     placements = []
     for trial in range(3):
-        rt, ctx = make_runtime(policy="rimms", n_cpu=0,
-                               accelerators=("gpu1", "gpu0", "gpu2"),
-                               scheduler="data_affinity")
         # fresh host inputs: zero bytes valid at every accelerator → tie
-        bufs, tasks = build_2fft(ctx, 64)
-        rt.run(tasks)
+        rt, _, _ = run_chain(lambda c: build_2fft(c, 64), "rimms",
+                             scheduler="data_affinity", n_cpu=0,
+                             accelerators=("gpu1", "gpu0", "gpu2"))
         placements.append([pe for _, pe in rt.task_log])
     assert placements[0] == placements[1] == placements[2]
     # the tie must resolve to the lexicographically-smallest PE name,
@@ -110,12 +112,15 @@ def test_data_affinity_tie_break_is_deterministic():
 
 def test_pd_fragment_allocation_counts():
     """§3.2.3: with fragment(), one arena search per data point."""
-    rt, ctx = make_runtime(policy="rimms", accelerators=("gpu0",))
-    arena = list(ctx.spaces.values())[-1].arena
-    build_pd(ctx, ways=16, n=64, use_fragment=True)
-    n_frag = arena.n_allocs
-    rt2, ctx2 = make_runtime(policy="rimms", accelerators=("gpu0",))
-    build_pd(ctx2, ways=16, n=64, use_fragment=False)
+    with make_session(accelerators=("gpu0",)) as s:
+        arena = list(s.context.spaces.values())[-1].arena
+        build_pd(s.context, ways=16, n=64, use_fragment=True)
+        n_frag = arena.n_allocs
+    with make_session(accelerators=("gpu0",)) as s2:
+        arena = list(s2.context.spaces.values())[-1].arena
+        build_pd(s2.context, ways=16, n=64, use_fragment=False)
     # fragment path does ≤ 1 alloc per data point (host-side arenas are
     # only engaged when spaces are passed; here we compare host mallocs)
     assert n_frag <= arena.n_allocs
+    s.runtime.close()
+    s2.runtime.close()

@@ -1,18 +1,20 @@
 """Streaming session API (ISSUE 4): op registry, incremental DAG,
 buffer futures, concurrent submitters, exception propagation, lifecycle,
-and bit-identical equivalence with batch run_graph."""
+and bit-identical equivalence with the stream driven one task at a
+time."""
 
 import threading
 
 import numpy as np
 import pytest
 
-from repro.apps.radar import make_runtime, make_session, submit_2fzf
+from repro.apps.radar import make_session, submit_2fzf
 from repro.apps.synthetic import build_fork_join, submit_fork_join
 from repro.core import api as rimms
 from repro.core.graph import GraphBuilder, build_graph
 from repro.core.hete import AllocError, HeteContext, hete_sync
-from repro.core.runtime import Task
+from repro.core.runtime import Runtime, Task, make_emulated_soc
+from session_tasks import submit_tasks
 
 
 # ---------------------------------------------------------------------------
@@ -54,7 +56,8 @@ def test_op_double_registration_rejected_unless_replace():
 
 
 def test_registry_install_missing_only_keeps_manual_kernels():
-    rt, _ = make_runtime(policy="rimms", accelerators=("gpu0",))
+    rt = Runtime(*make_emulated_soc(accelerators=("gpu0",)), policy="rimms")
+    rimms.default_registry.install(rt)
     sentinel = lambda ins: ins[0]
     rt.register_kernel("fft", "cpu", sentinel)
     rimms.default_registry.install(rt, missing_only=True)
@@ -147,10 +150,11 @@ def test_session_radar_chain_matches_numpy():
         np.testing.assert_allclose(bufs["out"].result(), want, atol=1e-4)
 
 
-def test_session_bit_identical_to_run_graph_on_forkjoin():
+def test_session_bit_identical_to_one_task_at_a_time_on_forkjoin():
     """Acceptance: the streaming session path produces bit-identical
-    outputs and per-pair copy counts to batch run_graph under the rimms
-    policy + static round_robin placement on the radar fork-join."""
+    outputs and per-pair copy counts to the same fork-join built as a
+    task list and driven one task at a time, under the rimms policy +
+    static round_robin placement."""
     kw = dict(ways=4, n=1024, depth=2, seed=3)
     s = make_session(policy="rimms", scheduler="round_robin",
                      n_cpu=0, accelerators=("gpu0", "gpu1"))
@@ -159,17 +163,78 @@ def test_session_bit_identical_to_run_graph_on_forkjoin():
     s.barrier()
     snap_stream = s.ledger.snapshot()
     s.close()
+    s.runtime.close()
 
-    rt, ctx = make_runtime(policy="rimms", scheduler="round_robin",
-                           n_cpu=0, accelerators=("gpu0", "gpu1"))
-    bufs, tasks = build_fork_join(ctx, **kw)
-    rt.run_graph(tasks)
-    out_batch = hete_sync(bufs["out"], context=ctx).copy()
-    snap_batch = ctx.ledger.snapshot()
+    with make_session(policy="rimms", scheduler="round_robin",
+                      n_cpu=0, accelerators=("gpu0", "gpu1")) as ref:
+        bufs, tasks = build_fork_join(ref.context, **kw)
+        submit_tasks(ref, tasks, one_at_a_time=True)
+        out_ref = hete_sync(bufs["out"], context=ref.context).copy()
+        snap_ref = ref.ledger.snapshot()
+    ref.runtime.close()
 
-    assert np.array_equal(out_stream, out_batch)
-    assert snap_stream["by_pair"] == snap_batch["by_pair"]
-    assert snap_stream["total_copies"] == snap_batch["total_copies"]
+    assert np.array_equal(out_stream, out_ref)
+    assert snap_stream["by_pair"] == snap_ref["by_pair"]
+    assert snap_stream["total_copies"] == snap_ref["total_copies"]
+
+
+def test_concurrent_clients_bit_identical_to_one_task_at_a_time():
+    """Four client threads streaming pinned 2FZF chains against one
+    session produce the outputs and per-pair copy counts of the same
+    chains built as one task list and driven one task at a time."""
+    from repro.apps.radar import build_2fzf
+
+    clients, chains, n, accs = 4, 2, 1024, ("gpu0", "gpu1")
+
+    def seed(c, k):
+        return 1000 + c * 97 + k
+
+    s = make_session(policy="rimms", scheduler="round_robin", n_cpu=0,
+                     accelerators=accs)
+    outs, errors = {}, []
+
+    def client(c):
+        try:
+            futs = [submit_2fzf(s, n, pins=(accs[c % 2],) * 4, seed=seed(c, k),
+                                tag=f"_c{c}k{k}")["out"]
+                    for k in range(chains)]
+            outs[c] = [f.result(timeout=60) for f in futs]
+        except BaseException as e:  # pragma: no cover - surfaced below
+            errors.append(e)
+
+    threads = [threading.Thread(target=client, args=(c,))
+               for c in range(clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errors
+    s.barrier()
+    streamed = np.stack([np.stack(outs[c]) for c in range(clients)])
+    snap_stream = s.ledger.snapshot()
+    s.close()
+    s.runtime.close()
+
+    with make_session(policy="rimms", scheduler="round_robin", n_cpu=0,
+                      accelerators=accs) as ref:
+        rows, tasks = [], []
+        for c in range(clients):
+            row = []
+            for k in range(chains):
+                bufs, chain = build_2fzf(ref.context, n,
+                                         pins=(accs[c % 2],) * 4,
+                                         seed=seed(c, k))
+                tasks += chain
+                row.append(bufs["out"])
+            rows.append(row)
+        submit_tasks(ref, tasks, one_at_a_time=True)
+        want = np.stack([np.stack([hete_sync(hd, context=ref.context)
+                                   for hd in row]) for row in rows])
+        snap_ref = ref.ledger.snapshot()
+    ref.runtime.close()
+
+    assert np.array_equal(streamed, want)
+    assert snap_stream["by_pair"] == snap_ref["by_pair"]
 
 
 def test_session_heft_windowed_placement_correct_and_multi_pe():
@@ -439,30 +504,3 @@ def test_numpy_inputs_are_adopted():
         np.testing.assert_allclose(
             f.result(timeout=30), np.fft.fft(sig).astype(np.complex64),
             atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
-# runtime stats hygiene (ISSUE 4 satellite)
-# ---------------------------------------------------------------------------
-
-
-def test_run_resets_task_log_and_rr_state_each_run():
-    """Cross-run state leaks fixed: task_log holds exactly the last
-    run's placements and round-robin rotation restarts, so identical
-    task lists place identically on every run."""
-    from repro.apps.radar import build_2fzf
-
-    rt, ctx = make_runtime(policy="rimms", n_cpu=0,
-                           accelerators=("gpu0", "gpu1"))
-    bufs, tasks = build_2fzf(ctx, 128, seed=1)
-    rt.run(tasks)
-    first = list(rt.task_log)
-    assert len(first) == len(tasks)
-    rt.run(tasks)
-    assert rt.task_log == first  # same placements, not accumulated
-    assert rt._rr_state != {} and len(rt.task_log) == len(tasks)
-    rt.run_graph(tasks)
-    assert len(rt.task_log) == len(tasks)
-    rt.reset_stats()
-    assert rt.task_log == [] and rt._rr_state == {}
-    assert rt.last_report is None and rt.last_makespan_model == 0.0

@@ -51,6 +51,7 @@ def test_bit_identical_to_legacy_multi_tenant(setup):
         assert [r.generated for r in reqs] == want
         # runtime managed the KV: pages all recycled, tasks all traced
         assert eng.kv.used_pages == 1  # scratch page only
+        assert eng.kv.spill_bytes() == 0  # a roomy arena never spills
         rep = eng.qos_report()
         assert {"a", "b", "prefill"} <= set(rep["latency_percentiles"])
 

@@ -6,8 +6,9 @@ import dataclasses
 
 import numpy as np
 
-from repro.apps.radar import build_sar, make_runtime
+from repro.apps.radar import build_sar, make_session
 from repro.core.hete import hete_sync
+from session_tasks import submit_tasks
 
 
 def test_paper_end_to_end_sar():
@@ -16,12 +17,14 @@ def test_paper_end_to_end_sar():
     outs = {}
     copies = {}
     for policy in ("reference", "rimms"):
-        rt, ctx = make_runtime(policy=policy,
-                               accelerators=("fft_acc0", "zip_acc0"))
-        bufs, tasks = build_sar(ctx, scale=64, seed=11)  # 8-way + 4-way
-        rt.run(tasks)
-        outs[policy] = hete_sync(bufs["phase1"]["out"][1][0], context=ctx).copy()
-        copies[policy] = ctx.ledger.total_copies
+        with make_session(policy=policy, scheduler="round_robin",
+                          accelerators=("fft_acc0", "zip_acc0")) as s:
+            bufs, tasks = build_sar(s.context, scale=64, seed=11)  # 8-way + 4-way
+            submit_tasks(s, tasks)
+            outs[policy] = hete_sync(bufs["phase1"]["out"][1][0],
+                                     context=s.context).copy()
+            copies[policy] = s.ledger.total_copies
+        s.runtime.close()
     np.testing.assert_allclose(outs["reference"], outs["rimms"], atol=1e-4)
     assert copies["rimms"] < copies["reference"]
 

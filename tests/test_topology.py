@@ -11,6 +11,7 @@ from repro.core.locations import HOST, BandwidthModel, Location
 from repro.core.topology import (
     Topology, TopologyBandwidthModel, TopologyError, build_preset,
 )
+from session_tasks import submit_tasks
 
 G0, G1 = Location("device", "gpu0"), Location("device", "gpu1")
 
@@ -323,7 +324,8 @@ def test_scalar_model_single_device_unaffected():
 
 
 def _topo_runtime(topology, scheduler="round_robin", arena_bytes=64 << 20):
-    from repro.apps.radar import register_kernels
+    import repro.apps.radar  # noqa: F401  (registers the radar ops)
+    from repro.core.api import default_registry
     from repro.core.runtime import Runtime, make_emulated_soc
 
     pes, ctx = make_emulated_soc(
@@ -331,8 +333,15 @@ def _topo_runtime(topology, scheduler="round_robin", arena_bytes=64 << 20):
         topology=topology,
     )
     rt = Runtime(pes, ctx, policy="rimms", scheduler=scheduler)
-    register_kernels(rt)
+    default_registry.install(rt)
     return rt, ctx
+
+
+def _topo_session(topology, scheduler="round_robin"):
+    from repro.apps.radar import make_session
+
+    return make_session(policy="rimms", scheduler=scheduler, n_cpu=0,
+                        accelerators=("gpu0", "gpu1"), topology=topology)
 
 
 def test_make_emulated_soc_wires_topology_model():
@@ -343,62 +352,94 @@ def test_make_emulated_soc_wires_topology_model():
 
 
 def test_topologies_are_bit_identical_and_replay_deterministic():
-    """The topology changes modeled cost, never data: serial and graph
-    outputs match across platforms, and the graph executor's topology
-    replay yields the same modeled makespan on every run."""
+    """The topology changes modeled cost, never data: the stream and the
+    stream driven one task at a time produce the same outputs across
+    platforms, and the stream's topology replay yields the same modeled
+    makespan on every run."""
     from repro.apps.synthetic import build_fork_join
 
     outs, makespans = [], {}
     for topo in ("nvlink_mesh", "host_bridged_fpga"):
-        for mode in ("serial", "graph"):
-            rt, ctx = _topo_runtime(topo)
-            bufs, tasks = build_fork_join(ctx, ways=2, n=1024, depth=1,
-                                          seed=3)
-            (rt.run if mode == "serial" else rt.run_graph)(tasks)
-            outs.append(hete_sync(bufs["out"], context=ctx))
-            makespans[(topo, mode)] = rt.last_makespan_model
-            rt.close()
+        for one in (False, True):
+            with _topo_session(topo) as s:
+                bufs, tasks = build_fork_join(s.context, ways=2, n=1024,
+                                              depth=1, seed=3)
+                submit_tasks(s, tasks, one_at_a_time=one)
+                outs.append(hete_sync(bufs["out"], context=s.context))
+                makespans[(topo, one)] = s.report()["makespan_model"]
+            s.runtime.close()
     for o in outs[1:]:
         np.testing.assert_array_equal(outs[0], o)
     # bridged platform pays more modeled time on the same schedule
-    assert (makespans[("host_bridged_fpga", "graph")]
-            > makespans[("nvlink_mesh", "graph")])
+    assert (makespans[("host_bridged_fpga", False)]
+            > makespans[("nvlink_mesh", False)])
     # replay determinism: same build → exactly the same makespan
-    rt, ctx = _topo_runtime("nvlink_mesh")
-    bufs, tasks = build_fork_join(ctx, ways=2, n=1024, depth=1, seed=3)
-    rt.run_graph(tasks)
-    m1 = rt.last_makespan_model
-    rt.close()
-    assert m1 == makespans[("nvlink_mesh", "graph")]
+    with _topo_session("nvlink_mesh") as s:
+        bufs, tasks = build_fork_join(s.context, ways=2, n=1024, depth=1,
+                                      seed=3)
+        submit_tasks(s, tasks)
+        m1 = s.report()["makespan_model"]
+    s.runtime.close()
+    assert m1 == makespans[("nvlink_mesh", False)]
 
 
-def test_graph_timeline_has_link_transfer_lanes():
+def test_stream_timeline_has_link_transfer_lanes():
     from repro.apps.synthetic import build_fork_join
 
-    rt, ctx = _topo_runtime("pcie_tree")
-    _, tasks = build_fork_join(ctx, ways=2, n=1024, depth=1, seed=0)
-    rt.run_graph(tasks)
-    xfers = rt.timeline.transfers()
+    with _topo_session("pcie_tree") as s:
+        _, tasks = build_fork_join(s.context, ways=2, n=1024, depth=1, seed=0)
+        submit_tasks(s, tasks)
+        timeline = s.report()["timeline"]
+    s.runtime.close()
+    xfers = timeline.transfers()
     assert xfers, "topology run recorded no transfer lanes"
     links = {x.link for x in xfers}
     assert any("bridge:pcie0" in l for l in links)
-    txt = rt.timeline.gantt(40)
+    txt = timeline.gantt(40)
     assert "=" in txt and "bridge:pcie0" in txt
-    rt.close()
 
 
 def test_heft_with_topology_runs_and_places_correctly():
     from repro.apps.radar import build_2fzf
 
-    rt, ctx = _topo_runtime("nvlink_mesh", scheduler="heft")
-    bufs, tasks = build_2fzf(ctx, 256, seed=4)
-    rt.run_graph(tasks)
-    want = np.fft.ifft(
-        np.fft.fft(bufs["a"].data) * np.fft.fft(bufs["b"].data)
-    ).astype(np.complex64)
-    np.testing.assert_allclose(
-        hete_sync(bufs["out"], context=ctx), want, atol=1e-4)
-    rt.close()
+    with _topo_session("nvlink_mesh", scheduler="heft") as s:
+        bufs, tasks = build_2fzf(s.context, 256, seed=4)
+        submit_tasks(s, tasks)
+        want = np.fft.ifft(
+            np.fft.fft(bufs["a"].data) * np.fft.fft(bufs["b"].data)
+        ).astype(np.complex64)
+        np.testing.assert_allclose(
+            hete_sync(bufs["out"], context=s.context), want, atol=1e-4)
+    s.runtime.close()
+
+
+@pytest.mark.parametrize("one_at_a_time", [True, False],
+                         ids=["one_at_a_time", "concurrent"])
+def test_radar_pipeline_spills_to_peer_bit_identical(one_at_a_time):
+    """A Pulse-Doppler pipeline pinned to gpu0, whose arena holds half
+    its working set, next to an idle gpu1 one NVLink away: eviction
+    spills device→device, and the outputs match a roomy arena's."""
+    from repro.apps.radar import _parallel_fzf, make_session
+
+    ways, n = 4, 512
+    working_set = 6 * ways * n * 8  # six complex64 parents
+    outs, snaps = {}, {}
+    for tight in (False, True):
+        arena = {"gpu0": working_set // 2 if tight else 64 << 20,
+                 "gpu1": 64 << 20}
+        with make_session(policy="rimms", scheduler="round_robin", n_cpu=0,
+                          accelerators=("gpu0", "gpu1"), arena_bytes=arena,
+                          topology="nvlink_mesh") as s:
+            pts, tasks = _parallel_fzf(s.context, ways, n, use_fragment=True)
+            for t in tasks:
+                t.pin = "gpu0"
+            submit_tasks(s, tasks, one_at_a_time=one_at_a_time)
+            outs[tight] = hete_sync(pts["out"][0], context=s.context).copy()
+            snaps[tight] = s.ledger.snapshot()
+        s.runtime.close()
+    assert snaps[True]["total_evictions"] > 0
+    assert snaps[True]["spills_to_peer"] > 0
+    np.testing.assert_array_equal(outs[False], outs[True])
 
 
 # ---------------------------------------------------------------------------
@@ -431,59 +472,62 @@ def test_insert_slot_commit_keeps_intervals_disjoint():
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 4 satellites: serial per-link contention + prefetch ordering
+# ISSUE 4 satellites: per-link contention in the replay + prefetch ordering
 # ---------------------------------------------------------------------------
 
 
-def test_serial_modeled_staging_overlaps_disjoint_routes():
-    """Serial run() issues a task's input copies concurrently at the
-    task's modeled start: two inputs arriving over disjoint links (host
-    uplink vs peer NVLink) overlap — staging costs max(), not sum() —
-    matching the graph executor's replay pricing."""
+def test_replay_staging_overlaps_disjoint_routes():
+    """The stream's replay issues a task's input copies concurrently at
+    the task's ready time: two inputs arriving over disjoint links (host
+    uplink vs peer NVLink) overlap — staging costs max(), not sum()."""
     from repro.core.runtime import Task
 
-    rt, ctx = _topo_runtime("nvlink_mesh")
-    n = 1 << 14
-    x, y, fy, out = (ctx.malloc((n,), np.complex64) for _ in range(4))
-    tasks = [
-        Task("fft", [y], [fy], pin="gpu1", name="warm"),  # fy lands on gpu1
-        Task("zip", [x, fy], [out], pin="gpu0", name="z"),
-    ]
-    rt.run(tasks)
-    ev = {e.task: e for e in rt.timeline.events()}["z"]
+    with _topo_session("nvlink_mesh") as s:
+        ctx = s.context
+        n = 1 << 14
+        x, y, fy, out = (ctx.malloc((n,), np.complex64) for _ in range(4))
+        tasks = [
+            Task("fft", [y], [fy], pin="gpu1", name="warm"),  # fy lands on gpu1
+            Task("zip", [x, fy], [out], pin="gpu0", name="z"),
+        ]
+        submit_tasks(s, tasks)
+        ev = {e.task: e for e in s.report()["timeline"].events()}["z"]
+    s.runtime.close()
     bw = ctx.ledger.bandwidth_model
     t_host = bw.seconds(HOST, G0, x.nbytes)  # host→gpu0 uplink
     t_peer = bw.seconds(G1, G0, fy.nbytes)   # gpu1→gpu0 NVLink
-    comp = rt.cost_model.prior_estimate("zip", "gpu", x.nbytes + fy.nbytes)
+    comp = s.runtime.cost_model.prior_estimate("zip", "gpu",
+                                               x.nbytes + fy.nbytes)
     stage_m = (ev.model_end - ev.model_start) - comp
     assert stage_m == pytest.approx(max(t_host, t_peer))
     assert stage_m < t_host + t_peer  # strictly better than store-and-forward
-    rt.close()
 
 
-def test_serial_modeled_staging_serializes_on_shared_link():
+def test_replay_staging_serializes_on_shared_link():
     """…but two inputs sharing one link (host-bridged UDMA) queue behind
     each other: per-link contention, not naive overlap."""
     from repro.core.runtime import Task
 
-    rt, ctx = _topo_runtime("host_bridged_fpga")
-    n = 1 << 14
-    x, y, out = (ctx.malloc((n,), np.complex64) for _ in range(3))
-    tasks = [Task("zip", [x, y], [out], pin="gpu0", name="z")]
-    rt.run(tasks)
-    ev = rt.timeline.events()[0]
+    with _topo_session("host_bridged_fpga") as s:
+        ctx = s.context
+        n = 1 << 14
+        x, y, out = (ctx.malloc((n,), np.complex64) for _ in range(3))
+        submit_tasks(s, [Task("zip", [x, y], [out], pin="gpu0", name="z")])
+        timeline = s.report()["timeline"]
+    s.runtime.close()
+    ev = timeline.events()[0]
     bw = ctx.ledger.bandwidth_model
     t_one = bw.seconds(HOST, G0, x.nbytes)
-    comp = rt.cost_model.prior_estimate("zip", "gpu", x.nbytes + y.nbytes)
+    comp = s.runtime.cost_model.prior_estimate("zip", "gpu",
+                                               x.nbytes + y.nbytes)
     stage_m = (ev.model_end - ev.model_start) - comp
     assert stage_m == pytest.approx(2 * t_one)  # serialized on the one link
     # the Gantt transfer lanes on that link must not overlap
-    lanes = [t for t in rt.timeline.transfers()
+    lanes = [t for t in timeline.transfers()
              if t.link == "host:cpu->device:gpu0"]
     assert len(lanes) == 2
     lanes.sort(key=lambda t: t.model_start)
     assert lanes[0].model_end <= lanes[1].model_start + 1e-12
-    rt.close()
 
 
 def test_prefetch_order_issues_least_contended_route_first():

@@ -6,8 +6,9 @@ import threading
 
 import numpy as np
 
-from repro.apps.radar import build_2fzf, make_runtime, make_session, submit_2fzf
+from repro.apps.radar import build_2fzf, make_session, submit_2fzf
 from repro.core import api as rimms
+from repro.core.hete import HeteContext
 from repro.core.trace import (
     MODEL_PID,
     WALL_PID,
@@ -18,6 +19,7 @@ from repro.core.trace import (
     trace,
     trace_lint,
 )
+from session_tasks import submit_tasks
 
 
 # ---------------------------------------------------------------------------
@@ -85,11 +87,14 @@ def test_export_structure_is_perfetto_loadable():
 
 
 def test_modeled_and_wall_land_in_separate_process_groups():
-    rt, ctx = make_runtime(policy="rimms", accelerators=("gpu0",))
+    s = make_session(scheduler="round_robin", accelerators=("gpu0",))
+    ctx = s.context
     with trace(context=ctx) as tc:
         _, tasks = build_2fzf(ctx, 64, pins=("gpu0",) * 4)
-        rt.run(tasks)
+        submit_tasks(s, tasks)
+        s.close()  # pushes the stream's modeled timeline
         doc = tc.export()
+    s.runtime.close()
     by_pid = {}
     for e in doc["traceEvents"]:
         if e["ph"] == "X":
@@ -104,7 +109,7 @@ def test_global_trace_attaches_new_contexts():
     assert global_collector() is None
     with trace() as tc:
         assert global_collector() is tc
-        rt, ctx = make_runtime(policy="rimms", accelerators=("gpu0",))
+        ctx = HeteContext()
         assert ctx.tracer is tc
     assert global_collector() is None
 
@@ -253,24 +258,30 @@ def test_lint_flags_compute_before_staging_done():
 
 
 def test_lint_conservation_nets_out_preattach_baseline():
-    rt, ctx = make_runtime(policy="rimms", accelerators=("gpu0",))
+    s = make_session(scheduler="round_robin", accelerators=("gpu0",))
+    ctx = s.context
     _, tasks = build_2fzf(ctx, 64, pins=("gpu0",) * 4)
-    rt.run(tasks)  # untraced copies accumulate first
+    submit_tasks(s, tasks)  # untraced copies accumulate first
     with trace(context=ctx) as tc:
         _, tasks2 = build_2fzf(ctx, 64, pins=("gpu0",) * 4, seed=1)
-        rt.run(tasks2)
+        submit_tasks(s, tasks2)
+        s.close()  # pushes the stream's modeled timeline
         assert trace_lint(tc.export()) == []
+    s.runtime.close()
 
 
 def test_lint_conservation_across_ledger_reset():
-    rt, ctx = make_runtime(policy="rimms", accelerators=("gpu0",))
+    s = make_session(scheduler="round_robin", accelerators=("gpu0",))
+    ctx = s.context
     with trace(context=ctx) as tc:
         _, tasks = build_2fzf(ctx, 64, pins=("gpu0",) * 4)
-        rt.run(tasks)
+        submit_tasks(s, tasks)
         ctx.ledger.reset()  # opens a fresh conservation epoch
         _, tasks2 = build_2fzf(ctx, 64, pins=("gpu0",) * 4, seed=1)
-        rt.run(tasks2)
+        submit_tasks(s, tasks2)
+        s.close()  # pushes the stream's modeled timeline
         assert trace_lint(tc.export()) == []
+    s.runtime.close()
 
 
 # ---------------------------------------------------------------------------
@@ -487,3 +498,23 @@ def test_tracing_off_builds_no_annotation_and_writes_no_event(monkeypatch):
         on.runtime.close()
     assert {f"rimms.{c}" for c in ("submit", "qos", "stage", "compute",
                                     "writeback")} <= set(built)
+
+
+def test_lint_cli_passes_a_session_trace_and_fails_a_broken_one(tmp_path):
+    """``python -m repro.core.trace`` exits 0 on a session's exported
+    trace, 1 on one whose spans break an invariant."""
+    from repro.core.trace import _main as lint_cli
+
+    good = tmp_path / "good.json"
+    with make_session(scheduler="round_robin", accelerators=("gpu0",),
+                      trace=True) as s:
+        submit_2fzf(s, 64, pins=("gpu0",) * 4)
+    s.export_trace(str(good))
+    s.runtime.close()
+    assert lint_cli([str(good)]) == 0
+    doc = json.loads(good.read_text())
+    span = next(e for e in doc["traceEvents"] if e["ph"] == "X")
+    span["dur"] = -1.0
+    bad = tmp_path / "bad.json"
+    bad.write_text(json.dumps(doc))
+    assert lint_cli([str(good), str(bad)]) == 1
