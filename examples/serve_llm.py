@@ -1,11 +1,11 @@
 """Serving driver: multi-tenant continuous batching on the RIMMS Session.
 
 A small dense LM serves two tenants' prompt streams through
-:class:`repro.serve.session_engine.SessionServeEngine`: every tenant is
-a QoS client with its own decode weight and KV page quota, KV pages live
-in runtime-managed page-group buffers, and the engine reports per-tenant
-decode latency percentiles + SLO burn rates from the deterministic QoS
-replay.  ``--legacy`` runs the same workload through the hand-managed
+:class:`repro.serve.session_engine.SessionServeEngine`: every tenant has
+its own KV page quota and latency objective, KV pages live in
+runtime-managed page-group buffers, each engine step decodes both
+tenants' slots in one task, and the engine reports per-tenant decode
+latency percentiles + SLO burn rates from the deterministic QoS replay.  ``--legacy`` runs the same workload through the hand-managed
 :class:`repro.serve.engine.ServeEngine` instead — both engines generate
 bit-identical token streams.
 
@@ -47,12 +47,10 @@ def serve_session(cfg, params, work):
     with SessionServeEngine(cfg, params, max_batch=4, page_size=16,
                             num_pages=256, max_pages_per_seq=16,
                             allocator="bitset") as eng:
-        # two tenants: "pro" gets 4x the decode weight and most of the
-        # KV page budget; "free" runs under a tight quota.
-        eng.tenant("pro", weight=4.0, quota_pages=192,
-                   slo_latency_s=1.0, slo_target=0.99)
-        eng.tenant("free", weight=1.0, quota_pages=32,
-                   slo_latency_s=1.0, slo_target=0.99)
+        # two tenants: "pro" gets most of the KV page budget; "free"
+        # runs under a tight quota.
+        eng.tenant("pro", quota_pages=192, slo_latency_s=1.0, slo_target=0.99)
+        eng.tenant("free", quota_pages=32, slo_latency_s=1.0, slo_target=0.99)
         reqs = [eng.submit(p, m, tenant=("pro" if i % 2 == 0 else "free"))
                 for i, (p, m) in enumerate(work)]
         eng.run()
@@ -60,7 +58,7 @@ def serve_session(cfg, params, work):
         for name in ("pro", "free"):
             pct = rep["latency_percentiles"][name]
             slo = rep["slo"][name]
-            print(f"  tenant {name}: {pct['count']} decode substeps, "
+            print(f"  tenant {name}: {pct['count']} decode steps, "
                   f"modeled p95 {pct['p95'] * 1e6:.1f}us, "
                   f"slo burn rate {slo['burn_rate']:.3f}")
         print(f"  kv spill bytes: {eng.kv.spill_bytes()}")

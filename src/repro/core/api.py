@@ -73,7 +73,7 @@ from .qos import DEFAULT_CLIENT, BackpressureFull, QoSManager, admission_cost
 from .runtime import (BACKENDS, Runtime, Task,  # noqa: F401
                       make_emulated_soc, platform_names, register_platform,
                       resolve_backend)
-from .telemetry import Sampler, metrics_text, serve_metrics, slo_eval
+from .telemetry import Sampler, latency_report, metrics_text, serve_metrics
 from .trace import (NULL_REGION, MetricsRegistry, TraceCollector, trace,  # noqa: F401
                     trace_lint)
 
@@ -1006,35 +1006,14 @@ class Session:
                 i: (self._builder.nodes[i].task.client or DEFAULT_CLIENT)
                 for i in finish
             }
-        # Fresh registry per call: qos_report() may be called repeatedly
-        # and the replay is a full re-simulation each time — recording
-        # into self.metrics would double-count latencies.
-        reg = MetricsRegistry()
         lat_by_client: Dict[str, List[float]] = {}
         for i, end in finish.items():
-            latency = end - release[i]
-            reg.histogram(f"latency_model_s/{client_of[i]}").record(latency)
-            lat_by_client.setdefault(client_of[i], []).append(latency)
-        percentiles: Dict[str, Dict[str, float]] = {}
-        for name, hist in reg.histograms():
-            percentiles[name.split("/", 1)[1]] = {
-                "p50": hist.percentile(50),
-                "p95": hist.percentile(95),
-                "p99": hist.percentile(99),
-                "mean": hist.mean,
-                "count": hist.count,
-            }
+            lat_by_client.setdefault(client_of[i], []).append(end - release[i])
         # SLO burn rates (ISSUE 8): evaluated over the same deterministic
         # replay latencies — burn > 1 means the error budget is being
         # spent faster than the objective allows.
         qos_params = self.qos.params()
-        slo: Dict[str, Dict[str, Any]] = {}
-        for name, cfg in qos_params["clients"].items():
-            if cfg.get("slo_latency_s") is None:
-                continue
-            slo[name] = slo_eval(lat_by_client.get(name, []),
-                                 cfg["slo_latency_s"],
-                                 cfg.get("slo_target") or 0.99)
+        percentiles, slo = latency_report(lat_by_client, qos_params["clients"])
         return {
             "makespan_model": makespan,
             "timeline": timeline,

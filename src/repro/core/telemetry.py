@@ -57,6 +57,7 @@ __all__ = [
     "serve_metrics",
     "MetricsServer",
     "slo_eval",
+    "latency_report",
     "shape_bucket",
     "divergence_serial",
     "aggregate_divergence",
@@ -596,3 +597,31 @@ def slo_eval(latencies: List[float], objective_s: float,
         "burn_rate": burn,
         "breached": burn > 1.0,
     }
+
+
+def latency_report(latencies: Dict[str, List[float]],
+                   clients: Dict[str, dict]) -> Tuple[dict, dict]:
+    """Percentiles of each tenant's task latencies (``latencies``: tenant
+    name to seconds), for the tenants that have any, and an
+    :func:`slo_eval` for each tenant of ``clients`` (name to QoS
+    parameters, as ``QoSManager.params()["clients"]``) that declares an
+    objective."""
+    percentiles: Dict[str, Dict[str, float]] = {}
+    for name in sorted(latencies):
+        if not latencies[name]:
+            continue
+        hist = Histogram()
+        for v in latencies[name]:
+            hist.record(v)
+        percentiles[name] = {
+            "p50": hist.percentile(50),
+            "p95": hist.percentile(95),
+            "p99": hist.percentile(99),
+            "mean": hist.mean,
+            "count": hist.count,
+        }
+    slo = {name: slo_eval(latencies.get(name, []), cfg["slo_latency_s"],
+                          cfg.get("slo_target") or 0.99)
+           for name, cfg in clients.items()
+           if cfg.get("slo_latency_s") is not None}
+    return percentiles, slo

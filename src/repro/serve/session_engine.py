@@ -6,12 +6,15 @@ telemetry.  This engine runs the same continuous-batching decode loop
 *through* the runtime instead (ROADMAP item 2, the "millions of users"
 scenario):
 
-* every tenant is a QoS client on a :class:`~repro.core.api.Session` —
-  weighted DRR admission, bounded in-flight windows, per-tenant decode
-  latency percentiles and SLO burn rates in ``qos_report()``;
-* the KV cache is a :class:`~repro.core.kv_manager.KVManager`: page
-  groups are Session buffers in the device arena, with per-tenant page
-  quotas enforced by the tenant-aware paged pool;
+* tenancy acts where a tenant's requests differ: the KV cache is a
+  :class:`~repro.core.kv_manager.KVManager` whose page groups are
+  Session buffers in the device arena, with per-tenant page quotas
+  enforced by the tenant-aware paged pool and at admission; and each
+  tenant's SLO is evaluated over the decode steps that carried its rows
+  (``qos_report()``);
+* an engine step decodes every active slot, whatever its tenant, in one
+  ``llm_decode`` task: the rows are independent, so splitting the batch
+  by tenant would only repeat the step's host path and its weight reads;
 * prefill and decode are distinct registered ops (``llm_prefill``
   throughput-bound, ``llm_decode`` latency-sensitive) with their own QoS
   weights/windows, so placement, staging, spans, and divergence
@@ -23,10 +26,11 @@ scenario):
   step that touches them — there is no serving-specific copy code.
 
 Token streams are bit-identical to the legacy engine on the same
-submission order: the per-tenant masked sub-steps write the same values
-into the same pages (KV entries are deterministic, idempotent functions
-of ``(token, position, params)``, and every per-row output depends only
-on that row's inputs plus its own gathered pages).
+submission order: both decode every active slot in one step that
+writes the same values into the same pages (KV entries are
+deterministic, idempotent functions of ``(token, position, params)``,
+and every per-row output depends only on that row's inputs plus its
+own gathered pages).
 
 A model with sliding-window and full attention layers and sparse-expert
 MLPs (family ``swa_moe``) runs through the same loop with two KV
@@ -40,7 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +54,7 @@ from repro.configs.base import ArchConfig
 from repro.core.api import OpRegistry, Session
 from repro.core.kv_manager import KVManager
 from repro.core.paged_kv import ring_pages
+from repro.core.telemetry import latency_report
 from repro.core.trace import NULL_REGION
 from repro.models import layers as L
 
@@ -115,10 +120,11 @@ class SessionServeEngine:
     """Session-backed continuous-batching engine.
 
     Drop-in for :class:`~repro.serve.engine.ServeEngine` plus tenancy:
-    ``submit(prompt, max_new_tokens, tenant=...)`` queues a request
-    under a QoS client; ``step()`` admits waiting requests (prefill
-    tasks under the shared throughput-bound ``prefill`` client) and runs
-    one lock-step decode as per-tenant latency-sensitive sub-steps.
+    ``submit(prompt, max_new_tokens, tenant=...)`` queues a request for
+    a tenant; ``step()`` admits waiting requests (prefill tasks under
+    the throughput-bound ``prefill`` client) and runs one lock-step
+    decode over every active slot as one task of the latency-sensitive
+    ``decode`` client (``decode_weight``, ``decode_window``).
 
     With no ``session`` the engine owns a fresh emulated SoC whose
     single device arena (``arena_bytes``) backs the KV groups —
@@ -149,8 +155,6 @@ class SessionServeEngine:
         self.max_pages = max_pages_per_seq
         self.max_batch = max_batch
         self.eos_id = eos_id
-        self._decode_weight = decode_weight
-        self._decode_window = decode_window
 
         self._registry = OpRegistry()
         self._register_kernels()
@@ -195,7 +199,7 @@ class SessionServeEngine:
             self._ring_tokens = ring * page_size
             #: routing of the last ``step()``: ``"prefill"``, a (prompt
             #: length, (layers, experts) counts) pair per prompt taken
-            #: in; ``"decode"``, the counts summed over its sub-steps
+            #: in; ``"decode"``, the counts of its decode task
             self.last_routing = {"prefill": [], "decode": None}
         #: prompt tokens one prefill call takes in, a row each; with window
         #: rings, no more than a ring's slots less the window, so that no
@@ -204,7 +208,11 @@ class SessionServeEngine:
                              if self.hybrid else max_batch)
         self._prefill_client = session.client(
             "prefill", weight=prefill_weight, window=prefill_window)
-        self._tenants: Dict[str, object] = {}  # name -> SessionClient
+        self._decode_client = session.client(
+            "decode", weight=decode_weight, window=decode_window)
+        self._tenants: set[str] = set()
+        #: each decode task's stream node and the tenants it carried rows of
+        self._decode_charges: List[Tuple[int, Tuple[str, ...]]] = []
 
         self.block_tables = np.full(
             (max_batch, max_pages_per_seq), self.kv.scratch_page, np.int32)
@@ -281,21 +289,14 @@ class SessionServeEngine:
            replace=True)(prefill_kernel)
 
     # -- tenants -------------------------------------------------------------
-    def tenant(self, name: str, *, weight: Optional[float] = None,
-               window: Optional[int] = None,
-               quota_pages: Optional[int] = None,
+    def tenant(self, name: str, *, quota_pages: Optional[int] = None,
                slo_latency_s: Optional[float] = None,
                slo_target: Optional[float] = None):
-        """Register (or update) a tenant: a QoS client for its decode
-        tasks plus an optional KV page quota."""
-        cl = self.session.client(
-            name,
-            weight=self._decode_weight if weight is None else weight,
-            window=self._decode_window if window is None else window,
-            slo_latency_s=slo_latency_s, slo_target=slo_target,
-        )
-        if name not in self._tenants:
-            self._tenants[name] = cl
+        """Register (or update) a tenant: a Session client that holds its
+        latency objective, plus an optional KV page quota."""
+        cl = self.session.client(name, slo_latency_s=slo_latency_s,
+                                 slo_target=slo_target)
+        self._tenants.add(name)
         if quota_pages is not None:
             for kv in self.kvs:  # the quota holds in each pool
                 kv.set_quota(name, quota_pages)
@@ -417,6 +418,11 @@ class SessionServeEngine:
 
     # -- decode --------------------------------------------------------------
     def _decode_substep(self, mask: np.ndarray, client) -> np.ndarray:
+        """One ``llm_decode`` task under ``client`` over the slots in
+        ``mask``; returns the next token of every slot.  A row outside
+        ``mask`` gets no token worth reading, yet still writes keys,
+        computed without attention, at its position in its own pages:
+        ``_step`` passes every active slot."""
         sess = self.session
         tok = sess.malloc((self.max_batch,), np.int32, client=client)
         tok.data[...] = self.slot_tok
@@ -431,18 +437,21 @@ class SessionServeEngine:
         )
         for b in (tok, pos, *tbs):
             sess.free(b)
+        rows = np.flatnonzero(mask)
+        sess.metrics.counter("serve/decode_calls").inc()
+        sess.metrics.counter("serve/decode_rows").inc(len(rows))
         out = futs[0].result()
+        self._decode_charges.append(
+            (futs[0].node, tuple(dict.fromkeys(self.slot_req[s].tenant for s in rows))))
         sess.free(nxt)
         if routed:
-            counts = futs[1].result().copy()
+            self.last_routing["decode"] = futs[1].result().copy()
             sess.free(routed[0])
-            decode = self.last_routing["decode"]
-            self.last_routing["decode"] = counts if decode is None else decode + counts
         return out
 
     def step(self) -> int:
-        """One lock-step decode over all active slots — submitted as one
-        latency-sensitive sub-step per tenant present; returns #active."""
+        """One lock-step decode over all active slots, as one task;
+        returns #active."""
         with self._region("step"):
             return self._step()
 
@@ -459,30 +468,22 @@ class SessionServeEngine:
         metrics = self.session.metrics
         if self.hybrid:
             self._count_pages(active)
-        for tname, client in self._tenants.items():
-            slots = [s for s in range(self.max_batch)
-                     if self.slot_req[s] is not None
-                     and self.slot_req[s].tenant == tname]
-            if not slots:
-                continue
-            mask = np.zeros((self.max_batch,), bool)
-            mask[slots] = True
-            nxt = self._decode_substep(mask, client)
-            for slot in slots:
-                req = self.slot_req[slot]
-                tok = int(nxt[slot])
-                req.generated.append(tok)
-                metrics.counter("serve_tokens_generated").inc()
-                self.slot_pos[slot] += 1
-                self.slot_tok[slot] = tok
-                if (len(req.generated) >= req.max_new_tokens
-                        or tok == self.eos_id):
-                    req.done = True
-                    for kv, bt in zip(self.kvs, self.tables):
-                        kv.free(req.rid)
-                        bt[slot, :] = kv.scratch_page
-                    self.slot_req[slot] = None
-                    metrics.counter("serve_requests_completed").inc()
+        nxt = self._decode_substep(active, self._decode_client)
+        for slot in np.flatnonzero(active):
+            req = self.slot_req[slot]
+            tok = int(nxt[slot])
+            req.generated.append(tok)
+            metrics.counter("serve_tokens_generated").inc()
+            self.slot_pos[slot] += 1
+            self.slot_tok[slot] = tok
+            if (len(req.generated) >= req.max_new_tokens
+                    or tok == self.eos_id):
+                req.done = True
+                for kv, bt in zip(self.kvs, self.tables):
+                    kv.free(req.rid)
+                    bt[slot, :] = kv.scratch_page
+                self.slot_req[slot] = None
+                metrics.counter("serve_requests_completed").inc()
         self._finish_step()
         return n_active
 
@@ -529,10 +530,23 @@ class SessionServeEngine:
 
     # -- reporting / lifecycle ----------------------------------------------
     def qos_report(self):
-        """The session's deterministic QoS replay — per-tenant decode
-        latency percentiles, SLO burn rates, fairness, metrics."""
+        """The session's deterministic QoS replay — latency percentiles,
+        SLO burn rates, fairness, metrics — with each tenant charged the
+        replayed latency of every decode task that carried one of its
+        rows."""
         self.session.barrier()
-        return self.session.qos_report()
+        rep = self.session.qos_report()
+        finish, release = rep["finish_model"], rep["release_model"]
+        lat: Dict[str, List[float]] = {}
+        for node, tenants in self._decode_charges:
+            for name in tenants:
+                lat.setdefault(name, []).append(finish[node] - release[node])
+        clients = rep["qos"]["clients"]
+        percentiles, slo = latency_report(
+            lat, {name: clients[name] for name in self._tenants})
+        rep["latency_percentiles"].update(percentiles)
+        rep["slo"].update(slo)
+        return rep
 
     def close(self) -> None:
         if self._owns_session and not self.session.closed:

@@ -307,6 +307,64 @@ def test_routing_counts_and_counters_follow_the_traffic(fresh_programs):
         assert m["kv/window/ring_wraps"] == sum(1 for p in written if p % ring == 0) == 2
 
 
+def _decode_one_tenant_at_a_time(eng):
+    """Make ``eng`` decode each tenant's rows in a masked call of its own,
+    and its routing counts the calls' sum; returns the number of tenants
+    of each decoding step.
+
+    A masked row still writes its keys into its own pages, computed
+    without attention or experts; so, as in a step of per-tenant calls,
+    a tenant's rows go into the later calls already advanced to the next
+    position, which their next step writes again before reading it."""
+    real, seen = eng._decode_substep, []
+
+    def per_tenant(mask, client):
+        tok, pos = eng.slot_tok.copy(), eng.slot_pos.copy()
+        out, total = np.zeros_like(tok), 0
+        tenants = dict.fromkeys(eng.slot_req[s].tenant for s in np.flatnonzero(mask))
+        for name in tenants:
+            own = mask & np.array([r is not None and r.tenant == name for r in eng.slot_req])
+            out[own] = real(own, client)[own]
+            total = total + eng.last_routing["decode"]
+            eng.slot_tok[own], eng.slot_pos[own] = out[own], pos[own] + 1
+        eng.slot_tok[:], eng.slot_pos[:] = tok, pos  # the engine advances them
+        eng.last_routing["decode"] = total
+        seen.append(len(tenants))
+        return out
+
+    eng._decode_substep = per_tenant
+    return seen
+
+
+def test_two_tenants_decode_in_one_task_as_if_one_at_a_time(fresh_programs):
+    """With two tenants in flight together, each step is one decode task;
+    its tokens and routing counts equal those of the same requests
+    decoded one tenant at a time."""
+    cfg = small()
+    w = make_weights(cfg, SEED + 3)
+    rng = np.random.default_rng(6)
+    work = [([int(t) for t in rng.integers(0, 256, n)], k, name)
+            for n, k, name in ((3, 6, "a"), (5, 4, "b"), (2, 7, "a"), (4, 5, "b"))]
+    runs = []
+    for split in (False, True):
+        with engine(cfg, w, max_batch=4) as eng:
+            seen = _decode_one_tenant_at_a_time(eng) if split else None
+            reqs = [eng.submit(p, k, tenant=name) for p, k, name in work]
+            routing = []
+            while not all(r.done for r in reqs):
+                if eng.step():
+                    routing.append(eng.last_routing["decode"])
+            decodes = sum(1 for n, _ in eng.session.runtime.task_log
+                          if n.startswith("llm_decode"))
+            runs.append(([r.generated for r in reqs], routing, decodes))
+    (tokens, routing, decodes), (want, want_routing, split_decodes) = runs
+    assert max(seen) == 2 and split_decodes == sum(seen) > len(seen)
+    assert decodes == len(routing) == len(seen)
+    assert tokens == want
+    for got, summed in zip(routing, want_routing):
+        np.testing.assert_array_equal(got, summed)
+
+
 def test_dense_family_keeps_its_step_program():
     """A dense model's step still comes from ``_jit_grouped_step`` with
     one block table and no routing output."""

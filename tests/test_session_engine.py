@@ -56,6 +56,63 @@ def test_bit_identical_to_legacy_multi_tenant(setup):
         assert {"a", "b", "prefill"} <= set(rep["latency_percentiles"])
 
 
+def _step_all(eng, reqs):
+    """Step ``eng`` until ``reqs`` are done; per decoding step, the
+    tenants whose requests took a token in it."""
+    steps = []
+    while not all(r.done for r in reqs):
+        before = [len(r.generated) for r in reqs]
+        if eng.step():
+            steps.append({r.tenant for r, n in zip(reqs, before) if len(r.generated) > n})
+    return steps
+
+
+def test_one_decode_task_per_step_over_every_tenant(setup):
+    """Two tenants in flight together decode in one ``llm_decode`` task
+    per step, and the counters count those tasks and the rows they
+    carried."""
+    cfg, model, params = setup
+    work = make_work(cfg.vocab, n=6, seed=3)
+    want = legacy_tokens(cfg, params, work)
+    with SessionServeEngine(cfg, params, max_batch=3, page_size=8,
+                            num_pages=64, max_pages_per_seq=8,
+                            pages_per_group=8) as eng:
+        reqs = [eng.submit(p, m, tenant=["a", "b"][i % 2])
+                for i, (p, m) in enumerate(work)]
+        steps = _step_all(eng, reqs)
+        assert [r.generated for r in reqs] == want
+        assert any(len(t) == 2 for t in steps)  # both tenants in one step
+        decodes = [n for n, _ in eng.session.runtime.task_log
+                   if n.startswith("llm_decode")]
+        assert len(decodes) == len(steps)
+        m = eng.session.metrics
+        assert m.counter("serve/decode_calls").value == len(steps)
+        assert m.counter("serve/decode_rows").value == sum(len(r.generated) for r in reqs)
+
+
+def test_qos_report_charges_each_tenant_its_decode_steps(setup):
+    """Every tenant keeps a latency entry and an SLO evaluation, over the
+    decode steps in which it had a row."""
+    cfg, model, params = setup
+    work = make_work(cfg.vocab, n=7, seed=5)
+    with SessionServeEngine(cfg, params, max_batch=3, page_size=8,
+                            num_pages=64, max_pages_per_seq=8,
+                            pages_per_group=8) as eng:
+        for name in ("a", "b"):
+            eng.tenant(name, slo_latency_s=60.0, slo_target=0.99)
+        # b's one request waits behind a's first three
+        reqs = [eng.submit(p, m, tenant="b" if i == 3 else "a")
+                for i, (p, m) in enumerate(work)]
+        steps = _step_all(eng, reqs)
+        rep = eng.qos_report()
+        for name in ("a", "b"):
+            n = sum(1 for t in steps if name in t)
+            assert rep["latency_percentiles"][name]["count"] == n
+            assert rep["slo"][name]["tasks"] == n and not rep["slo"][name]["breached"]
+        assert 0 < rep["latency_percentiles"]["b"]["count"] < len(steps)
+        assert rep["latency_percentiles"]["decode"]["count"] == len(steps)
+
+
 def test_spill_under_pressure_is_bit_identical(setup):
     cfg, model, params = setup
     rng = np.random.default_rng(7)
